@@ -401,12 +401,14 @@ class MeshLoweredEntrypoint:
         return collective_totals(self.hlo_text, BUDGET_OPS)
 
     def dropped_donations(self) -> List[str]:
-        """Per-device ShapedArray reprs from the lower-time
-        dropped-donation warning (empty → every donation aliased)."""
+        """Per-device aval reprs (``float32[8,64]``) from the lower-time
+        dropped-donation warning (empty → every donation aliased).  The
+        pattern takes the bare form jax 0.9 prints and the
+        ``ShapedArray(...)`` wrapping of earlier releases alike."""
         out: List[str] = []
         for msg in self.lower_warnings:
             if _DONATION_WARNING in msg:
-                out.extend(re.findall(r"ShapedArray\(([^)]*)\)", msg))
+                out.extend(re.findall(r"[a-z]+[0-9]*\[[0-9,\s]*\]", msg))
         return out
 
 

@@ -354,8 +354,11 @@ class HostCallbackRule(PerfRule):
     severity = SEV_ERROR
     title = "host callback reachable from a jit entrypoint"
 
-    _PRIMS = ("debug_callback", "pure_callback", "io_callback",
-              "host_callback", "outside_call", "infeed", "outfeed")
+    # jax 0.9 lowers jax.debug.print to its own `debug_print` primitive
+    # (it was a `debug_callback` before)
+    _PRIMS = ("debug_callback", "debug_print", "pure_callback",
+              "io_callback", "host_callback", "outside_call", "infeed",
+              "outfeed")
 
     def check_entrypoint(self, traced):
         spec = traced.spec

@@ -24,6 +24,10 @@ from .registry import EntrypointSpec
 
 #: StableHLO main-signature argument attribute marking a GRANTED donation
 _ALIAS_ATTR = "tf.aliasing_output"
+#: a donated arg the lowering could NOT pair with an output of its own
+#: shape/dtype but handed to XLA as a reusable buffer (same element
+#: count); whether XLA used it shows only in the compiled module
+_DONOR_ATTR = "jax.buffer_donor"
 
 
 @dataclasses.dataclass
@@ -147,7 +151,7 @@ class TracedEntrypoint:
         ``alias_attr_count``/``hlo_arg_type_counts`` (exact, parse-only)
         or the lower-time warning set instead of these per-leaf flags."""
         li = 0
-        for type_str, aliased in self._hlo_args():
+        for type_str, aliased, _ in self._hlo_args():
             while li < len(leaves) and \
                     _mlir_type(leaves[li].dtype, leaves[li].shape) \
                     != type_str:
@@ -160,14 +164,16 @@ class TracedEntrypoint:
         for leaf in leaves[li:]:
             leaf.present = False
 
-    def _hlo_args(self) -> List[Tuple[str, bool]]:
-        """(tensor type, has tf.aliasing_output) per ``main`` arg, in
-        order — parsed once from the lowered module text."""
+    def _hlo_args(self) -> List[Tuple[str, bool, bool]]:
+        """(tensor type, has tf.aliasing_output, has jax.buffer_donor)
+        per ``main`` arg, in order — parsed once from the lowered module
+        text."""
         if getattr(self, "_hlo_args_cache", None) is None:
             m = re.search(r"func\.func (?:public )?@main\((.*?)\)\s*->",
                           self.lowered_text, re.S)
             self._hlo_args_cache = [] if not m else [
-                (am.group(1), _ALIAS_ATTR in (am.group(2) or ""))
+                (am.group(1), _ALIAS_ATTR in (am.group(2) or ""),
+                 _DONOR_ATTR in (am.group(2) or ""))
                 for am in re.finditer(
                     r"%arg\d+:\s*tensor<([^>]*)>\s*(\{[^}]*\})?",
                     m.group(1))]
@@ -176,33 +182,43 @@ class TracedEntrypoint:
     def alias_attr_count(self) -> int:
         """How many ``main`` args the lowered module actually aliases —
         exact (no leaf alignment involved)."""
-        return sum(1 for _, aliased in self._hlo_args() if aliased)
+        return sum(1 for _, aliased, _ in self._hlo_args() if aliased)
 
     def hlo_arg_type_counts(self) -> Dict[str, int]:
         """Tensor-type multiset of the kept ``main`` args; comparing it
         against the spec leaves' type multiset tells whether any leaf of
         a given type was eliminated (count mismatch = ambiguity)."""
         counts: Dict[str, int] = {}
-        for type_str, _ in self._hlo_args():
+        for type_str, _, _ in self._hlo_args():
             counts[type_str] = counts.get(type_str, 0) + 1
         return counts
 
     def dropped_donations(self) -> List[Tuple[str, Tuple[int, ...]]]:
-        """(dtype, shape) of every donated buffer the lowering REFUSED to
-        alias, parsed from jax's authoritative lower-time warning ("Some
-        donated buffers were not usable: ShapedArray(...)").  This is the
-        primary dropped-donation signal: it fires exactly for mismatches
-        — an unused donated arg is eliminated and freed WITHOUT a warning
-        — so it is immune to the positional ambiguity of aligning HLO
-        args against flat leaves when identical tensor types repeat."""
+        """(dtype, shape) of every donated buffer that ends up aliasing
+        nothing.  Two exact sources, neither of which aligns HLO args
+        against flat leaves (ambiguous when tensor types repeat):
+
+        * jax's lower-time warning ("Some donated buffers were not
+          usable: float32[128,128]") — no output has even the element
+          count.  An unused donated arg is eliminated and freed WITHOUT
+          a warning;
+        * a ``jax.buffer_donor`` arg that the COMPILED module still
+          lists under ``buffer_donor={...}``: the lowering left the
+          pairing to XLA (same element count, other dtype/shape) and
+          XLA found no output to reuse it for."""
         out: List[Tuple[str, Tuple[int, ...]]] = []
         for w in self.warnings:
             if "donated buffers were not usable" not in w.lower():
                 continue
-            for m in re.finditer(r"ShapedArray\((\w+)\[([0-9,\s]*)\]", w):
-                shape = tuple(int(s) for s in m.group(2).split(",")
-                              if s.strip())
-                out.append((m.group(1), shape))
+            for m in re.finditer(r"\b([a-z]+[0-9]*)\[([0-9,\s]*)\]", w):
+                out.append((m.group(1), _dims(m.group(2).split(","))))
+        args = self._hlo_args()
+        if any(donor for _, _, donor in args):
+            header = self.compiled().as_text().split("\n", 1)[0]
+            m = re.search(r"buffer_donor=\{([^}]*(?:\{\}[^}]*)*)\}", header)
+            for pm in re.finditer(r"\((\d+),", m.group(1) if m else ""):
+                *dims, el = args[int(pm.group(1))][0].split("x")
+                out.append((_NP_DTYPES.get(el, el), _dims(dims)))
         return out
 
     # -- jaxpr walk ----------------------------------------------------------
@@ -236,7 +252,7 @@ class TracedEntrypoint:
         try:
             from jax._src import source_info_util
 
-            frame = source_info_util.user_frame(eqn.source_info)
+            frame = source_info_util.user_frame(eqn.source_info.traceback)
             if frame is None:
                 return "", 0
             fname = frame.file_name
@@ -271,6 +287,13 @@ _MLIR_DTYPES = {
     "int64": "i64", "uint8": "ui8", "uint16": "ui16", "uint32": "ui32",
     "uint64": "ui64", "bool": "i1", "complex64": "complex<f32>",
 }
+
+
+_NP_DTYPES = {v: k for k, v in _MLIR_DTYPES.items()}
+
+
+def _dims(parts) -> Tuple[int, ...]:
+    return tuple(int(d) for d in parts if d.strip())
 
 
 def _mlir_type(dtype: str, shape: Tuple[int, ...]) -> str:

@@ -91,8 +91,9 @@ AXIS_PIPE = "pipe"         # pipeline parallelism
 
 # ---------------------------------------------------------------------------
 # TPU chip peak bf16 FLOP/s by jax device_kind (public specs; MXU peak).
-# Single source of truth for every MFU computation (bench.py,
-# benchmarks/llm_bench.py, probes).
+# Single source of truth for every MFU computation, read through
+# `flight_recorder.chip_peak_flops`.  A kind that is not listed has no
+# peak: the library then reports no MFU and the bench scripts refuse.
 # ---------------------------------------------------------------------------
 TPU_PEAK_BF16_FLOPS = {
     "TPU v5 lite": 197e12,   # v5e
@@ -101,4 +102,3 @@ TPU_PEAK_BF16_FLOPS = {
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,   # v6e/Trillium
 }
-TPU_PEAK_BF16_DEFAULT = 197e12

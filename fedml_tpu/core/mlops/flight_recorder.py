@@ -413,20 +413,17 @@ def tree_nbytes(tree: Any) -> int:
 
 # -- measured MFU + per-program telemetry ------------------------------------
 
-def chip_peak_flops(device: Any = None) -> float:
-    """Peak bf16 FLOP/s of the attached chip, from the single-source
-    table in `constants` (default for unknown kinds, e.g. CPU proxies)."""
-    from ...constants import TPU_PEAK_BF16_DEFAULT, TPU_PEAK_BF16_FLOPS
+def chip_peak_flops(device: Any = None) -> Optional[float]:
+    """Peak bf16 FLOP/s of ``device`` (default: the first attached one)
+    from the single-source table in `constants`; None for a kind the
+    table does not list (a CPU, an unknown chip) — never an assumed peak."""
+    from ...constants import TPU_PEAK_BF16_FLOPS
 
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:  # noqa: BLE001
-            return TPU_PEAK_BF16_DEFAULT
-    return TPU_PEAK_BF16_FLOPS.get(
-        str(getattr(device, "device_kind", "")), TPU_PEAK_BF16_DEFAULT)
+        device = jax.devices()[0]
+    return TPU_PEAK_BF16_FLOPS.get(str(getattr(device, "device_kind", "")))
 
 
 def program_cost(compiled: Any) -> Optional[Dict[str, float]]:
@@ -497,12 +494,16 @@ def note_program(name: str, compiled: Any,
 
 
 def measured_mfu(program: str, flops: float, device_seconds: float,
-                 device: Any = None) -> float:
+                 device: Any = None) -> Optional[float]:
     """MFU from measured device time: ``flops / seconds / chip_peak``.
-    Sets the per-program gauge and returns the value."""
+    Sets the per-program gauge and returns the value; None (and no gauge)
+    where the device has no known peak."""
+    peak = chip_peak_flops(device)
+    if peak is None:
+        return None
     if device_seconds <= 0:
         return 0.0
-    mfu = float(flops) / float(device_seconds) / chip_peak_flops(device)
+    mfu = float(flops) / float(device_seconds) / peak
     _measured_mfu().labels(program=program).set(mfu)
     return mfu
 

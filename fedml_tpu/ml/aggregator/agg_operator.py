@@ -55,7 +55,7 @@ def uniform_average(trees: Sequence[Any], denom: float = None) -> Any:
     )
 
 
-def agg_stacked(stacked: Any, weights: jnp.ndarray) -> Any:
+def agg_stacked(stacked: Any, weights: jnp.ndarray, **epilogue_kw) -> Any:
     """Weighted average over a leading client axis.
 
     ``stacked``: pytree whose leaves have shape [n_clients, ...];
@@ -72,8 +72,9 @@ def agg_stacked(stacked: Any, weights: jnp.ndarray) -> Any:
     Routed through the fused round-epilogue kernel family
     (``ops/epilogue.py``): on TPU each leaf is one pallas HBM pass; off
     TPU the jnp fallback is this contract's original math, bit for bit.
+    ``epilogue_kw`` passes through to ``weighted_reduce``.
     """
-    return _epilogue.weighted_reduce(stacked, weights)
+    return _epilogue.weighted_reduce(stacked, weights, **epilogue_kw)
 
 
 def mix_global(global_tree: Any, agg_tree: Any, server_lr: Any) -> Any:

@@ -42,10 +42,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .pallas_ops import _BLOCK, _HAS_PALLAS, _on_tpu
+from jax.experimental import pallas as pl
 
-if _HAS_PALLAS:  # pragma: no branch
-    from jax.experimental import pallas as pl
+from .pallas_ops import _BLOCK, _on_tpu
 
 #: lane width of the traced-scalar params row (lane dim must be a
 #: multiple of 128 on TPU; slots: server_lr, adam bias corrections)
@@ -115,8 +114,6 @@ def _norm_weights(weights: jnp.ndarray) -> jnp.ndarray:
 
 
 def _use_pallas(prefer_pallas: Optional[bool]) -> bool:
-    if not _HAS_PALLAS:
-        return False
     return _on_tpu() if prefer_pallas is None else bool(prefer_pallas)
 
 

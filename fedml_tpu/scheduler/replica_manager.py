@@ -73,7 +73,10 @@ class ReplicaProcessManager:
         if self.registry_root:
             cmd += ["--root", self.registry_root]
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")  # replicas default off-chip
+        # a chip belongs to one process at a time, and this parent may
+        # hold it: replica workers stay on the CPU unless the operator
+        # hands them the chip by setting JAX_PLATFORMS for them
+        env.setdefault("JAX_PLATFORMS", "cpu")
         proc = subprocess.Popen(cmd, env=env, stdout=subprocess.DEVNULL,
                                 stderr=subprocess.STDOUT)
         rep = _Replica(proc, port)

@@ -381,9 +381,9 @@ def decode_multi(params: Dict[str, Any],
                  top_k: jnp.ndarray, top_p: jnp.ndarray, rng: jax.Array,
                  heads: int, k: int, exact_filters: bool = False):
     """k tokens per row in ONE dispatch, sampling on-device — the
-    autoregressive loop never returns to the host mid-chunk (a ~k×
-    dispatch-latency win on remote/tunneled accelerators, and no per-token
-    host sync on local ones).
+    autoregressive loop never returns to the host mid-chunk, so there is
+    one dispatch and no per-token host sync (``k`` = the engine's
+    ``tokens_per_dispatch``; its value is not re-measured on a local chip).
 
     ``prompt_buf`` [B, k]: tokens to teacher-force (chunked prefill);
     row i consumes ``prompt_n[i]`` of them, then switches to its own

@@ -395,7 +395,8 @@ class StreamingParrotAPI:
         in_axes = algo_in_axes(self.algo)
         aggregate = build_aggregate(self.args, self.algo, self.n_total,
                                     server_tx=getattr(self, "server_tx",
-                                                      None))
+                                                      None),
+                                    mesh=self.mesh)
         algo = self.algo
         local_update = self.local_update
         n_strata = len(self.sampler.strata)

@@ -16,12 +16,12 @@ def _run(monkeypatch=None, sabotage=False):
 
         orig = pa.agg_stacked
 
-        def broken(new_vars, weights):
+        def broken(new_vars, weights, **kw):
             # sabotage: the aggregate comes out 20x too small (the
             # "aggregation output numerically wrong" failure class — e.g.
             # a mis-scaled weight normalization); learning stalls and the
             # run must miss the guard threshold
-            out = orig(new_vars, weights)
+            out = orig(new_vars, weights, **kw)
             import jax
 
             return jax.tree_util.tree_map(

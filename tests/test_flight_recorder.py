@@ -141,13 +141,25 @@ def test_program_cost_memory_and_mfu(armed):
     kinds = [r.get("kind") for r in fr.load_flight_log(armed)]
     assert "program" in kinds
 
+    # a stand-in device whose kind the peak table lists: the CPU these
+    # tests run on has no peak, and so no MFU (asserted below)
+    from types import SimpleNamespace
+
+    from fedml_tpu.constants import TPU_PEAK_BF16_FLOPS
+
+    v5e = SimpleNamespace(device_kind="TPU v5 lite")
+    assert fr.chip_peak_flops(v5e) == TPU_PEAK_BF16_FLOPS["TPU v5 lite"]
     mfu = fr.measured_mfu("test/matmul", flops=cost["flops"],
-                          device_seconds=0.001)
+                          device_seconds=0.001, device=v5e)
     assert 0.0 < mfu == pytest.approx(
-        cost["flops"] / 0.001 / fr.chip_peak_flops())
-    assert fr.measured_mfu("test/matmul", 1e9, 0.0) == 0.0
+        cost["flops"] / 0.001 / fr.chip_peak_flops(v5e))
+    assert fr.measured_mfu("test/matmul", 1e9, 0.0, device=v5e) == 0.0
     text = metrics_mod.render_prometheus()
     assert 'fedml_measured_mfu{program="test/matmul"}' in text
+    # no assumed peak: an unlisted kind (this CPU) gives no number at all
+    assert fr.chip_peak_flops() is None
+    assert fr.measured_mfu("test/unknown", 1e9, 0.001) is None
+    assert 'program="test/unknown"' not in metrics_mod.render_prometheus()
 
 
 # -- summarize / report / diff ------------------------------------------------
@@ -251,7 +263,8 @@ def test_parrot_fused_coverage_and_overhead(args_factory, tmp_path):
     assert s["kinds"]["parrot_fused"]["phases_s"]["device_compute"] > 0
     prog = s["programs"].get("parrot/fused_round_scan")
     assert prog is not None and prog.get("flops", 0) > 0
-    assert prog.get("last_mfu", 0) > 0
+    # this run is on the CPU, which has no peak in the table: no MFU
+    assert "last_mfu" not in prog
 
 
 def test_unfused_parrot_round_records(args_factory, tmp_path):

@@ -526,7 +526,19 @@ def _repo_root():
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_cli_load_run_report_and_slo_gate(tmp_path):
+@pytest.fixture
+def keep_registry():
+    """``fedml load run`` starts from an empty process-wide registry.
+    Put back what it dropped, so modules that took their metric handles at
+    import (the pod scheduler) still export them to tests that run later
+    in this process."""
+    before = metrics_mod.REGISTRY.collect()
+    yield
+    with metrics_mod.REGISTRY._lock:
+        metrics_mod.REGISTRY._metrics.update(before)
+
+
+def test_cli_load_run_report_and_slo_gate(tmp_path, keep_registry):
     from fedml_tpu.cli.cli import cli
 
     out = str(tmp_path / "soak")
@@ -569,7 +581,7 @@ def test_cli_load_run_report_and_slo_gate(tmp_path):
 
 
 @pytest.mark.slow
-def test_cli_load_curve_finds_knee(tmp_path):
+def test_cli_load_curve_finds_knee(tmp_path, keep_registry):
     """Acceptance: the CPU-proxy sweep locates a saturation knee and the
     engine degrades gracefully past it (shedding engaged, admitted p99
     bounded)."""

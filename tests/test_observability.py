@@ -336,13 +336,15 @@ class _StubBundle:
 def test_llm_engine_populates_metrics():
     from fedml_tpu.serving.llm_engine import BatchedLLMEngine
 
-    reg = metrics_mod.REGISTRY.collect()
-    ttft = reg["fedml_llm_ttft_seconds"].labels(engine="batched")
-    tokens = reg["fedml_llm_tokens_total"].labels(engine="batched")
-    ttft_before, tokens_before = ttft.count, tokens.value
-
+    # the engine registers its metrics when it is built (get-or-create),
+    # so read the registry after that — this test must not depend on an
+    # earlier one having built an engine
     eng = BatchedLLMEngine(_StubBundle(), {}, max_batch=2, window=16)
     try:
+        reg = metrics_mod.REGISTRY.collect()
+        ttft = reg["fedml_llm_ttft_seconds"].labels(engine="batched")
+        tokens = reg["fedml_llm_tokens_total"].labels(engine="batched")
+        ttft_before, tokens_before = ttft.count, tokens.value
         out = eng.generate([1, 2, 3], max_new=5, timeout=60.0)
         assert len(out) == 8
     finally:

@@ -56,6 +56,30 @@ def test_mesh_backend_shards_clients(args_factory):
     assert m["test_acc"] > 0.15
 
 
+@pytest.mark.parametrize("backend,prefer_pallas",
+                         [("parrot", None), ("mesh", False)])
+def test_round_aggregates_through_agg_stacked(args_factory, monkeypatch,
+                                              backend, prefer_pallas):
+    """The round's weighted mean goes through ``parrot_api.agg_stacked``:
+    the seam the slow bench-guard test sabotages.  On a mesh it asks for
+    the jnp form (GSPMD cannot partition the epilogue's kernels)."""
+    import fedml_tpu.simulation.parrot.parrot_api as pa
+
+    orig, calls = pa.agg_stacked, []
+
+    def recording(stacked, weights, **kw):
+        calls.append(kw)
+        return orig(stacked, weights, **kw)
+
+    monkeypatch.setattr(pa, "agg_stacked", recording)
+    m = _run(args_factory(backend=backend, client_num_in_total=8,
+                          client_num_per_round=8, comm_round=2,
+                          data_scale=0.3))
+    assert np.isfinite(m["test_loss"])
+    assert calls and all(kw == {"prefer_pallas": prefer_pallas}
+                         for kw in calls)
+
+
 @pytest.mark.parametrize("optimizer", [
     "FedAvg", "FedProx", "FedOpt", "FedNova", "SCAFFOLD", "FedDyn", "Mime",
 ])
