@@ -21,6 +21,7 @@ A process-wide default ``REGISTRY`` backs the module-level ``counter`` /
 
 from __future__ import annotations
 
+import itertools
 import re
 import threading
 import time
@@ -263,10 +264,17 @@ class _Metric:
         return "\n".join(lines)
 
 
+_generations = itertools.count()
+
+
 class MetricsRegistry:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._metrics: Dict[str, _Metric] = {}
+        #: renewed by `reset`, and no two registries share one: a holder
+        #: of cached children compares it to know that its handles are no
+        #: longer exported
+        self.generation = next(_generations)
 
     def _get_or_create(self, name: str, help: str, kind: str,
                        labels: Sequence[str],
@@ -314,6 +322,7 @@ class MetricsRegistry:
         long-lived objects keep working but stop being exported."""
         with self._lock:
             self._metrics.clear()
+            self.generation = next(_generations)
 
 
 #: process-wide default registry (what the control plane exports)

@@ -18,17 +18,21 @@ OpenTelemetry-shaped identity on top of the existing mlops JSONL pipeline:
   ``fedml_span_seconds`` histogram in `metrics.py`;
 * when `jax.profiler` is importable and annotations are enabled, every span
   also opens a ``jax.profiler.TraceAnnotation`` so host-side spans line up
-  with XLA events in a captured profiler trace.
+  with XLA events in a captured profiler trace;
+* `phase()` is the light tier for hot loops (an engine iteration, a
+  ``train()`` call): a clock pair, the same annotation and the same
+  histogram, and none of the identity above.
 
 Everything is stdlib; JAX involvement is strictly optional.
 """
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from . import metrics as _metrics
 
@@ -93,6 +97,35 @@ def _span_seconds() -> Any:
 def enable_jax_annotations(on: bool) -> None:
     global _jax_annotations
     _jax_annotations = "1" if on else "0"
+
+
+#: ``jax.profiler.TraceAnnotation``, looked up at the first span: the class,
+#: or False where JAX cannot be imported
+_annotation_cls: Any = None
+
+
+def _open_annotation(name: str) -> Any:
+    """An entered ``TraceAnnotation`` (a host event on the profiler's own
+    clock whenever a profiler session is open, next to nothing when none
+    is), or None: annotations off, JAX absent, or the profiler unusable."""
+    global _annotation_cls
+    if _jax_annotations == "0":
+        return None
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation_cls = TraceAnnotation
+        except ImportError:
+            _annotation_cls = False
+    if not _annotation_cls:
+        return None
+    try:
+        ann = _annotation_cls(name)
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 — profiler unusable
+        return None
 
 
 def _new_id(nbytes: int) -> str:
@@ -193,19 +226,7 @@ class Span:
         # jax TraceAnnotation (TraceMe) is same-thread scoped; only scoped
         # `with span(...)` use can guarantee that, so manually-ended spans
         # (which e.g. a timer thread may close) pass annotate=False
-        self._annotation = self._open_annotation() if annotate else None
-
-    def _open_annotation(self):
-        if _jax_annotations == "0":
-            return None
-        try:
-            from jax.profiler import TraceAnnotation
-
-            ann = TraceAnnotation(self.name)
-            ann.__enter__()
-            return ann
-        except Exception:  # noqa: BLE001 — jax absent or profiler unusable
-            return None
+        self._annotation = _open_annotation(name) if annotate else None
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -267,6 +288,83 @@ def span(name: str, parent: Optional[TraceContext] = None,
     """``with span("train_round", round=7): ...`` — child of the current
     thread-local span (or of ``parent``), auto-ended on exit."""
     return Span(name, parent=parent, attrs=attrs)
+
+
+# -- the light tier: hot loops ------------------------------------------------
+
+_phase_children: Dict[str, Any] = {}
+_phase_generation = -1
+
+
+def _phase_child(name: str) -> Any:
+    """``fedml_span_seconds{name}``'s child, cached per name; the cache is
+    dropped when the registry has been reset since (test isolation), so it
+    never holds an unexported handle."""
+    global _phase_generation
+    gen = _metrics.REGISTRY.generation
+    if gen != _phase_generation:
+        _phase_children.clear()
+        _phase_generation = gen
+    child = _phase_children.get(name)
+    if child is None:
+        child = _phase_children[name] = _span_seconds().labels(name=name)
+    return child
+
+
+class Phase:
+    """One timed stretch of a hot loop.  Use `phase()`."""
+
+    __slots__ = ("name", "dur_s", "_t0", "_annotation")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        #: seconds on ``time.perf_counter``, once exited
+        self.dur_s = 0.0
+
+    def __enter__(self) -> "Phase":
+        self._annotation = _open_annotation(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur_s = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        _phase_child(self.name).observe(self.dur_s)
+        return False
+
+
+def phase(name: str) -> Phase:
+    """``with phase("fedml.serve.fetch") as ph: ...`` then ``ph.dur_s``.
+
+    For a loop that turns many times a second, where `span()` is too heavy:
+    no ids, no thread-local stack, no ``spans.jsonl`` record and no sink
+    budget.  It times itself, shows in a profiler trace under ``name`` while
+    a profiler session is open (the annotation is thread-scoped: enter and
+    exit on one thread), and observes ``fedml_span_seconds{name}``.  Use
+    `span()` for what belongs to a round's stitched trace, and this for
+    what the profiler or a histogram is to see.  ``name`` is a fixed string,
+    or has one suffix from a small set (a dispatch length, a prefill
+    bucket): readers match names exactly and every name is a label."""
+    return Phase(name)
+
+
+#: `note_iteration` speaks up for an iteration longer than this many
+#: seconds that is also this many times the one before it
+SLOW_ITERATION_S = 1.0
+SLOW_ITERATION_RATIO = 4.0
+
+
+def note_iteration(what: str, total_s: float, prev_s: Optional[float],
+                   parts: Sequence[Tuple[str, float]]) -> None:
+    """One warning line when an iteration of a hot loop stood still: which
+    of its phases held the time says whether the process waited for the
+    device or stalled in host code.  ``prev_s`` is the iteration before it
+    (None: there was none, nothing is logged)."""
+    if (prev_s is not None and total_s > SLOW_ITERATION_S
+            and total_s > SLOW_ITERATION_RATIO * prev_s):
+        logging.warning("%s took %.2f s: %s", what, total_s, " ".join(
+            f"{name} {secs:.2f}" for name, secs in parts))
 
 
 # -- trace summarization (the `fedml trace summarize` renderer) --------------

@@ -206,6 +206,9 @@ def flash_attention_residuals(q: jnp.ndarray, k: jnp.ndarray,
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         interpret=interpret,
+        # the kernel's name in a device trace; the blockwise backward is
+        # plain jnp and has none there
+        name="flash_fwd",
     )(qf, kf, vf)
     return (out.reshape(b, h, t, d), l.reshape(b, h, t),
             m.reshape(b, h, t))

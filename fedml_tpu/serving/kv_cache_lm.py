@@ -19,7 +19,7 @@ math; parity-tested token-for-token against the non-cached forward).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -372,14 +372,12 @@ def _exact_filter_keep(logits: jnp.ndarray, temps: jnp.ndarray,
     return keep, scaled, greedy
 
 
-@partial(jax.jit, static_argnames=("heads", "k", "exact_filters"),
-         donate_argnums=(1,))
-def decode_multi(params: Dict[str, Any],
-                 cache: List[Dict[str, jnp.ndarray]],
-                 prompt_buf: jnp.ndarray, prompt_n: jnp.ndarray,
-                 pos0: jnp.ndarray, temps: jnp.ndarray,
-                 top_k: jnp.ndarray, top_p: jnp.ndarray, rng: jax.Array,
-                 heads: int, k: int, exact_filters: bool = False):
+def _decode_multi(params: Dict[str, Any],
+                  cache: List[Dict[str, jnp.ndarray]],
+                  prompt_buf: jnp.ndarray, prompt_n: jnp.ndarray,
+                  pos0: jnp.ndarray, temps: jnp.ndarray,
+                  top_k: jnp.ndarray, top_p: jnp.ndarray, rng: jax.Array,
+                  heads: int, k: int, exact_filters: bool = False):
     """k tokens per row in ONE dispatch, sampling on-device — the
     autoregressive loop never returns to the host mid-chunk, so there is
     one dispatch and no per-token host sync (``k`` = the engine's
@@ -442,6 +440,41 @@ def decode_multi(params: Dict[str, Any],
             "v": jnp.where(hit[:, :, None, None], vf, layer["v"]),
         })
     return out_cache, emitted.T                            # [B, k]
+
+
+@lru_cache(maxsize=16)
+def _decode_multi_jit(k: int):
+    """`_decode_multi` jitted for one dispatch length under a name that
+    carries it: a device trace's ``XLA Modules`` line then tells
+    ``jit_decode_multi_k8`` from ``jit_decode_multi_k2`` (``k`` is static
+    either way, so this compiles nothing more than one jit would)."""
+    def named(params, cache, prompt_buf, prompt_n, pos0, temps, top_k,
+              top_p, rng, heads, exact_filters=False):
+        return _decode_multi(params, cache, prompt_buf, prompt_n, pos0,
+                             temps, top_k, top_p, rng, heads, k,
+                             exact_filters)
+
+    named.__name__ = named.__qualname__ = f"decode_multi_k{k}"
+    return jax.jit(named, static_argnames=("heads", "exact_filters"),
+                   donate_argnums=(1,))
+
+
+def decode_multi(params, cache, prompt_buf, prompt_n, pos0, temps, top_k,
+                 top_p, rng, heads: int, k: int,
+                 exact_filters: bool = False):
+    """`_decode_multi` through the jitted program of its dispatch length;
+    ``cache`` is donated."""
+    return _decode_multi_jit(k)(params, cache, prompt_buf, prompt_n, pos0,
+                                temps, top_k, top_p, rng, heads,
+                                exact_filters)
+
+
+# what ``jax.jit`` gave the one program this used to be: the plain function,
+# for a caller that jits it inside its own (`quantization`), and the
+# lowering, for the compile rehearsals
+decode_multi.__wrapped__ = _decode_multi
+decode_multi.lower = lambda *args, k, **kw: _decode_multi_jit(k).lower(
+    *args, **kw)
 
 
 class KVCacheLM:

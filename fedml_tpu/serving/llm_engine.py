@@ -22,11 +22,11 @@ import queue
 import threading
 import time
 from concurrent.futures import Future, TimeoutError as FuturesTimeoutError
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..core.mlops import flight_recorder, ledger, tracing
+from ..core.mlops import ledger, tracing
 from ..core.mlops import metrics as _metrics
 from ..core.mlops.lock_profiler import named_lock
 from .admission import ServingAdmissionController, ShedError
@@ -231,13 +231,14 @@ def _scatter_cache_row(cache, row_cache, slot):
     if _scatter_cache_row_jit is None:
         import jax
 
-        def _impl(cache, row_cache, slot):
+        def scatter_cache_row(cache, row_cache, slot):
             return [
                 {"k": layer["k"].at[slot].set(row["k"][0]),
                  "v": layer["v"].at[slot].set(row["v"][0])}
                 for layer, row in zip(cache, row_cache)]
 
-        _scatter_cache_row_jit = jax.jit(_impl, donate_argnums=(0,))
+        _scatter_cache_row_jit = jax.jit(scatter_cache_row,
+                                         donate_argnums=(0,))
     return _scatter_cache_row_jit(cache, row_cache, slot)
 
 
@@ -477,12 +478,8 @@ class BatchedLLMEngine:
                 logits = np.asarray(self._step(self.variables,
                                                jnp.asarray(x),
                                                jnp.asarray(pos)))
-            # histogram-only attribution: per-token flight-log writes
-            # would BE the overhead the recorder exists to catch
-            dt_step = time.monotonic() - t_step
-            flight_recorder.observe_phase(
-                "device_compute", dt_step, program="serving/decode_step")
-            self._metrics.note_decode(dt_step, self.active_count)
+            self._metrics.note_decode(time.monotonic() - t_step,
+                                      self.active_count)
             produced = 0
             for slot, req in enumerate(self._active):
                 if req is None:
@@ -681,6 +678,9 @@ class KVCacheLLMEngine:
         self._tokens_done = 0
         self._t_start = time.monotonic()
         self._metrics = _EngineMetrics("kv")
+        #: the length of the iteration (admit to end of stream) before the
+        #: one under way, for `tracing.note_iteration`
+        self._prev_iter_s: Optional[float] = None
         self._jax, self._jnp = jax, jnp
         self._worker = threading.Thread(target=self._loop, daemon=True,
                                         name="kv-llm-engine")
@@ -763,22 +763,42 @@ class KVCacheLLMEngine:
         return not self._stop.is_set() and self._worker.is_alive()
 
     # -- worker -------------------------------------------------------------
-    def _admit(self) -> bool:
+    def _admit(self) -> Tuple[bool, float]:
         """Admit pending requests into free slots; returns True iff any
         admitted request was ADMISSION-PREFILLED (its first token is one
-        short dispatch away — the turbo-dispatch precondition)."""
-        any_prefilled = False
+        short dispatch away — the turbo-dispatch precondition), and the
+        seconds the admissions took."""
+        any_prefilled, admit_s = False, 0.0
         for slot in range(self.max_batch):
             if self._active[slot] is None:
                 try:
                     req = self._pending.get_nowait()
                 except queue.Empty:
                     break
-                self._active[slot] = req
-                self._pos[slot] = 0
-                self._metrics.note_admit(req, slot)
-                any_prefilled |= self._prefill_admit(slot, req)
-        return any_prefilled
+                prefilled, secs = self._admit_one(slot, req)
+                any_prefilled |= prefilled
+                admit_s += secs
+        return any_prefilled, admit_s
+
+    def _admit_one(self, slot: int, req: "_Request") -> Tuple[bool, float]:
+        """One request just popped off ``_pending`` into ``slot``; True iff
+        it was admission-prefilled, and the seconds it took."""
+        with tracing.phase("fedml.serve.admit") as admit:
+            self._active[slot] = req
+            self._pos[slot] = 0
+            self._metrics.note_admit(req, slot)
+            prefilled = self._prefill_admit(slot, req)
+        return prefilled, admit.dur_s
+
+    def _end_iteration(self, admit_s: float, *parts: tracing.Phase) -> None:
+        """Close the iteration that its admissions and these phases made
+        up; say so when it stood still."""
+        total = admit_s + sum(ph.dur_s for ph in parts)
+        tracing.note_iteration(
+            "kv-engine: iteration", total, self._prev_iter_s,
+            [("admit", admit_s)] + [
+                (ph.name.split(".")[2], ph.dur_s) for ph in parts])
+        self._prev_iter_s = total
 
     def _retire(self, req: "_Request", outcome: str) -> None:
         self._metrics.note_retire(req, outcome)
@@ -809,19 +829,21 @@ class KVCacheLLMEngine:
         if tp is None:
             tp = self.lm.max_len
         jnp = self._jnp
-        toks = np.zeros((1, tp), np.int32)
-        toks[0, :p] = req.ids
-        t_prefill = time.monotonic()
+        # both are enqueues: neither waits for the device
+        with tracing.phase(f"fedml.serve.prefill.t{tp}") as prefill:
+            toks = np.zeros((1, tp), np.int32)
+            toks[0, :p] = req.ids
+            try:
+                row_cache, _ = self.lm.prefill(jnp.asarray(toks),
+                                               jnp.asarray([p], np.int32))
+            except Exception:  # noqa: BLE001 — no donation yet: safe fallback
+                logging.exception("kv-engine: admission prefill failed; "
+                                  "falling back to chunked prefill")
+                return False
         try:
-            row_cache, _ = self.lm.prefill(jnp.asarray(toks),
-                                           jnp.asarray([p], np.int32))
-        except Exception:  # noqa: BLE001 — no donation yet: safe fallback
-            logging.exception("kv-engine: admission prefill failed; "
-                              "falling back to chunked prefill")
-            return False
-        try:
-            self._cache = _scatter_cache_row(
-                self._cache, row_cache, jnp.asarray(slot, np.int32))
+            with tracing.phase("fedml.serve.scatter") as scatter:
+                self._cache = _scatter_cache_row(
+                    self._cache, row_cache, jnp.asarray(slot, np.int32))
         except Exception:  # noqa: BLE001
             # the scatter DONATES self._cache; an execution-time failure
             # (e.g. OOM) may have consumed it.  Rebuild an empty cache and
@@ -838,7 +860,7 @@ class KVCacheLLMEngine:
                 self._pos[:] = 0
             return False
         self._pos[slot] = p - 1
-        self._metrics.note_prefill(req, time.monotonic() - t_prefill)
+        self._metrics.note_prefill(req, prefill.dur_s + scatter.dur_s)
         return True
 
     #: admission-turbo dispatch length: the FIRST dispatch after an
@@ -853,16 +875,13 @@ class KVCacheLLMEngine:
     def _loop(self) -> None:
         jnp = self._jnp
         while not self._stop.is_set():
-            turbo = self._admit()
+            turbo, admit_s = self._admit()
             if self.active_count == 0:
                 try:
                     req = self._pending.get(timeout=0.5)
                 except queue.Empty:
                     continue
-                self._active[0] = req
-                self._pos[0] = 0
-                self._metrics.note_admit(req, 0)
-                turbo = self._prefill_admit(0, req)
+                turbo, admit_s = self._admit_one(0, req)
             self._metrics.queue.set(self._pending.qsize())
             self._metrics.active.set(self.active_count)
             self._metrics.occupancy.set(self.active_count / self.max_batch)
@@ -877,7 +896,7 @@ class KVCacheLLMEngine:
             if turbo and self.ADMIT_TURBO_K and self.ADMIT_TURBO_K < k:
                 k = self.ADMIT_TURBO_K
             if k > 1 and self._can_multi(k):
-                self._step_multi(k)
+                self._step_multi(k, admit_s)
                 continue
             # build this step's token vector: next prompt token (chunked
             # prefill) or the last sampled token
@@ -897,15 +916,15 @@ class KVCacheLLMEngine:
                     if self._pos[slot] < len(req.ids) else 0
             if self.active_count == 0:
                 continue
-            t_step = time.monotonic()
-            with self._metrics.step.time():
+            # the one-token fallback, whole: the dispatch, the wait for the
+            # device and the copy out
+            with tracing.phase("fedml.serve.decode1") as decode1:
                 self._cache, logits = self.lm.decode(
                     self._cache, jnp.asarray(tokens), jnp.asarray(self._pos))
                 logits = np.asarray(logits)
-            dt_step = time.monotonic() - t_step
-            flight_recorder.observe_phase(
-                "device_compute", dt_step, program="serving/decode_step")
-            self._metrics.note_decode(dt_step, self.active_count)
+            self._metrics.step.observe(decode1.dur_s)
+            self._metrics.note_decode(decode1.dur_s, self.active_count)
+            self._end_iteration(admit_s, decode1)
             produced = 0
             for slot, req in enumerate(self._active):
                 if req is None:
@@ -974,54 +993,62 @@ class KVCacheLLMEngine:
                 return False
         return True
 
-    def _step_multi(self, k: int) -> None:
+    def _step_multi(self, k: int, admit_s: float) -> None:
         import jax
+
+        from .kv_cache_lm import FILTER_CAP
 
         jnp = self._jnp
         b = self.max_batch
-        prompt_buf = np.zeros((b, k), np.int32)
-        prompt_n = np.ones((b,), np.int32)
-        temps = np.zeros((b,), np.float32)
-        top_k = np.zeros((b,), np.int32)
-        top_p = np.ones((b,), np.float32)
-        for slot, req in enumerate(self._active):
-            if req is None:
-                continue
-            pos = int(self._pos[slot])
-            upcoming = req.ids[pos:pos + k]
-            if not upcoming:           # mid-generation: feed last sample
-                upcoming = [req.ids[-1]]
-            prompt_buf[slot, :len(upcoming)] = upcoming
-            prompt_n[slot] = len(upcoming)
-            temps[slot] = req.temperature
-            top_k[slot] = req.top_k
-            top_p[slot] = req.top_p
-        self._rng_key, sub = jax.random.split(self._rng_key)
-        t_dispatch = time.monotonic()
-        # exact-filter dispatch (VERDICT r4 item 7): on a big vocab any
-        # filtered row routes the dispatch through the full-vocab
-        # bisection sampler — it is EXACT for every top_k/top_p (no
-        # 128-candidate truncation) and measured FASTER than the capped
-        # path at GPT-2 geometry (331 vs 373 ms/dispatch, bs128 k16,
-        # vocab 50257 on v5e: the bisection's ~60 compare+reduce passes
-        # cost less than one 50k-wide lax.top_k per token).  Unfiltered
-        # batches keep the plain path.  The flag is static per jit — at
-        # most two compiled variants.
-        from .kv_cache_lm import FILTER_CAP
-
-        exact = bool(self.lm.vocab > FILTER_CAP and np.any(
-            (temps > 0) & ((top_k > 0) | (top_p < 1.0))))
-        self._cache, emitted = self.lm.decode_multi(
-            self._cache, jnp.asarray(prompt_buf), jnp.asarray(prompt_n),
-            jnp.asarray(self._pos), jnp.asarray(temps),
-            jnp.asarray(top_k), jnp.asarray(top_p), sub, k,
-            exact_filters=exact)
-        emitted = np.asarray(emitted)
-        dt_dispatch = time.monotonic() - t_dispatch
+        with tracing.phase("fedml.serve.build") as build:
+            prompt_buf = np.zeros((b, k), np.int32)
+            prompt_n = np.ones((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            top_k = np.zeros((b,), np.int32)
+            top_p = np.ones((b,), np.float32)
+            for slot, req in enumerate(self._active):
+                if req is None:
+                    continue
+                pos = int(self._pos[slot])
+                upcoming = req.ids[pos:pos + k]
+                if not upcoming:       # mid-generation: feed last sample
+                    upcoming = [req.ids[-1]]
+                prompt_buf[slot, :len(upcoming)] = upcoming
+                prompt_n[slot] = len(upcoming)
+                temps[slot] = req.temperature
+                top_k[slot] = req.top_k
+                top_p[slot] = req.top_p
+            self._rng_key, sub = jax.random.split(self._rng_key)
+            # exact-filter dispatch (VERDICT r4 item 7): on a big vocab any
+            # filtered row routes the dispatch through the full-vocab
+            # bisection sampler — it is EXACT for every top_k/top_p (no
+            # 128-candidate truncation) and measured FASTER than the capped
+            # path at GPT-2 geometry (331 vs 373 ms/dispatch, bs128 k16,
+            # vocab 50257 on v5e: the bisection's ~60 compare+reduce passes
+            # cost less than one 50k-wide lax.top_k per token).  Unfiltered
+            # batches keep the plain path.  The flag is static per jit — at
+            # most two compiled variants.
+            exact = bool(self.lm.vocab > FILTER_CAP and np.any(
+                (temps > 0) & ((top_k > 0) | (top_p < 1.0))))
+            operands = (jnp.asarray(prompt_buf), jnp.asarray(prompt_n),
+                        jnp.asarray(self._pos), jnp.asarray(temps),
+                        jnp.asarray(top_k), jnp.asarray(top_p))
+        # an enqueue: the wait for the device is the fetch
+        with tracing.phase(f"fedml.serve.dispatch.k{k}") as dispatch:
+            self._cache, emitted = self.lm.decode_multi(
+                self._cache, *operands, sub, k, exact_filters=exact)
+        with tracing.phase("fedml.serve.fetch") as fetch:
+            emitted = np.asarray(emitted)
+        dt_dispatch = dispatch.dur_s + fetch.dur_s
         self._metrics.step.observe(dt_dispatch)
-        flight_recorder.observe_phase(
-            "device_compute", dt_dispatch, program="serving/decode_step")
         self._metrics.note_decode(dt_dispatch, self.active_count)
+        with tracing.phase("fedml.serve.stream") as stream:
+            self._stream(emitted, k)
+        self._end_iteration(admit_s, build, dispatch, fetch, stream)
+
+    def _stream(self, emitted: np.ndarray, k: int) -> None:
+        """Hand a dispatch's tokens to their requests (the clients'
+        ``on_token`` callbacks run here) and retire what finished."""
         produced = 0
         for slot, req in enumerate(self._active):
             if req is None:

@@ -18,7 +18,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import logging
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -26,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ...core.mlops import tracing
 from ...ml.engine.model_bundle import ModelBundle, masked_loss
 from .lora import _path_str, apply_lora, count_trainable, init_lora
 
@@ -144,6 +144,9 @@ class LLMTrainer:
         self._train_epoch = jax.jit(
             self._build_epoch_fn(),
             donate_argnums=(0, 1) if config.use_lora else ())
+        #: the length of the `train` call before this one, for
+        #: `tracing.note_iteration`
+        self._prev_train_s: Optional[float] = None
 
     def _trainables(self):
         return self.lora if self.cfg.use_lora else self.variables["params"]
@@ -162,8 +165,9 @@ class LLMTrainer:
                                      rng=rng)
             return masked_loss("lm", logits, batch["y"], batch["mask"])
 
-        def epoch(trainable, opt_state, base_params, model_state, batches,
-                  rng):
+        # the name is the program's in a device trace: ``jit_sft_epoch``
+        def sft_epoch(trainable, opt_state, base_params, model_state,
+                      batches, rng):
             nb = batches["x"].shape[0]
             if use_lora and mesh is not None:
                 # base params are FROZEN across the epoch scan, but the
@@ -204,15 +208,35 @@ class LLMTrainer:
                 step, (trainable, opt_state, rng), jnp.arange(nb))
             return trainable, opt_state, jnp.mean(losses)
 
-        return epoch
+        return sft_epoch
 
     def train(self, token_ids: np.ndarray) -> Dict[str, float]:
+        phases: List[tracing.Phase] = []
+        with tracing.phase("fedml.sft.train") as call:
+            out = self._train(token_ids, phases)
+        tracing.note_iteration(
+            "llm-trainer: train()", call.dur_s, self._prev_train_s,
+            [(ph.name.split(".")[2], ph.dur_s) for ph in phases])
+        self._prev_train_s = call.dur_s
+        return out
+
+    def _train(self, token_ids: np.ndarray,
+               phases: List[tracing.Phase]) -> Dict[str, float]:
+        """`train` proper; its phases are appended to ``phases`` in the
+        order they open."""
         cfg = self.cfg
-        batches_np = pack_sequences(np.asarray(token_ids), cfg.seq_len,
-                                    cfg.batch_size)
-        batches = jax.tree_util.tree_map(jnp.asarray, batches_np)
+
+        def phase(name: str) -> tracing.Phase:
+            phases.append(tracing.phase(name))
+            return phases[-1]
+
+        with phase("fedml.sft.pack"):
+            batches_np = pack_sequences(np.asarray(token_ids), cfg.seq_len,
+                                        cfg.batch_size)
+            batches = jax.tree_util.tree_map(jnp.asarray, batches_np)
         trainable = self._trainables()
-        opt_state = self.tx.init(trainable)
+        with phase("fedml.sft.opt_init"):
+            opt_state = self.tx.init(trainable)
         base_params = self.variables["params"]
         model_state = {k: v for k, v in self.variables.items()
                        if k != "params"}
@@ -224,14 +248,15 @@ class LLMTrainer:
             # batch dim (axis 1 of [nb, B, T]) shards over `data`; base
             # params shard per strategy (fsdp = ZeRO-style), LoRA/trainable
             # and optimizer state stay replicated (they're small)
-            batches = jax.device_put(
-                batches, NamedSharding(self.mesh, P(None, "data")))
-            base_params = jax.device_put(
-                base_params, make_param_shardings(base_params, self.mesh,
-                                                  self.cfg.strategy))
-            repl = NamedSharding(self.mesh, P())
-            trainable = jax.device_put(trainable, repl)
-            opt_state = jax.device_put(opt_state, repl)
+            with phase("fedml.sft.place"):
+                batches = jax.device_put(
+                    batches, NamedSharding(self.mesh, P(None, "data")))
+                base_params = jax.device_put(
+                    base_params, make_param_shardings(
+                        base_params, self.mesh, self.cfg.strategy))
+                repl = NamedSharding(self.mesh, P())
+                trainable = jax.device_put(trainable, repl)
+                opt_state = jax.device_put(opt_state, repl)
         rng = jax.random.PRNGKey(1)
         history = []
         ckpt = None
@@ -242,9 +267,9 @@ class LLMTrainer:
         ctx = self.mesh if self.mesh is not None else \
             contextlib.nullcontext()
         for ep in range(cfg.epochs):
-            t0 = time.time()
             rng, sub = jax.random.split(rng)
-            with ctx:
+            # an enqueue: the wait for the program is the loss fetch
+            with phase("fedml.sft.epoch") as epoch, ctx:
                 trainable, opt_state, loss = self._train_epoch(
                     trainable, opt_state, base_params, model_state, batches,
                     sub)
@@ -256,12 +281,14 @@ class LLMTrainer:
                 self.lora = trainable
             # one deliberate sync per EPOCH (not per step): the scalar gates
             # logging/checkpointing, and the scan above has already retired
-            loss_host = float(loss)  # fedml: noqa[JAX003] — epoch boundary
+            with phase("fedml.sft.loss_fetch") as fetch:
+                loss_host = float(loss)  # fedml: noqa[JAX003] — epoch boundary
             history.append(loss_host)
             logging.info("llm epoch %d: loss %.4f (%.1fs)", ep, loss_host,
-                         time.time() - t0)
+                         epoch.dur_s + fetch.dur_s)
             if ckpt is not None:
-                ckpt.save(ep, {"round_idx": ep, "trainable": trainable})
+                with phase("fedml.sft.checkpoint"):
+                    ckpt.save(ep, {"round_idx": ep, "trainable": trainable})
         if cfg.use_lora:
             self.lora = trainable
         else:
