@@ -26,18 +26,29 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.pallas_kv_store import store_positions
 from ..parallel.seq_parallel import _ln, init_lm_params, lm_forward
 
 
 def init_cache(params: Dict[str, Any], batch: int, max_len: int,
                heads: int) -> List[Dict[str, jnp.ndarray]]:
+    """The cache: per layer a K and a V of ``[B, H, Dh, T]``, positions
+    last.  That is the order a v5e gives the array in memory whatever order
+    is asked (a last dimension of Dh = 64 would be padded to the 128 lanes,
+    so the chip's layout puts the 1024 positions there); saying it in the
+    shape lets `ops.pallas_kv_store` address a row's block of positions as
+    whole tiles, and the scan's attention costs what it did (64.3 -> 64.1 ms
+    of an 8-token dispatch; `decode_step` and the quantized path were not
+    timed on the chip: PERF.md, PR 25).
+    `prefill`, `decode_step`, `decode_multi` and the engine's
+    `_scatter_cache_row` all take and return this one definition."""
     dim = params["embed"].shape[1]
     dh = dim // heads
     dt = params["embed"].dtype        # bf16 params -> bf16 cache (an fp32
     # zero cache would silently promote every where-update to fp32,
     # doubling decode HBM traffic)
-    return [{"k": jnp.zeros((batch, max_len, heads, dh), dt),
-             "v": jnp.zeros((batch, max_len, heads, dh), dt)}
+    return [{"k": jnp.zeros((batch, heads, dh, max_len), dt),
+             "v": jnp.zeros((batch, heads, dh, max_len), dt)}
             for _ in params["blocks"]]
 
 
@@ -102,11 +113,9 @@ def prefill(params: Dict[str, Any], tokens: jnp.ndarray,
         q = heads_of(blk["wq"], "bq").transpose(0, 2, 1, 3)
         k = heads_of(blk["wk"], "bk")
         v = heads_of(blk["wv"], "bv")
-        if max_len and max_len > t:
-            pad = ((0, 0), (0, max_len - t), (0, 0), (0, 0))
-            cache.append({"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
-        else:
-            cache.append({"k": k, "v": v})
+        pad = ((0, 0), (0, 0), (0, 0), (0, max(max_len - t, 0)))
+        cache.append({"k": jnp.pad(k.transpose(0, 2, 3, 1), pad),
+                      "v": jnp.pad(v.transpose(0, 2, 3, 1), pad)})
         kt = k.transpose(0, 2, 1, 3)
         vt = v.transpose(0, 2, 1, 3)
         s = jnp.einsum("bhqd,bhkd->bhqk", q, kt) / np.sqrt(dh)
@@ -134,32 +143,36 @@ def _decode_core(params: Dict[str, Any],
     """One token per row (traced body shared by the single- and multi-token
     dispatch entry points).
 
-    The cache update is a broadcast-compare SELECT, not a scatter: a
-    per-row ``.at[rows, pos].set`` lowers to an XLA scatter that measured
-    2.9x slower than the select on v5e (21.9 vs 7.5 ms/step at B=32
-    T=1024; a per-row dynamic_update_slice chain was just as slow —
-    benchmarks/BENCH_NOTES.md round 4)."""
+    The cache update is a broadcast-compare SELECT over the whole cache,
+    every token: the one-token fallback's own cost (the engine takes this
+    path only when a row is within a dispatch of the cache's end, and for
+    ``tokens_per_dispatch=1``).  Per token it beat what XLA offers instead:
+    a per-row ``.at[rows, pos].set`` lowers to a scatter that measured 2.9x
+    slower on v5e (21.9 vs 7.5 ms/step at B=32 T=1024; a per-row
+    dynamic_update_slice chain was just as slow: benchmarks/BENCH_NOTES.md
+    round 4), and once a dispatch that scatter still costs 41 ms at GPT-2
+    large's 72 arrays (PERF.md, PR 25).  `decode_multi` stores through
+    `ops.pallas_kv_store` instead, which is what this path would use too
+    if it came to matter."""
     b = token.shape[0]
     dim = params["embed"].shape[1]
     dh = dim // heads
-    t_cache = cache[0]["k"].shape[1]
+    t_cache = cache[0]["k"].shape[-1]
     h = params["embed"][token] + params["pos"][pos]       # [B, D]
     new_cache = []
     iota = jnp.arange(t_cache)
-    hit = (iota[None, :] == pos[:, None])                 # [B, T]
+    hit = (iota[None, :] == pos[:, None])[:, None, None]  # [B, 1, 1, T]
     for blk, layer in zip(params["blocks"], cache):
         y = _ln(h, blk["ln1"])
         q, k_new, v_new = _qkv(y, blk, b, heads, dh)
-        k_cache = jnp.where(hit[:, :, None, None], k_new[:, None],
-                            layer["k"])
-        v_cache = jnp.where(hit[:, :, None, None], v_new[:, None],
-                            layer["v"])
+        k_cache = jnp.where(hit, k_new[..., None], layer["k"])
+        v_cache = jnp.where(hit, v_new[..., None], layer["v"])
         new_cache.append({"k": k_cache, "v": v_cache})
-        s = jnp.einsum("bhd,bthd->bht", q, k_cache) / np.sqrt(dh)
+        s = jnp.einsum("bhd,bhdt->bht", q, k_cache) / np.sqrt(dh)
         valid = (iota[None] <= pos[:, None])              # [B, T]
         s = jnp.where(valid[:, None, :], s, -1e30)
         w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bht,bthd->bhd", w, v_cache)
+        o = jnp.einsum("bht,bhdt->bhd", w, v_cache)
         h = _post_attention(h, o, blk, b, dim)
     h = _ln(h, params["ln_f"])
     return new_cache, _head(h, params)                    # [B, V]
@@ -181,7 +194,7 @@ def _decode_core_chunked(params: Dict[str, Any],
     b = token.shape[0]
     dim = params["embed"].shape[1]
     dh = dim // heads
-    t_cache = cache[0]["k"].shape[1]
+    t_cache = cache[0]["k"].shape[-1]
     kcap = kc.shape[2]
     pos = pos0 + j
     h = params["embed"][token] + params["pos"][pos]       # [B, D]
@@ -198,13 +211,13 @@ def _decode_core_chunked(params: Dict[str, Any],
             kc, k_new[None, :, None].astype(kc.dtype), (li, 0, j, 0, 0))
         vc = jax.lax.dynamic_update_slice(
             vc, v_new[None, :, None].astype(vc.dtype), (li, 0, j, 0, 0))
-        s_full = jnp.einsum("bhd,bthd->bht", q, layer["k"]) / np.sqrt(dh)
+        s_full = jnp.einsum("bhd,bhdt->bht", q, layer["k"]) / np.sqrt(dh)
         s_full = jnp.where(valid_full[:, None, :], s_full, -1e30)
         s_chunk = jnp.einsum("bhd,bkhd->bhk", q, kc[li]) / np.sqrt(dh)
         s_chunk = jnp.where(valid_chunk[None, None, :], s_chunk, -1e30)
         s = jnp.concatenate([s_full, s_chunk], axis=-1)   # [B, H, T+K]
         w = jax.nn.softmax(s, axis=-1)
-        o = (jnp.einsum("bht,bthd->bhd", w[..., :t_cache], layer["v"])
+        o = (jnp.einsum("bht,bhdt->bhd", w[..., :t_cache], layer["v"])
              + jnp.einsum("bhk,bkhd->bhd", w[..., t_cache:], vc[li]))
         h = _post_attention(h, o, blk, b, dim)
     h = _ln(h, params["ln_f"])
@@ -390,10 +403,26 @@ def _decode_multi(params: Dict[str, Any],
     Returns (cache, emitted [B, k]) where emitted[i, j] is the model output
     after feeding inner token j — new tokens from j = prompt_n[i]-1 on.
 
-    The inner scan never rewrites the [B, T] cache: new K/V land in a
-    [L, B, k] chunk buffer (`_decode_core_chunked`) and are written back
-    ONCE after the scan — without this the per-token full-cache rewrite
-    made the step ~3x slower than its HBM read floor (BENCH_NOTES r4)."""
+    The inner scan never writes the [B, T] cache: new K/V land in a
+    [L, B, k] chunk buffer (`_decode_core_chunked`; a full-cache rewrite
+    every token made the step ~3x slower than its HBM read floor,
+    BENCH_NOTES r4), and after the scan `ops.pallas_kv_store` stores each
+    row's k positions into the donated cache IN PLACE, in this same
+    program: the tiles that hold positions ``pos0[i] .. pos0[i] + k - 1``
+    of each row are read and written, nothing else of the cache's size is
+    (held by tests/test_chip_compile.py).  Until PR 25 the write-back
+    gathered the chunk to the cache's full shape and selected, which XLA
+    turned into some nine passes over all 6 GB of GPT-2 large's 32-slot
+    cache: 105 ms of every dispatch, whatever its length; the store is 4.6
+    ms whatever k (my chip runs, PR 25, device time: a dispatch of 2 tokens
+    130.9 -> 27.3 ms, of 8 tokens 198.6 -> 94.1 ms, tokens and cache bit
+    for bit the same).
+
+    A row with ``pos0 + k > T``: its positions below T are stored, those at
+    or beyond T are dropped, none is moved to fit (what the select did;
+    tests/test_decode_writeback.py).  The engine never sends an active row
+    like that (`_can_multi`); an idle slot's row may be, and is never read
+    before an admission writes it again."""
     b = prompt_buf.shape[0]
     nl = len(params["blocks"])
     dim = params["embed"].shape[1]
@@ -424,21 +453,14 @@ def _decode_multi(params: Dict[str, Any],
     carry0 = (kc0, vc0, prompt_buf[:, 0], rng)
     (kc, vc, _, _), emitted = jax.lax.scan(step, carry0, jnp.arange(k))
 
-    # write the chunk back into the persistent cache: full-cache position
-    # iota maps to chunk slot iota - pos0[i] for iota in [pos0, pos0+k)
-    t_cache = cache[0]["k"].shape[1]
-    iota = jnp.arange(t_cache)
-    hit = ((iota[None] >= pos0[:, None])
-           & (iota[None] < pos0[:, None] + k))            # [B, T]
-    slot = jnp.clip(iota[None] - pos0[:, None], 0, k - 1)  # [B, T]
+    # chunk slot j of row i is position pos0[i] + j of the cache
     out_cache = []
     for li, layer in enumerate(cache):
-        kf = jnp.take_along_axis(kc[li], slot[:, :, None, None], axis=1)
-        vf = jnp.take_along_axis(vc[li], slot[:, :, None, None], axis=1)
-        out_cache.append({
-            "k": jnp.where(hit[:, :, None, None], kf, layer["k"]),
-            "v": jnp.where(hit[:, :, None, None], vf, layer["v"]),
-        })
+        new_k, new_v = store_positions(
+            [layer["k"], layer["v"]],
+            [kc[li].transpose(0, 2, 3, 1), vc[li].transpose(0, 2, 3, 1)],
+            pos0)
+        out_cache.append({"k": new_k, "v": new_v})
     return out_cache, emitted.T                            # [B, k]
 
 

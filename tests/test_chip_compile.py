@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compile for the real chip, at real widths.
+"""The main path's Pallas kernels compile for the real chip, at real widths,
+and `decode_multi` around its kernel keeps the cache in place.
 
 No chip is attached here: the TPU compiler that is installed compiles for a
 v5e that is described, not present (on-chip-measurement guide, section 2,
@@ -147,6 +148,66 @@ def test_int8_dequantize_compiles_for_v5e(one_chip, monkeypatch):
     _assert_kernel(_compile_text(
         fn, one_chip, ((RESNET56_FLAT,), jnp.int8),
         ((rows,), jnp.float32)))
+
+
+@pytest.mark.parametrize("k", [2, 8])
+def test_decode_multi_stores_in_place_for_v5e(one_chip, monkeypatch, k):
+    """`decode_multi` at the serving cell's widths (GPT-2 large, 32 slots,
+    1024 positions; one layer): the k new positions go into the donated
+    cache in place.  The output aliases the cache and no temporary comes
+    near a K/V array's size (84 MB; with the select write-back this program
+    held 271 MB of them a layer), so an edit that brings a whole-cache
+    temporary back fails here and not in a cell."""
+    from fedml_tpu.ops import pallas_kv_store
+    from fedml_tpu.parallel.seq_parallel import init_lm_params
+    from fedml_tpu.serving import kv_cache_lm
+
+    monkeypatch.setattr(pallas_kv_store, "_on_tpu", lambda: True)
+    slots, heads, dim, positions, vocab = 32, 20, 1280, 1024, 50257
+    spec = functools.partial(jax.tree_util.tree_map, lambda a: (
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)))
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        init_lm_params(jax.random.PRNGKey(0), vocab, dim=dim, layers=1,
+                       heads=heads, max_len=positions)))
+    cache = jax.eval_shape(functools.partial(
+        kv_cache_lm.init_cache, batch=slots, max_len=positions, heads=heads),
+        params)
+    vec = lambda dt, *s: jax.ShapeDtypeStruct((slots, *s), dt,
+                                              sharding=one_chip)
+    compiled = kv_cache_lm.decode_multi.lower(
+        spec(params), spec(cache), vec(jnp.int32, k), vec(jnp.int32),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+        spec(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+        heads=heads, k=k).compile()
+    _assert_kernel(compiled.as_text())
+    array_bytes = slots * positions * dim * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 2 * array_bytes, "cache not updated in place"
+    assert m.temp_size_in_bytes < array_bytes // 4, m.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("shape,k", [
+    ((32, 20, 64, 1000), 8),      # GPT-2 large, `lm_max_len` 1000
+    ((8, 12, 64, 2000), 8),       # GPT-2 small, a longer cache
+    ((32, 20, 64, 1000), 130),    # a window over three blocks, the last ragged
+    ((4, 12, 64, 40), 2),         # shorter than one lane tile
+], ids=["large-1000", "small-2000", "large-1000-k130", "small-40"])
+def test_kv_store_compiles_at_ragged_lengths_for_v5e(one_chip, monkeypatch,
+                                                     shape, k):
+    """`store_positions` serves any cache length (`model_hub` hands the
+    user's `lm_max_len` through): 128-position blocks whose last is ragged,
+    never a whole row, which at these sizes does not fit the chip's VMEM."""
+    from fedml_tpu.ops import pallas_kv_store
+
+    monkeypatch.setattr(pallas_kv_store, "_on_tpu", lambda: True)
+    array = (shape, jnp.bfloat16)
+    chunk = (shape[:3] + (k,), jnp.bfloat16)
+    text = _compile_text(
+        lambda a, b, c, d, p: pallas_kv_store.store_positions(
+            [a, b], [c, d], p),
+        one_chip, array, array, chunk, chunk, ((shape[0],), jnp.int32))
+    _assert_kernel(text)
 
 
 def test_resnet56_constants_match_the_model():

@@ -186,7 +186,7 @@ def test_kv_cache_decode_matches_full_forward():
     # padding-token K/V between their length and t0 — harmless: decode
     # overwrites each position BEFORE the pos-mask ever admits it.
     cache, last = lm.prefill(jnp.asarray(toks), jnp.asarray(length))
-    assert cache[0]["k"].shape[1] == lm.max_len
+    assert cache[0]["k"].shape[-1] == lm.max_len       # [B, H, Dh, T]
 
     out = [list(p) for p in prompts]
     pos = length.copy()
@@ -612,7 +612,7 @@ def test_prefill_cache_supports_decode_past_prompt_width():
                           layers=2, heads=4, max_len=32)
     prompt = list(np.random.RandomState(4).randint(0, 50, size=5))
     cache, last = lm.prefill(jnp.asarray([prompt]), jnp.asarray([5]))
-    assert cache[0]["k"].shape[1] == lm.max_len
+    assert cache[0]["k"].shape[-1] == lm.max_len       # [B, H, Dh, T]
     ids = list(prompt)
     nxt = int(jnp.argmax(last[0]))
     ids.append(nxt)
