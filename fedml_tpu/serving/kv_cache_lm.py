@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.pallas_decode_attention import MASKED, decode_attention
 from ..ops.pallas_kv_store import store_positions
 from ..parallel.seq_parallel import _ln, init_lm_params, lm_forward
 
@@ -36,10 +37,12 @@ def init_cache(params: Dict[str, Any], batch: int, max_len: int,
     last.  That is the order a v5e gives the array in memory whatever order
     is asked (a last dimension of Dh = 64 would be padded to the 128 lanes,
     so the chip's layout puts the 1024 positions there); saying it in the
-    shape lets `ops.pallas_kv_store` address a row's block of positions as
-    whole tiles, and the scan's attention costs what it did (64.3 -> 64.1 ms
-    of an 8-token dispatch; `decode_step` and the quantized path were not
-    timed on the chip: PERF.md, PR 25).
+    shape lets the two kernels of `decode_multi` address a row's block of
+    positions as whole tiles: `ops.pallas_kv_store` writes the blocks a
+    dispatch's new positions fall in, `ops.pallas_decode_attention` reads
+    the blocks below a row's length and no others (PERF.md, PR 25 and 28).
+    `decode_step`, the one-token fallback, still reads and selects over the
+    whole length; it and the quantized path were not timed on the chip.
     `prefill`, `decode_step`, `decode_multi` and the engine's
     `_scatter_cache_row` all take and return this one definition."""
     dim = params["embed"].shape[1]
@@ -178,6 +181,30 @@ def _decode_core(params: Dict[str, Any],
     return new_cache, _head(h, params)                    # [B, V]
 
 
+def _attend_cache_and_chunk(q: jnp.ndarray, layer: Dict[str, jnp.ndarray],
+                            kc: jnp.ndarray, vc: jnp.ndarray,
+                            pos0: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
+    """One layer's attention of ``q`` [B, H, Dh] over row i's cache positions
+    below ``pos0[i]`` and the chunk's slots up to ``j`` (``kc``/``vc``
+    [B, K, H, Dh]): the softmax over all of them, float32 [B, H, Dh].  The
+    cache's half comes from `ops.pallas_decode_attention` unnormalised, with
+    its scores' maximum and sum; the chunk's few positions are scored here
+    and the two halves merged by those statistics (the flash-decoding
+    merge)."""
+    dh = q.shape[-1]
+    o_full, m_full, l_full = decode_attention(
+        q, layer["k"], layer["v"], pos0, 1.0 / np.sqrt(dh))
+    s_chunk = jnp.einsum("bhd,bkhd->bhk", q, kc) / np.sqrt(dh)
+    s_chunk = jnp.where((jnp.arange(kc.shape[1]) <= j)[None, None, :],
+                        s_chunk, MASKED)
+    m = jnp.maximum(m_full, jnp.max(s_chunk, axis=-1))    # [B, H]
+    w_full = jnp.exp(m_full - m)
+    w_chunk = jnp.exp(s_chunk - m[..., None])             # 0 where masked
+    return (o_full * w_full[..., None]
+            + jnp.einsum("bhk,bkhd->bhd", w_chunk, vc)) / (
+                l_full * w_full + jnp.sum(w_chunk, axis=-1))[..., None]
+
+
 def _decode_core_chunked(params: Dict[str, Any],
                          cache: List[Dict[str, jnp.ndarray]],
                          kc: jnp.ndarray, vc: jnp.ndarray,
@@ -189,19 +216,14 @@ def _decode_core_chunked(params: Dict[str, Any],
     step ``j``) — the flash-decoding split that lets `decode_multi` avoid
     rewriting the [B, T] cache every token.  Row i's absolute position is
     ``pos0[i] + j``; full-cache entries are valid strictly below ``pos0``
-    (everything newer lives in the chunk buffer).  Returns the updated
-    chunk buffers and the logits."""
+    (everything newer lives in the chunk buffer), and of the cache only the
+    blocks of positions below ``pos0[i]`` are read (`_attend_cache_and_chunk`).
+    Returns the updated chunk buffers and the logits."""
     b = token.shape[0]
     dim = params["embed"].shape[1]
     dh = dim // heads
-    t_cache = cache[0]["k"].shape[-1]
-    kcap = kc.shape[2]
     pos = pos0 + j
     h = params["embed"][token] + params["pos"][pos]       # [B, D]
-    iota_t = jnp.arange(t_cache)
-    iota_k = jnp.arange(kcap)
-    valid_full = (iota_t[None] < pos0[:, None])           # [B, T]
-    valid_chunk = (iota_k <= j)                           # [K]
     for li, (blk, layer) in enumerate(zip(params["blocks"], cache)):
         y = _ln(h, blk["ln1"])
         q, k_new, v_new = _qkv(y, blk, b, heads, dh)
@@ -211,14 +233,7 @@ def _decode_core_chunked(params: Dict[str, Any],
             kc, k_new[None, :, None].astype(kc.dtype), (li, 0, j, 0, 0))
         vc = jax.lax.dynamic_update_slice(
             vc, v_new[None, :, None].astype(vc.dtype), (li, 0, j, 0, 0))
-        s_full = jnp.einsum("bhd,bhdt->bht", q, layer["k"]) / np.sqrt(dh)
-        s_full = jnp.where(valid_full[:, None, :], s_full, -1e30)
-        s_chunk = jnp.einsum("bhd,bkhd->bhk", q, kc[li]) / np.sqrt(dh)
-        s_chunk = jnp.where(valid_chunk[None, None, :], s_chunk, -1e30)
-        s = jnp.concatenate([s_full, s_chunk], axis=-1)   # [B, H, T+K]
-        w = jax.nn.softmax(s, axis=-1)
-        o = (jnp.einsum("bht,bhdt->bhd", w[..., :t_cache], layer["v"])
-             + jnp.einsum("bhk,bkhd->bhd", w[..., t_cache:], vc[li]))
+        o = _attend_cache_and_chunk(q, layer, kc[li], vc[li], pos0, j)
         h = _post_attention(h, o, blk, b, dim)
     h = _ln(h, params["ln_f"])
     return kc, vc, _head(h, params)                       # [B, V]
@@ -406,23 +421,31 @@ def _decode_multi(params: Dict[str, Any],
     The inner scan never writes the [B, T] cache: new K/V land in a
     [L, B, k] chunk buffer (`_decode_core_chunked`; a full-cache rewrite
     every token made the step ~3x slower than its HBM read floor,
-    BENCH_NOTES r4), and after the scan `ops.pallas_kv_store` stores each
-    row's k positions into the donated cache IN PLACE, in this same
-    program: the tiles that hold positions ``pos0[i] .. pos0[i] + k - 1``
-    of each row are read and written, nothing else of the cache's size is
-    (held by tests/test_chip_compile.py).  Until PR 25 the write-back
-    gathered the chunk to the cache's full shape and selected, which XLA
-    turned into some nine passes over all 6 GB of GPT-2 large's 32-slot
-    cache: 105 ms of every dispatch, whatever its length; the store is 4.6
-    ms whatever k (my chip runs, PR 25, device time: a dispatch of 2 tokens
-    130.9 -> 27.3 ms, of 8 tokens 198.6 -> 94.1 ms, tokens and cache bit
-    for bit the same).
+    BENCH_NOTES r4), and each inner step reads of the cache what is alive:
+    `ops.pallas_decode_attention` visits, per row, the blocks of positions
+    below ``pos0[i]`` and merges them with the chunk by the softmax
+    statistics.  Until PR 28 two contractions ran over all T positions of
+    every row and masked afterwards: at GPT-2 large's 32 slots 6 GB read a
+    token step, at 90% of the HBM's rate, some 5% of it alive in the serving
+    cell (PERF.md, PR 28).  A row with ``pos0[i] == 0`` (the engine sends
+    that for a slot that holds no request) reads nothing.
+
+    After the scan `ops.pallas_kv_store` stores each row's k positions into
+    the donated cache IN PLACE, in this same program: the tiles that hold
+    positions ``pos0[i] .. pos0[i] + k - 1`` of each row are read and
+    written, nothing else of the cache's size is (held by
+    tests/test_chip_compile.py, as is the absence of a full-length score).
+    Until PR 25 the write-back gathered the chunk to the cache's full shape
+    and selected, which XLA turned into some nine passes over all 6 GB: 105
+    ms of every dispatch, whatever its length; the store is 4.6 ms whatever
+    k (my chip runs, PR 25, device time).
 
     A row with ``pos0 + k > T``: its positions below T are stored, those at
     or beyond T are dropped, none is moved to fit (what the select did;
     tests/test_decode_writeback.py).  The engine never sends an active row
-    like that (`_can_multi`); an idle slot's row may be, and is never read
-    before an admission writes it again."""
+    like that (`_can_multi`).  An idle slot comes with ``pos0 == 0``: its
+    positions 0 .. k-1 are written, and the row is never read before an
+    admission writes it again."""
     b = prompt_buf.shape[0]
     nl = len(params["blocks"])
     dim = params["embed"].shape[1]

@@ -36,6 +36,11 @@ from .admission import ServingAdmissionController, ShedError
 _rid_counter = itertools.count(1)
 
 
+#: positions of one row in a cache block, the unit the cache counters count in
+#: (a lane tile of the cache's ``[B, H, Dh, T]``)
+CACHE_BLOCK = 128
+
+
 class _EngineMetrics:
     """Per-engine cached label children — one label lookup at construction
     instead of one per decode step.  Metric objects resolve get-or-create
@@ -111,6 +116,18 @@ class _EngineMetrics:
             "KV-cache positions in use across active slots, sampled on "
             "the engine loop", labels=("engine",)).labels(
                 engine=engine_label)
+        # what `decode_multi`'s attention reads of the cache, in blocks of
+        # CACHE_BLOCK positions a row, summed over token steps
+        self.cache_blocks_live = _metrics.counter(
+            "fedml_llm_cache_blocks_live_total",
+            "Cache blocks (128 positions of one row) holding a position a "
+            "query may attend to, summed over decode_multi's token steps",
+            labels=("engine",)).labels(engine=engine_label)
+        self.cache_blocks = _metrics.counter(
+            "fedml_llm_cache_blocks_total",
+            "Cache blocks there are (max_batch x ceil(T / 128)), summed "
+            "over decode_multi's token steps",
+            labels=("engine",)).labels(engine=engine_label)
         self._decode_lock = named_lock("_EngineMetrics._decode_lock")
         self._decode_steps = 0
         self._decode_secs = 0.0
@@ -1003,13 +1020,16 @@ class KVCacheLLMEngine:
         with tracing.phase("fedml.serve.build") as build:
             prompt_buf = np.zeros((b, k), np.int32)
             prompt_n = np.ones((b,), np.int32)
+            # a slot that holds no request is sent with length 0: the
+            # attention then reads nothing of its row
+            pos0 = np.zeros((b,), np.int32)
             temps = np.zeros((b,), np.float32)
             top_k = np.zeros((b,), np.int32)
             top_p = np.ones((b,), np.float32)
             for slot, req in enumerate(self._active):
                 if req is None:
                     continue
-                pos = int(self._pos[slot])
+                pos = pos0[slot] = int(self._pos[slot])
                 upcoming = req.ids[pos:pos + k]
                 if not upcoming:       # mid-generation: feed last sample
                     upcoming = [req.ids[-1]]
@@ -1031,8 +1051,12 @@ class KVCacheLLMEngine:
             exact = bool(self.lm.vocab > FILTER_CAP and np.any(
                 (temps > 0) & ((top_k > 0) | (top_p < 1.0))))
             operands = (jnp.asarray(prompt_buf), jnp.asarray(prompt_n),
-                        jnp.asarray(self._pos), jnp.asarray(temps),
+                        jnp.asarray(pos0), jnp.asarray(temps),
                         jnp.asarray(top_k), jnp.asarray(top_p))
+            self._metrics.cache_blocks_live.inc(
+                k * int(np.sum(-(-pos0 // CACHE_BLOCK))))
+            self._metrics.cache_blocks.inc(
+                k * b * -(-self.lm.max_len // CACHE_BLOCK))
         # an enqueue: the wait for the device is the fetch
         with tracing.phase(f"fedml.serve.dispatch.k{k}") as dispatch:
             self._cache, emitted = self.lm.decode_multi(

@@ -1,5 +1,6 @@
 """The main path's Pallas kernels compile for the real chip, at real widths,
-and `decode_multi` around its kernel keeps the cache in place.
+and `decode_multi` around its kernels keeps the cache in place and scores no
+position that is not live.
 
 No chip is attached here: the TPU compiler that is installed compiles for a
 v5e that is described, not present (on-chip-measurement guide, section 2,
@@ -150,41 +151,95 @@ def test_int8_dequantize_compiles_for_v5e(one_chip, monkeypatch):
         ((rows,), jnp.float32)))
 
 
-@pytest.mark.parametrize("k", [2, 8])
-def test_decode_multi_stores_in_place_for_v5e(one_chip, monkeypatch, k):
-    """`decode_multi` at the serving cell's widths (GPT-2 large, 32 slots,
-    1024 positions; one layer): the k new positions go into the donated
-    cache in place.  The output aliases the cache and no temporary comes
-    near a K/V array's size (84 MB; with the select write-back this program
-    held 271 MB of them a layer), so an edit that brings a whole-cache
-    temporary back fails here and not in a cell."""
-    from fedml_tpu.ops import pallas_kv_store
+#: the serving cell's widths: GPT-2 large, 32 slots, 1024 positions
+SLOTS, HEADS, DIM, POSITIONS, VOCAB = 32, 20, 1280, 1024, 50257
+
+
+@pytest.fixture(scope="module")
+def decode_multi_compiled(one_chip):
+    """`decode_multi` at the serving cell's widths, one layer, compiled for
+    the described chip with its kernels steered to their TPU branch: one
+    compile a dispatch length, shared by the tests that read it."""
+    from fedml_tpu.ops import pallas_decode_attention, pallas_kv_store
     from fedml_tpu.parallel.seq_parallel import init_lm_params
     from fedml_tpu.serving import kv_cache_lm
 
-    monkeypatch.setattr(pallas_kv_store, "_on_tpu", lambda: True)
-    slots, heads, dim, positions, vocab = 32, 20, 1280, 1024, 50257
     spec = functools.partial(jax.tree_util.tree_map, lambda a: (
         jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)))
     params = jax.eval_shape(lambda: jax.tree_util.tree_map(
         lambda a: a.astype(jnp.bfloat16),
-        init_lm_params(jax.random.PRNGKey(0), vocab, dim=dim, layers=1,
-                       heads=heads, max_len=positions)))
+        init_lm_params(jax.random.PRNGKey(0), VOCAB, dim=DIM, layers=1,
+                       heads=HEADS, max_len=POSITIONS)))
     cache = jax.eval_shape(functools.partial(
-        kv_cache_lm.init_cache, batch=slots, max_len=positions, heads=heads),
+        kv_cache_lm.init_cache, batch=SLOTS, max_len=POSITIONS, heads=HEADS),
         params)
-    vec = lambda dt, *s: jax.ShapeDtypeStruct((slots, *s), dt,
+    vec = lambda dt, *s: jax.ShapeDtypeStruct((SLOTS, *s), dt,
                                               sharding=one_chip)
-    compiled = kv_cache_lm.decode_multi.lower(
-        spec(params), spec(cache), vec(jnp.int32, k), vec(jnp.int32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
-        spec(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
-        heads=heads, k=k).compile()
+
+    @functools.lru_cache(maxsize=None)
+    def compiled(k):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(pallas_kv_store, "_on_tpu", lambda: True)
+            patch.setattr(pallas_decode_attention, "_on_tpu", lambda: True)
+            return kv_cache_lm.decode_multi.lower(
+                spec(params), spec(cache), vec(jnp.int32, k), vec(jnp.int32),
+                vec(jnp.int32), vec(jnp.float32), vec(jnp.int32),
+                vec(jnp.float32),
+                spec(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+                heads=HEADS, k=k).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("k", [2, 8])
+def test_decode_multi_stores_in_place_for_v5e(decode_multi_compiled, k):
+    """The k new positions go into the donated cache in place.  The output
+    aliases the cache and no temporary comes near a K/V array's size (84 MB;
+    with the select write-back this program held 271 MB of them a layer), so
+    an edit that brings a whole-cache temporary back fails here and not in a
+    cell."""
+    compiled = decode_multi_compiled(k)
     _assert_kernel(compiled.as_text())
-    array_bytes = slots * positions * dim * 2
+    array_bytes = SLOTS * POSITIONS * DIM * 2
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes == 2 * array_bytes, "cache not updated in place"
     assert m.temp_size_in_bytes < array_bytes // 4, m.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("k", [2, 8])
+def test_decode_multi_scores_no_dead_position_for_v5e(decode_multi_compiled,
+                                                      k):
+    """The attention over the cache is the kernel `decode_attention`, which
+    visits live blocks only: no value of the program has a score for every
+    position of every row (``f32[32,20,1024]``, what the two contractions
+    over the whole cache made until PR 28, 6 GB read a token step)."""
+    text = decode_multi_compiled(k).as_text()
+    assert "decode_attention" in text and "kv_store_positions" in text
+    assert f"f32[{SLOTS},{HEADS},{POSITIONS}]" not in text
+
+
+@pytest.mark.parametrize("shape,query", [
+    ((32, 20, 64, 1024), jnp.float32),    # the serving cell
+    ((32, 20, 64, 1024), jnp.bfloat16),   # its first layer's query
+    ((32, 20, 64, 1000), jnp.float32),    # `lm_max_len` 1000: a ragged block
+    ((8, 12, 64, 300), jnp.float32),      # GPT-2 small, a short ragged cache
+    ((4, 12, 64, 40), jnp.float32),       # shorter than one lane tile
+], ids=["large-1024", "large-1024-bf16-query", "large-1000", "small-300",
+        "small-40"])
+def test_decode_attention_compiles_for_v5e(one_chip, monkeypatch, shape,
+                                           query):
+    """`decode_attention` at the cell's size and at lengths that are not
+    whole blocks: blocks of whole lane tiles whose last is ragged, the
+    grid's length counted on the device."""
+    from fedml_tpu.ops import pallas_decode_attention
+
+    monkeypatch.setattr(pallas_decode_attention, "_on_tpu", lambda: True)
+    array = (shape, jnp.bfloat16)
+    text = _compile_text(
+        lambda q, k, v, n: pallas_decode_attention.decode_attention(
+            q, k, v, n, 0.125),
+        one_chip, (shape[:3], query), array, array, (shape[:1], jnp.int32))
+    _assert_kernel(text)
 
 
 @pytest.mark.parametrize("shape,k", [
