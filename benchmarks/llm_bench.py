@@ -5,7 +5,7 @@ Two measurements, both on a GPT-2-small-class transformer (dim 768,
 trainer fine-tunes, `train/llm/hf_trainer.py`, and its scalellm wrapper
 serves, `scalellm/__init__.py`):
 
-* **SFT train step** — the functional LM (`parallel/seq_parallel.py`)
+* **SFT train step** — the functional LM (`models/functional_lm.py`)
   under one jitted AdamW step, bf16 matmuls / fp32 optimizer, seq 1024.
   Reports tokens/s and analytic MFU against the chip's bf16 peak.
   FLOP accounting counts what the program EXECUTES (full T x T attention
@@ -68,11 +68,11 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import optax  # noqa: E402
 
-from fedml_tpu.parallel.ring_attention import reference_attention  # noqa: E402
-from fedml_tpu.parallel.seq_parallel import (  # noqa: E402
+from fedml_tpu.models.functional_lm import (  # noqa: E402
     init_lm_params,
     lm_loss,
 )
+from fedml_tpu.parallel.ring_attention import reference_attention  # noqa: E402
 from fedml_tpu.serving.kv_cache_lm import KVCacheLM  # noqa: E402
 
 from fedml_tpu.core.mlops.flight_recorder import chip_peak_flops  # noqa: E402

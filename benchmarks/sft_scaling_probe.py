@@ -55,9 +55,9 @@ def measure(dim, layers, heads, vocab, bs, attn, remat, accum=1):
     import optax
 
     from fedml_tpu.core.mlops.flight_recorder import chip_peak_flops
+    from fedml_tpu.models.functional_lm import init_lm_params, lm_loss
     from fedml_tpu.ops.pallas_attention import flash_attention
     from fedml_tpu.parallel.ring_attention import reference_attention
-    from fedml_tpu.parallel.seq_parallel import init_lm_params, lm_loss
 
     peak = chip_peak_flops()
     if peak is None:

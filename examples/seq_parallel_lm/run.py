@@ -24,10 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedml_tpu.ml.engine.mesh import build_mesh
-from fedml_tpu.parallel.seq_parallel import (
-    build_seq_parallel_train_step,
-    init_lm_params,
-)
+from fedml_tpu.models.functional_lm import init_lm_params
+from fedml_tpu.parallel.seq_parallel import build_seq_parallel_train_step
 
 
 def main() -> None:

@@ -133,9 +133,10 @@ def create(args: Any, output_dim: Optional[int] = None) -> ModelBundle:
         module = TinyTransformerLM(vocab_size=num_classes, dtype=dtype)
         task = TASK_LM
     elif name in ("functional_lm", "kv_lm"):
-        # the pure-pytree LM shared with parallel/seq_parallel and the
-        # KV-cache serving engine: fine-tune it here (LoRA targets its
-        # wq/wk/wv/wo/w1/w2 matmuls), then serve the SAME params through
+        # the pure-pytree LM (models/functional_lm.py) shared with the
+        # sequence-parallel step and the KV-cache serving engine:
+        # fine-tune it here (LoRA targets its wq/wk/wv/wo/w1/w2 matmuls),
+        # then serve the SAME params through
         # serving/kv_cache_lm.KVCacheLM with zero conversion
         from .functional_lm import FunctionalLMModule
 

@@ -5,11 +5,12 @@ parallelism is listed "absent ... TPU-native equivalent to design fresh").
 `ring_attention.py` / `ulysses.py` provide the attention op; this module is
 the full training step built around it:
 
-* a pure-functional transformer LM (params = plain pytree) whose
-  position-wise ops (embed, layernorm, MLP, logits) shard trivially over the
-  ``seq`` mesh axis via sharding constraints, and whose attention runs as a
-  `shard_map` island using ring attention (ppermute K/V ring, flash-kernel
-  partials) or Ulysses (all-to-all head sharding);
+* the pure-functional transformer LM of `models/functional_lm.py` (params =
+  plain pytree), whose position-wise ops (embed, layernorm, MLP, logits)
+  shard trivially over the ``seq`` mesh axis via sharding constraints, and
+  whose attention runs as a `shard_map` island using ring attention
+  (ppermute K/V ring, flash-kernel partials) or Ulysses (all-to-all head
+  sharding);
 * `build_seq_parallel_train_step` — one jitted step (loss, grads, SGD
   update) over token batches sharded [B, T/P]; gradients flow through the
   custom ring/flash VJPs, so the whole thing trains on hardware.
@@ -23,125 +24,14 @@ context on one device, which is the point of context parallelism.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..constants import AXIS_SEQ
+from ..models.functional_lm import lm_loss
 from .ring_attention import reference_attention, ring_attention
 from .ulysses import ulysses_attention
-
-
-def init_lm_params(key: jax.Array, vocab: int, dim: int = 64,
-                   layers: int = 2, heads: int = 4,
-                   max_len: int = 512) -> Dict[str, Any]:
-    """Transformer-LM parameter pytree (pre-LN blocks, learned positions)."""
-    keys = jax.random.split(key, 2 + layers)
-    p: Dict[str, Any] = {
-        "embed": jax.random.normal(keys[0], (vocab, dim)) * 0.02,
-        "pos": jax.random.normal(keys[1], (max_len, dim)) * 0.02,
-        "blocks": [],
-        "ln_f": {"scale": jnp.ones((dim,)), "bias": jnp.zeros((dim,))},
-    }
-    for i in range(layers):
-        kq, kk, kv, ko, k1, k2 = jax.random.split(keys[2 + i], 6)
-        s = 1.0 / np.sqrt(dim)
-        p["blocks"].append({
-            "ln1": {"scale": jnp.ones((dim,)), "bias": jnp.zeros((dim,))},
-            "wq": jax.random.normal(kq, (dim, dim)) * s,
-            "wk": jax.random.normal(kk, (dim, dim)) * s,
-            "wv": jax.random.normal(kv, (dim, dim)) * s,
-            "wo": jax.random.normal(ko, (dim, dim)) * s,
-            "ln2": {"scale": jnp.ones((dim,)), "bias": jnp.zeros((dim,))},
-            "w1": jax.random.normal(k1, (dim, 4 * dim)) * s,
-            "w2": jax.random.normal(k2, (4 * dim, dim)) * (s / 2.0),
-        })
-    return p
-
-
-#: LayerNorm epsilon — 1e-5 matches the HF GPT-2 default so imported
-#: checkpoints (`train/llm/weight_import.py`) reproduce reference logits
-LN_EPS = 1e-5
-
-
-def _ln(x, g):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.var(x, -1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + LN_EPS) * g["scale"] + g["bias"]
-
-
-def lm_forward(params: Dict[str, Any], tokens: jnp.ndarray, heads: int,
-               attn_fn, remat: bool = False) -> jnp.ndarray:
-    """[B, T] int tokens → [B, T, V] logits.  ``attn_fn(q, k, v)`` consumes
-    [B, H, T, D_h] — plug in full attention, a shard_map'd ring, or Ulysses;
-    everything else is position-wise and sharding-constraint friendly.
-    ``remat=True`` rematerializes each block's activations in the backward
-    pass (`jax.checkpoint`), trading FLOPs for the activation memory that
-    dominates long-context training."""
-    b, t = tokens.shape
-    dim = params["embed"].shape[1]
-    dh = dim // heads
-    # NOTE positions must be GLOBAL: tokens arrive [B, T] logically; under
-    # jit the T axis is sharded and iota is partitioned correctly by XLA.
-    h = params["embed"][tokens] + params["pos"][:t][None]
-
-    def block(h, blk):
-        y = _ln(h, blk["ln1"])
-
-        def proj(w, bias_key):
-            z = y @ w
-            if bias_key in blk:        # optional biases (imported HF
-                z = z + blk[bias_key]  # checkpoints carry them; native
-            return z                   # init is bias-free)
-
-        def split_heads(z):
-            return z.reshape(b, t, heads, dh).transpose(0, 2, 1, 3)
-
-        q = split_heads(proj(blk["wq"], "bq"))
-        k = split_heads(proj(blk["wk"], "bk"))
-        v = split_heads(proj(blk["wv"], "bv"))
-        o = attn_fn(q, k, v)                       # [B, H, T, Dh]
-        o = o.transpose(0, 2, 1, 3).reshape(b, t, dim)
-        o = o @ blk["wo"]
-        if "bo" in blk:
-            o = o + blk["bo"]
-        h = h + o
-        y = _ln(h, blk["ln2"])
-        z = y @ blk["w1"]
-        if "b1" in blk:
-            z = z + blk["b1"]
-        z = jax.nn.gelu(z) @ blk["w2"]
-        if "b2" in blk:
-            z = z + blk["b2"]
-        return h + z
-
-    if remat:
-        block = jax.checkpoint(block)
-    for blk in params["blocks"]:
-        h = block(h, blk)
-    h = _ln(h, params["ln_f"])
-    if "w_out" in params:                          # optional untied head
-        return h @ params["w_out"]
-    return h @ params["embed"].T                   # tied output embedding
-
-
-def lm_loss(params, tokens, heads, attn_fn,
-            remat: bool = False) -> jnp.ndarray:
-    """Next-token CE over [B, T].  The model runs on the FULL (sharded) T —
-    the last position is masked out of the loss instead of sliced off, so
-    the sequence axis stays evenly divisible by the mesh."""
-    b, t = tokens.shape
-    logits = lm_forward(params, tokens, heads, attn_fn, remat)  # [B, T, V]
-    targets = jnp.concatenate(
-        [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(
-        logits, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    mask = (jnp.arange(t) < t - 1).astype(jnp.float32)[None]
-    return jnp.sum((logz - gold) * mask) / (jnp.sum(mask) * b)
 
 
 def build_seq_parallel_train_step(mesh: Mesh, heads: int,

@@ -7,14 +7,16 @@ architecture):
 
 * ``prefill`` — one full forward over the prompt, returning the per-layer
   K/V cache rows and the next-token logits;
-* ``decode_step`` — one token per row per call against the cache, with a
-  PER-ROW position vector, so continuously-batched rows at different
-  generation depths share one fixed-shape jitted step (flax's built-in
-  decode cache keys on a single scalar index and cannot do this);
+* ``decode_multi`` — k tokens per row per dispatch against the cache,
+  sampled on the device, with a PER-ROW position vector, so continuously
+  batched rows at different generation depths share one fixed-shape program
+  (flax's built-in decode cache keys on a single scalar index and cannot do
+  this); k = 1 is the one-token step;
 * ``KVCacheLM`` — stateless convenience wrapper holding params/config.
 
-Model = `parallel.seq_parallel` functional LM (same params pytree, same
-math; parity-tested token-for-token against the non-cached forward).
+Model = `models/functional_lm` (same params pytree, the same `block`, which
+is handed an ``attend`` that keeps or reads the cache; parity-tested
+token-for-token against the non-cached forward).
 """
 
 from __future__ import annotations
@@ -26,9 +28,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.functional_lm import (
+    block,
+    embed,
+    head,
+    init_lm_params,
+    lm_forward,
+)
 from ..ops.pallas_decode_attention import MASKED, decode_attention
 from ..ops.pallas_kv_store import store_positions
-from ..parallel.seq_parallel import _ln, init_lm_params, lm_forward
 
 
 def init_cache(params: Dict[str, Any], batch: int, max_len: int,
@@ -41,10 +49,8 @@ def init_cache(params: Dict[str, Any], batch: int, max_len: int,
     positions as whole tiles: `ops.pallas_kv_store` writes the blocks a
     dispatch's new positions fall in, `ops.pallas_decode_attention` reads
     the blocks below a row's length and no others (PERF.md, PR 25 and 28).
-    `decode_step`, the one-token fallback, still reads and selects over the
-    whole length; it and the quantized path were not timed on the chip.
-    `prefill`, `decode_step`, `decode_multi` and the engine's
-    `_scatter_cache_row` all take and return this one definition."""
+    `prefill`, `decode_multi` and the engine's `_scatter_cache_row` all take
+    and return this one definition."""
     dim = params["embed"].shape[1]
     dh = dim // heads
     dt = params["embed"].dtype        # bf16 params -> bf16 cache (an fp32
@@ -55,130 +61,42 @@ def init_cache(params: Dict[str, Any], batch: int, max_len: int,
             for _ in params["blocks"]]
 
 
-def _with_bias(z, blk, bkey):
-    """Optional-bias add (imported HF checkpoints carry biases; native
-    init is bias-free — same convention as `seq_parallel.lm_forward`)."""
-    return z + blk[bkey] if bkey in blk else z
-
-
-def _head(h, params):
-    if "w_out" in params:                   # optional untied output head
-        return h @ params["w_out"]
-    return h @ params["embed"].T            # tied output embedding
-
-
-def _qkv(y, blk, b, heads, dh):
-    """Single-position q/k/v projections [B, H, Dh] (shared by both decode
-    cores — keep the transformer math in ONE place)."""
-    q = _with_bias(y @ blk["wq"], blk, "bq").reshape(b, heads, dh)
-    k = _with_bias(y @ blk["wk"], blk, "bk").reshape(b, heads, dh)
-    v = _with_bias(y @ blk["wv"], blk, "bv").reshape(b, heads, dh)
-    return q, k, v
-
-
-def _post_attention(h, o, blk, b, dim):
-    """Output projection + residual + MLP half of a block (shared by both
-    decode cores)."""
-    h = h + _with_bias(o.reshape(b, dim) @ blk["wo"], blk, "bo")
-    y = _ln(h, blk["ln2"])
-    return h + _with_bias(
-        jax.nn.gelu(_with_bias(y @ blk["w1"], blk, "b1")) @ blk["w2"],
-        blk, "b2")
-
-
 @partial(jax.jit, static_argnames=("heads", "max_len"))
 def prefill(params: Dict[str, Any], tokens: jnp.ndarray,
             length: jnp.ndarray, heads: int, max_len: int = 0
             ) -> Tuple[List[Dict[str, jnp.ndarray]], jnp.ndarray]:
     """Full pass over padded prompts [B, T] (valid length per row) →
     (cache sized for ``max_len`` positions, logits at the last valid
-    position).  ``max_len`` > T zero-pads the cache rows so decode_step can
-    keep writing past the prompt width (JAX would otherwise drop the
-    out-of-bounds scatter silently); 0 keeps the prompt width (only safe
+    position).  ``max_len`` > T zero-pads the cache rows so decoding can
+    keep writing past the prompt width; 0 keeps the prompt width (only safe
     when the caller re-scatters into a full-size cache itself)."""
-    b, t = tokens.shape
+    t = tokens.shape[1]
     if max_len and max_len < t:
         raise ValueError(f"prefill: max_len={max_len} < prompt width {t}")
-    dim = params["embed"].shape[1]
-    dh = dim // heads
-    h = params["embed"][tokens] + params["pos"][:t][None]
-    cache = []
+    dh = params["embed"].shape[1] // heads
+    pad = ((0, 0), (0, 0), (0, 0), (0, max(max_len - t, 0)))
     pos_ids = jnp.arange(t)
-    for blk in params["blocks"]:
-        y = _ln(h, blk["ln1"])
+    causal = (pos_ids[:, None] >= pos_ids[None, :])[None, None]
+    cache = []
 
-        def heads_of(w, bkey):
-            z = y @ w
-            if bkey in blk:      # optional biases (imported checkpoints)
-                z = z + blk[bkey]
-            return z.reshape(b, t, heads, dh)
-
-        q = heads_of(blk["wq"], "bq").transpose(0, 2, 1, 3)
-        k = heads_of(blk["wk"], "bk")
-        v = heads_of(blk["wv"], "bv")
-        pad = ((0, 0), (0, 0), (0, 0), (0, max(max_len - t, 0)))
+    def attend(q, k, v):
+        """Causal softmax attention over the prompt, [B, T, H, Dh]; the
+        layer's K and V go to the cache on the way."""
         cache.append({"k": jnp.pad(k.transpose(0, 2, 3, 1), pad),
                       "v": jnp.pad(v.transpose(0, 2, 3, 1), pad)})
-        kt = k.transpose(0, 2, 1, 3)
-        vt = v.transpose(0, 2, 1, 3)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, kt) / np.sqrt(dh)
-        causal = pos_ids[:, None] >= pos_ids[None, :]
-        s = jnp.where(causal[None, None], s, -1e30)
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vt)
-        h = h + _with_bias(
-            o.transpose(0, 2, 1, 3).reshape(b, t, dim) @ blk["wo"],
-            blk, "bo")
-        y = _ln(h, blk["ln2"])
-        h = h + _with_bias(
-            jax.nn.gelu(_with_bias(y @ blk["w1"], blk, "b1")) @ blk["w2"],
-            blk, "b2")
-    h = _ln(h, params["ln_f"])
-    logits = _head(h, params)                            # [B, T, V]
+        q, k, v = (z.transpose(0, 2, 1, 3) for z in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(dh)
+        s = jnp.where(causal, s, -1e30)
+        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+        return o.transpose(0, 2, 1, 3)
+
+    h = embed(params, tokens)
+    for blk in params["blocks"]:
+        h = block(h, blk, heads, attend)
+    logits = head(h, params)                             # [B, T, V]
     last = jnp.take_along_axis(
         logits, (length - 1)[:, None, None], axis=1)[:, 0]
     return cache, last
-
-
-def _decode_core(params: Dict[str, Any],
-                 cache: List[Dict[str, jnp.ndarray]],
-                 token: jnp.ndarray, pos: jnp.ndarray, heads: int
-                 ) -> Tuple[List[Dict[str, jnp.ndarray]], jnp.ndarray]:
-    """One token per row (traced body shared by the single- and multi-token
-    dispatch entry points).
-
-    The cache update is a broadcast-compare SELECT over the whole cache,
-    every token: the one-token fallback's own cost (the engine takes this
-    path only when a row is within a dispatch of the cache's end, and for
-    ``tokens_per_dispatch=1``).  Per token it beat what XLA offers instead:
-    a per-row ``.at[rows, pos].set`` lowers to a scatter that measured 2.9x
-    slower on v5e (21.9 vs 7.5 ms/step at B=32 T=1024; a per-row
-    dynamic_update_slice chain was just as slow: benchmarks/BENCH_NOTES.md
-    round 4), and once a dispatch that scatter still costs 41 ms at GPT-2
-    large's 72 arrays (PERF.md, PR 25).  `decode_multi` stores through
-    `ops.pallas_kv_store` instead, which is what this path would use too
-    if it came to matter."""
-    b = token.shape[0]
-    dim = params["embed"].shape[1]
-    dh = dim // heads
-    t_cache = cache[0]["k"].shape[-1]
-    h = params["embed"][token] + params["pos"][pos]       # [B, D]
-    new_cache = []
-    iota = jnp.arange(t_cache)
-    hit = (iota[None, :] == pos[:, None])[:, None, None]  # [B, 1, 1, T]
-    for blk, layer in zip(params["blocks"], cache):
-        y = _ln(h, blk["ln1"])
-        q, k_new, v_new = _qkv(y, blk, b, heads, dh)
-        k_cache = jnp.where(hit, k_new[..., None], layer["k"])
-        v_cache = jnp.where(hit, v_new[..., None], layer["v"])
-        new_cache.append({"k": k_cache, "v": v_cache})
-        s = jnp.einsum("bhd,bhdt->bht", q, k_cache) / np.sqrt(dh)
-        valid = (iota[None] <= pos[:, None])              # [B, T]
-        s = jnp.where(valid[:, None, :], s, -1e30)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bht,bhdt->bhd", w, v_cache)
-        h = _post_attention(h, o, blk, b, dim)
-    h = _ln(h, params["ln_f"])
-    return new_cache, _head(h, params)                    # [B, V]
 
 
 def _attend_cache_and_chunk(q: jnp.ndarray, layer: Dict[str, jnp.ndarray],
@@ -219,34 +137,20 @@ def _decode_core_chunked(params: Dict[str, Any],
     (everything newer lives in the chunk buffer), and of the cache only the
     blocks of positions below ``pos0[i]`` are read (`_attend_cache_and_chunk`).
     Returns the updated chunk buffers and the logits."""
-    b = token.shape[0]
-    dim = params["embed"].shape[1]
-    dh = dim // heads
-    pos = pos0 + j
-    h = params["embed"][token] + params["pos"][pos]       # [B, D]
+    h = embed(params, token, pos0 + j)                    # [B, D]
     for li, (blk, layer) in enumerate(zip(params["blocks"], cache)):
-        y = _ln(h, blk["ln1"])
-        q, k_new, v_new = _qkv(y, blk, b, heads, dh)
-        # uniform-position write: every row writes chunk slot j (cheap
-        # contiguous dynamic_update_slice, no per-row scatter)
-        kc = jax.lax.dynamic_update_slice(
-            kc, k_new[None, :, None].astype(kc.dtype), (li, 0, j, 0, 0))
-        vc = jax.lax.dynamic_update_slice(
-            vc, v_new[None, :, None].astype(vc.dtype), (li, 0, j, 0, 0))
-        o = _attend_cache_and_chunk(q, layer, kc[li], vc[li], pos0, j)
-        h = _post_attention(h, o, blk, b, dim)
-    h = _ln(h, params["ln_f"])
-    return kc, vc, _head(h, params)                       # [B, V]
+        def attend(q, k_new, v_new, li=li, layer=layer):
+            nonlocal kc, vc
+            # uniform-position write: every row writes chunk slot j (cheap
+            # contiguous dynamic_update_slice, no per-row scatter)
+            kc = jax.lax.dynamic_update_slice(
+                kc, k_new[None, :, None].astype(kc.dtype), (li, 0, j, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                vc, v_new[None, :, None].astype(vc.dtype), (li, 0, j, 0, 0))
+            return _attend_cache_and_chunk(q, layer, kc[li], vc[li], pos0, j)
 
-
-@partial(jax.jit, static_argnames=("heads",), donate_argnums=(1,))
-def decode_step(params: Dict[str, Any],
-                cache: List[Dict[str, jnp.ndarray]],
-                token: jnp.ndarray, pos: jnp.ndarray, heads: int
-                ) -> Tuple[List[Dict[str, jnp.ndarray]], jnp.ndarray]:
-    """One token per row: ``token`` [B] at per-row position ``pos`` [B].
-    Writes this position's K/V into the cache and returns next logits."""
-    return _decode_core(params, cache, token, pos, heads)
+        h = block(h, blk, heads, attend)
+    return kc, vc, head(h, params)                        # [B, V]
 
 
 #: sampler candidate cap: top-k / nucleus filtering runs over the top
@@ -440,12 +344,17 @@ def _decode_multi(params: Dict[str, Any],
     ms of every dispatch, whatever its length; the store is 4.6 ms whatever
     k (my chip runs, PR 25, device time).
 
-    A row with ``pos0 + k > T``: its positions below T are stored, those at
-    or beyond T are dropped, none is moved to fit (what the select did;
-    tests/test_decode_writeback.py).  The engine never sends an active row
-    like that (`_can_multi`).  An idle slot comes with ``pos0 == 0``: its
-    positions 0 .. k-1 are written, and the row is never read before an
-    admission writes it again."""
+    A row with ``pos0 + k > T`` (a request about to fill its cache: the
+    engine sends it like any other): its positions below T are stored, those
+    at or beyond T are dropped, none is moved to fit
+    (tests/test_decode_writeback.py).  An inner step whose position lies
+    beyond the position table is fed the table's last row (`embed`), and
+    what it emits is nobody's token: the engine's ``submit()`` holds prompt
+    plus output to T, so the last token a request is owed comes from feeding
+    position T - 2 at the latest, and `_stream` hands out no more than a
+    request is owed.  Other rows see nothing of it.  An idle slot comes with
+    ``pos0 == 0``: its positions 0 .. k-1 are written, and the row is never
+    read before an admission writes it again."""
     b = prompt_buf.shape[0]
     nl = len(params["blocks"])
     dim = params["embed"].shape[1]
@@ -524,7 +433,7 @@ decode_multi.lower = lambda *args, k, **kw: _decode_multi_jit(k).lower(
 
 class KVCacheLM:
     """Decode-oriented LM handle for the batched engine: owns params and
-    config, exposes prefill/decode with per-row positions."""
+    config, exposes prefill/decode_multi with per-row positions."""
 
     def __init__(self, params: Dict[str, Any], heads: int,
                  max_len: int) -> None:
@@ -549,9 +458,6 @@ class KVCacheLM:
         rows are sized so decode can continue past the prompt)."""
         ml = self.max_len if max_len == -1 else max_len
         return prefill(self.params, tokens, length, self.heads, ml)
-
-    def decode(self, cache, token, pos):
-        return decode_step(self.params, cache, token, pos, self.heads)
 
     def decode_multi(self, cache, prompt_buf, prompt_n, pos0, temps,
                      top_k, top_p, rng, k: int,

@@ -657,11 +657,12 @@ class LLMEnginePredictor:
 
 class KVCacheLLMEngine:
     """Continuous batching over a per-row KV cache (`kv_cache_lm.KVCacheLM`)
-    — the prefill/decode architecture of scalellm/vLLM, with CHUNKED
-    prefill: prompt tokens are teacher-forced through the same fixed-shape
-    decode step as generation, one token per row per step, so newly
-    admitted prompts stream in while other rows keep generating and the
-    engine has exactly ONE compiled step.  Each generated token costs
+    — the prefill/decode architecture of scalellm/vLLM.  An iteration of
+    the loop admits what is pending (a prompt longer than a dispatch is
+    prefilled whole, a shorter one is teacher-forced through the decode
+    program), picks the dispatch length and runs `decode_multi` once for
+    every slot: the ONE decode path, whatever the length and however near a
+    row is to the end of its cache.  Each generated token costs
     O(cache_len) attention instead of the full-window O(T²) re-forward of
     `BatchedLLMEngine`."""
 
@@ -674,10 +675,10 @@ class KVCacheLLMEngine:
 
         self.lm = lm
         self.max_batch = int(max_batch)
-        #: inner on-device loop length: when every active row has cache
-        #: headroom, decode_multi samples k tokens per dispatch (greedy,
-        #: temperature, top-k and nucleus filtering all run on-device)
-        #: with NO host round trip in between — a ~k x dispatch-latency win
+        #: inner on-device loop length: decode_multi samples k tokens per
+        #: dispatch (greedy, temperature, top-k and nucleus filtering all
+        #: run on-device) with NO host round trip in between — a ~k x
+        #: dispatch-latency win; 1 is one token a dispatch, the same program
         self.tokens_per_dispatch = max(int(tokens_per_dispatch), 1)
         self.admission = admission
         self._pending: "queue.Queue[_Request]" = queue.Queue()
@@ -687,7 +688,6 @@ class KVCacheLLMEngine:
         self._pos = np.zeros((self.max_batch,), np.int32)
         self._cache = lm.init_cache(self.max_batch)
         self._stop = threading.Event()
-        self._np_rng = np.random.default_rng(11)
         self._rng_key = jax.random.PRNGKey(13)
         #: guards loop-mutated counters that stats() snapshots from other
         #: threads (the autoscaler + load report read while the loop writes)
@@ -890,7 +890,6 @@ class KVCacheLLMEngine:
     ADMIT_TURBO_K = 2
 
     def _loop(self) -> None:
-        jnp = self._jnp
         while not self._stop.is_set():
             turbo, admit_s = self._admit()
             if self.active_count == 0:
@@ -912,59 +911,7 @@ class KVCacheLLMEngine:
             k = self.tokens_per_dispatch
             if turbo and self.ADMIT_TURBO_K and self.ADMIT_TURBO_K < k:
                 k = self.ADMIT_TURBO_K
-            if k > 1 and self._can_multi(k):
-                self._step_multi(k, admit_s)
-                continue
-            # build this step's token vector: next prompt token (chunked
-            # prefill) or the last sampled token
-            tokens = np.zeros((self.max_batch,), np.int32)
-            for slot, req in enumerate(self._active):
-                if req is None:
-                    continue
-                if req.cancelled.is_set():
-                    req.finish_reason = "cancelled"
-                    self._retire(req, "cancel")
-                    if not req.future.done():
-                        req.future.set_result(
-                            np.asarray(getattr(req, "prefix", []) + req.ids))
-                    self._active[slot] = None
-                    continue
-                tokens[slot] = req.ids[self._pos[slot]] \
-                    if self._pos[slot] < len(req.ids) else 0
-            if self.active_count == 0:
-                continue
-            # the one-token fallback, whole: the dispatch, the wait for the
-            # device and the copy out
-            with tracing.phase("fedml.serve.decode1") as decode1:
-                self._cache, logits = self.lm.decode(
-                    self._cache, jnp.asarray(tokens), jnp.asarray(self._pos))
-                logits = np.asarray(logits)
-            self._metrics.step.observe(decode1.dur_s)
-            self._metrics.note_decode(decode1.dur_s, self.active_count)
-            self._end_iteration(admit_s, decode1)
-            produced = 0
-            for slot, req in enumerate(self._active):
-                if req is None:
-                    continue
-                self._pos[slot] += 1
-                if self._pos[slot] < len(req.ids):
-                    continue                      # still prefilling
-                nxt = _sample_token(logits[slot], req, self._np_rng)
-                req.ids.append(nxt)
-                self._metrics.note_token(req)
-                req.emit(nxt)
-                req.remaining -= 1
-                produced += 1
-                if (req.remaining <= 0
-                        or self._pos[slot] + 1 >= self.lm.max_len):
-                    if req.remaining > 0:  # cache-capacity cut, not budget
-                        req.finish_reason = "length"
-                    self._retire(req, "finish")
-                    req.future.set_result(
-                        np.asarray(getattr(req, "prefix", []) + req.ids))
-                    self._active[slot] = None
-            with self._state_lock:
-                self._tokens_done += produced
+            self._step_multi(k, admit_s)
         for req in self._active:
             if req is not None and not req.future.done():
                 self._retire(req, "cancel")
@@ -998,17 +945,6 @@ class KVCacheLLMEngine:
         self._metrics.occupancy.set(active / self.max_batch)
         return {"tokens_per_s": tps, "queue_depth": depth,
                 "active": active, "capacity": self.max_batch}
-
-    def _can_multi(self, k: int) -> bool:
-        """Multi-token dispatch applies when every active row has k
-        positions of cache headroom (sampling — including top-k/nucleus
-        filtering — runs on-device)."""
-        for slot, req in enumerate(self._active):
-            if req is None:
-                continue
-            if self._pos[slot] + k >= self.lm.max_len:
-                return False
-        return True
 
     def _step_multi(self, k: int, admit_s: float) -> None:
         import jax
@@ -1072,7 +1008,11 @@ class KVCacheLLMEngine:
 
     def _stream(self, emitted: np.ndarray, k: int) -> None:
         """Hand a dispatch's tokens to their requests (the clients'
-        ``on_token`` callbacks run here) and retire what finished."""
+        ``on_token`` callbacks run here) and retire what finished.  A
+        request is handed no more than ``remaining``, which ``submit()``
+        held to the cache's length less the prompt: what a row emitted
+        past that (the tail of its last dispatch, its positions beyond the
+        cache included) is dropped here."""
         produced = 0
         for slot, req in enumerate(self._active):
             if req is None:

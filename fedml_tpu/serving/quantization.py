@@ -11,7 +11,7 @@ matrix instead, the next step is an in-kernel dequant matmul per the
 pallas quantization pattern (/opt/skills/guides/pallas_guide.md).
 
 API: ``quantize_lm_params`` converts the functional-LM pytree
-(`parallel.seq_parallel.init_lm_params` layout) into a quantized variant;
+(`models/functional_lm.init_lm_params` layout) into a quantized variant;
 ``QuantizedKVCacheLM`` is a drop-in `KVCacheLM` whose prefill/decode
 dequantize on the fly.  Norm scales/biases and embeddings stay in f32
 (embeddings are gathers, not matmuls, and norm params are tiny).
@@ -68,8 +68,8 @@ def _dequant_blocks(params: Dict[str, Any]) -> Dict[str, Any]:
 
 
 class QuantizedKVCacheLM(KVCacheLM):
-    """KVCacheLM over int8-quantized weights: same prefill/decode API, the
-    dequant happens inside the jitted steps (fused into the matmuls by
+    """KVCacheLM over int8-quantized weights: same prefill/decode_multi API,
+    the dequant happens inside the jitted steps (fused into the matmuls by
     XLA), so HBM weight traffic is ~half of the bf16 baseline."""
 
     @classmethod
@@ -79,9 +79,6 @@ class QuantizedKVCacheLM(KVCacheLM):
     def prefill(self, tokens, length, max_len: int = -1):
         ml = self.max_len if max_len == -1 else max_len
         return _q_prefill(self.params, tokens, length, self.heads, ml)
-
-    def decode(self, cache, token, pos):
-        return _q_decode(self.params, cache, token, pos, self.heads)
 
     def decode_multi(self, cache, prompt_buf, prompt_n, pos0, temps,
                      top_k, top_p, rng, k: int,
@@ -101,14 +98,6 @@ def _q_prefill(params, tokens, length, heads, max_len=0):
 
     return _k.prefill.__wrapped__(_dequant_blocks(params), tokens, length,
                                   heads, max_len)
-
-
-@partial(jax.jit, static_argnames=("heads",), donate_argnums=(1,))
-def _q_decode(params, cache, token, pos, heads):
-    from . import kv_cache_lm as _k
-
-    return _k.decode_step.__wrapped__(_dequant_blocks(params), cache, token,
-                                      pos, heads)
 
 
 @partial(jax.jit, static_argnames=("heads", "k", "exact_filters"),

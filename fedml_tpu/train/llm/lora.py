@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 DEFAULT_TARGETS = (r".*attention.*kernel", r".*(query|key|value|out).*kernel",
                    r".*Dense_\d+.*kernel",
-                   # functional-LM layout (parallel/seq_parallel.py):
+                   # functional-LM layout (models/functional_lm.py):
                    # per-block attention/MLP matmuls
                    r".*/w[qkvo]", r".*/w[12]")
 

@@ -4,7 +4,7 @@ Capability parity: the reference fine-tunes real HF checkpoints
 (`/root/reference/python/fedml/train/llm/train_utils.py:196-244`,
 AutoModelForCausalLM.from_pretrained).  TPU-native equivalent: map an
 on-disk checkpoint (npz or safetensors) onto the functional-LM parameter
-pytree (`parallel/seq_parallel.init_lm_params` layout) with a full
+pytree (`models/functional_lm.init_lm_params` layout) with a full
 shape/name REPORT, so train/llm fine-tuning and KV-cache serving start
 from real weights instead of random init.
 

@@ -161,7 +161,7 @@ def decode_multi_compiled(one_chip):
     the described chip with its kernels steered to their TPU branch: one
     compile a dispatch length, shared by the tests that read it."""
     from fedml_tpu.ops import pallas_decode_attention, pallas_kv_store
-    from fedml_tpu.parallel.seq_parallel import init_lm_params
+    from fedml_tpu.models.functional_lm import init_lm_params
     from fedml_tpu.serving import kv_cache_lm
 
     spec = functools.partial(jax.tree_util.tree_map, lambda a: (

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from fedml_tpu.core.mlops import metrics
+from fedml_tpu.models import functional_lm
 from fedml_tpu.ops.pallas_decode_attention import MASKED, decode_attention
 from fedml_tpu.serving import kv_cache_lm
 from fedml_tpu.serving.kv_cache_lm import KVCacheLM
@@ -113,16 +114,17 @@ def _reference_decode_multi(params, cache, prompt_buf, pos0, heads, k):
 
     def step(carry, j):
         kc, vc, tok = carry
-        h = params["embed"][tok] + params["pos"][pos0 + j]
+        h = functional_lm.embed(params, tok, pos0 + j)
         for li, (blk, layer) in enumerate(zip(params["blocks"], cache)):
-            y = kv_cache_lm._ln(h, blk["ln1"])
-            q, k_new, v_new = kv_cache_lm._qkv(y, blk, b, heads, dh)
-            kc = kc.at[li, :, j].set(k_new.astype(dt))
-            vc = vc.at[li, :, j].set(v_new.astype(dt))
-            o = _reference_attention(q, layer["k"], layer["v"], kc[li],
-                                     vc[li], pos0, j)
-            h = kv_cache_lm._post_attention(h, o, blk, b, h.shape[-1])
-        logits = kv_cache_lm._head(kv_cache_lm._ln(h, params["ln_f"]), params)
+            def attend(q, k_new, v_new, li=li, layer=layer):
+                nonlocal kc, vc
+                kc = kc.at[li, :, j].set(k_new.astype(dt))
+                vc = vc.at[li, :, j].set(v_new.astype(dt))
+                return _reference_attention(q, layer["k"], layer["v"],
+                                            kc[li], vc[li], pos0, j)
+
+            h = functional_lm.block(h, blk, heads, attend)
+        logits = functional_lm.head(h, params)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (kc, vc, tok), (tok, logits)
 

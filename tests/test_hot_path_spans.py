@@ -138,12 +138,11 @@ def test_engine_iteration_leaves_its_spans_in_a_profiler_trace(tmp_path):
     events = [e for e in _host_events(tmp_path)
               if e[0].startswith("fedml.serve.")]
     names = {e[0] for e in events}
-    assert names >= {
+    assert names == {
         "fedml.serve.admit", "fedml.serve.prefill.t32",
         "fedml.serve.scatter", "fedml.serve.build",
         "fedml.serve.dispatch.k2", "fedml.serve.dispatch.k4",
         "fedml.serve.fetch", "fedml.serve.stream"}
-    assert "fedml.serve.decode1" not in names
 
     def of(name):
         return sorted(e[1:] for e in events if e[0].startswith(name))

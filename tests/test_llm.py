@@ -154,6 +154,18 @@ def test_llm_trainer_sharded_strategies_match_unsharded(strategy):
                                rtol=1e-4)
 
 
+def _greedy_step(lm, cache, tokens, pos):
+    """One greedy token a row through the one decode path: `decode_multi`
+    with k = 1, ``tokens`` [B] fed at per-row positions ``pos`` [B]."""
+    b = len(tokens)
+    zeros = jnp.zeros((b,), jnp.float32)
+    cache, emitted = lm.decode_multi(
+        cache, jnp.asarray(tokens, jnp.int32)[:, None],
+        jnp.ones((b,), jnp.int32), jnp.asarray(pos, jnp.int32), zeros,
+        jnp.zeros((b,), jnp.int32), zeros + 1, jax.random.PRNGKey(0), 1)
+    return cache, np.asarray(emitted)[:, 0]
+
+
 def test_kv_cache_decode_matches_full_forward():
     """Prefill + per-row cached decode reproduces the non-cached forward
     token-for-token (greedy), including rows at DIFFERENT positions."""
@@ -194,10 +206,8 @@ def test_kv_cache_decode_matches_full_forward():
     for i in range(b):
         out[i].append(int(nxt[i]))
     for _ in range(max_new - 1):
-        cache, logits = lm.decode(cache, jnp.asarray(nxt),
-                                  jnp.asarray(pos))
+        cache, nxt = _greedy_step(lm, cache, nxt, pos)
         pos = pos + 1
-        nxt = np.asarray([int(jnp.argmax(logits[i])) for i in range(b)])
         for i in range(b):
             out[i].append(int(nxt[i]))
     assert out == ref_out
@@ -572,6 +582,76 @@ def test_kv_engine_surfaces_length_finish_reason():
         eng.stop()
 
 
+def test_kv_engine_row_at_the_end_of_its_cache_takes_the_one_decode_path():
+    """A request that fills its cache goes through `decode_multi` like any
+    other, its last dispatch reaching past the cache's end: it gets the
+    tokens, the count and the ``finish_reason`` it gets from one-token
+    dispatches, a neighbour in mid-generation gets the tokens it gets
+    alone, and the cache keeps its type and shape."""
+    from fedml_tpu.serving.kv_cache_lm import KVCacheLM
+    from fedml_tpu.serving.llm_engine import KVCacheLLMEngine
+
+    t = 32
+    lm = KVCacheLM.create(jax.random.PRNGKey(21), vocab=40, dim=32,
+                          layers=3, heads=4, max_len=t)
+    lm.params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), lm.params)
+    rng = np.random.RandomState(6)
+    filler, neighbour = (list(rng.randint(0, 40, size=10)) for _ in "ab")
+
+    def alone(prompt, max_new):
+        eng = KVCacheLLMEngine(lm, max_batch=2, tokens_per_dispatch=1)
+        try:
+            fut = eng.submit(prompt, max_new=max_new)
+            return list(fut.result(timeout=120)), fut.request.finish_reason
+        finally:
+            eng.stop()
+
+    want_filler, want_neighbour = alone(filler, 100), alone(neighbour, 12)
+    assert len(want_filler[0]) == t and want_filler[1] == "length"
+
+    dispatches = []
+    inner = lm.decode_multi
+
+    def recording(cache, prompt_buf, prompt_n, pos0, *rest, **kw):
+        dispatches.append((np.asarray(pos0), rest[-1]))      # (pos0, k)
+        return inner(cache, prompt_buf, prompt_n, pos0, *rest, **kw)
+
+    lm.decode_multi = recording
+    eng = KVCacheLLMEngine(lm, max_batch=2, tokens_per_dispatch=8)
+
+    def leaves():
+        return [(a.shape, a.dtype)
+                for a in jax.tree_util.tree_leaves(eng._cache)]
+
+    shapes = leaves()
+    futs, seen = [], []
+
+    def on_token(tok):
+        # on the engine's thread: the neighbour joins while the filler is
+        # ten tokens short of the end of its cache
+        seen.append(tok)
+        if len(seen) == 12:
+            futs.append(eng.submit(neighbour, max_new=12))
+
+    try:
+        first = eng.submit(filler, max_new=100, on_token=on_token)
+        got_filler = list(first.result(timeout=120))
+        got_neighbour = list(futs[0].result(timeout=120))
+    finally:
+        eng.stop()
+        lm.decode_multi = inner
+    # one dispatch at least reached past the filler's cache with the
+    # neighbour in the batch, past its prompt
+    assert any(pos0[0] + k > t and pos0[1] >= len(neighbour)
+               for pos0, k in dispatches), dispatches
+    assert (got_filler, first.request.finish_reason) == want_filler
+    assert (got_neighbour, futs[0].request.finish_reason) == want_neighbour
+    assert want_neighbour[1] == "stop"
+    assert leaves() == shapes
+    assert {dt for _, dt in shapes} == {jnp.dtype(jnp.bfloat16)}
+
+
 def test_stream_close_cancels_engine_request():
     """Closing the token stream mid-generation cancels the underlying
     request: its slot frees and the future resolves."""
@@ -603,8 +683,8 @@ def test_stream_close_cancels_engine_request():
 
 
 def test_prefill_cache_supports_decode_past_prompt_width():
-    """prefill returns a max_len cache: decode_step keeps matching the
-    full forward well past the prompt width (the old prompt-width cache
+    """prefill returns a max_len cache: decoding keeps matching the full
+    forward well past the prompt width (the old prompt-width cache
     silently dropped those writes)."""
     from fedml_tpu.serving.kv_cache_lm import KVCacheLM
 
@@ -618,10 +698,9 @@ def test_prefill_cache_supports_decode_past_prompt_width():
     ids.append(nxt)
     pos = 5
     for _ in range(12):                # 5 + 12 > prompt width by far
-        cache, logits = lm.decode(cache, jnp.asarray([nxt]),
-                                  jnp.asarray([pos]))
+        cache, out = _greedy_step(lm, cache, [nxt], [pos])
         pos += 1
-        nxt = int(jnp.argmax(logits[0]))
+        nxt = int(out[0])
         ids.append(nxt)
 
     ref = list(prompt)

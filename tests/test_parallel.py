@@ -242,8 +242,8 @@ def test_seq_parallel_lm_train_step_matches_full(strategy):
     """End-to-end sequence-parallel LM training: one jitted step over a
     seq=4 mesh (tokens sharded [B, T/4]) produces the same loss and updated
     params as the unsharded model, and training reduces the loss."""
-    from fedml_tpu.parallel.seq_parallel import (
-        build_seq_parallel_train_step, init_lm_params)
+    from fedml_tpu.models.functional_lm import init_lm_params
+    from fedml_tpu.parallel.seq_parallel import build_seq_parallel_train_step
 
     mesh = build_mesh({"seq": 4})
     vocab, heads, t = 37, 4, 32
@@ -276,8 +276,8 @@ def test_seq_parallel_lm_train_step_matches_full(strategy):
 
 def test_seq_parallel_remat_matches_no_remat():
     """jax.checkpoint over blocks changes memory, not math."""
-    from fedml_tpu.parallel.seq_parallel import (
-        build_seq_parallel_train_step, init_lm_params)
+    from fedml_tpu.models.functional_lm import init_lm_params
+    from fedml_tpu.parallel.seq_parallel import build_seq_parallel_train_step
 
     mesh = build_mesh({"seq": 4})
     params = init_lm_params(jax.random.PRNGKey(0), 31, dim=32, layers=2,
@@ -295,3 +295,31 @@ def test_seq_parallel_remat_matches_no_remat():
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                                 atol=1e-5, rtol=1e-5),
         outs[0][0], outs[1][0])
+
+
+def test_models_import_nothing_from_parallel():
+    """The model definitions sit under the strategies that shard them:
+    `fedml_tpu.parallel` imports `fedml_tpu.models`, never the other way
+    round, at the top of a module or inside a function."""
+    import ast
+    import pathlib
+
+    import fedml_tpu
+
+    root = pathlib.Path(fedml_tpu.__file__).parent
+    found = []
+    for path in sorted((root / "models").rglob("*.py")):
+        package = ("fedml_tpu",) + path.relative_to(root).parts[:-1]
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                base = ".".join(package[:len(package) - node.level + 1]
+                                if node.level else ())
+                module = ".".join(x for x in (base, node.module) if x)
+                names = [module] + [f"{module}.{a.name}" for a in node.names]
+            else:
+                continue
+            found += [f"{path.name}: {n}" for n in names
+                      if (n + ".").startswith("fedml_tpu.parallel.")]
+    assert not found, found

@@ -10,7 +10,7 @@ import struct
 import numpy as np
 import pytest
 
-from fedml_tpu.parallel.seq_parallel import init_lm_params, lm_forward
+from fedml_tpu.models.functional_lm import init_lm_params, lm_forward
 from fedml_tpu.train.llm.weight_import import (
     export_lm_weights,
     import_lm_weights,
@@ -138,10 +138,10 @@ def test_trainer_finetunes_from_imported_weights(tmp_path):
 
 
 def test_kv_cache_serving_matches_forward_on_imported_gpt2(tmp_path):
-    """The KV-cache serving path (prefill + decode_step) must reproduce
-    lm_forward on an imported checkpoint WITH biases — it reimplements
-    the block math, so missing bias support would silently serve wrong
-    logits."""
+    """The KV-cache serving path (prefill + decode_multi) must reproduce
+    lm_forward on an imported checkpoint WITH biases — it hands the shared
+    block its own attention, so a bias lost on the way to the cache would
+    silently serve wrong logits."""
     transformers = pytest.importorskip("transformers")
 
     cfg = transformers.GPT2Config(
@@ -153,7 +153,7 @@ def test_kv_cache_serving_matches_forward_on_imported_gpt2(tmp_path):
     params, report = import_lm_weights(sd, schema="gpt2")
     assert not report["missing"]
 
-    from fedml_tpu.serving.kv_cache_lm import decode_step, prefill
+    from fedml_tpu.serving.kv_cache_lm import decode_multi, prefill
 
     toks_np = np.random.RandomState(1).randint(0, 48, (2, 10))
     toks = jnp.asarray(toks_np)
@@ -164,14 +164,29 @@ def test_kv_cache_serving_matches_forward_on_imported_gpt2(tmp_path):
     np.testing.assert_allclose(np.asarray(last), full[:, -1], atol=1e-4,
                                rtol=1e-3)
 
-    # one decode step == forward over the extended sequence's last logit
+    # one greedy decode step (k = 1) == forward over the extended sequence:
+    # its token is the last logits' best, and the K/V it stored at position
+    # 10 are those a prefill of the extended sequence computes there
     nxt = jnp.asarray(np.random.RandomState(2).randint(0, 48, (2,)))
-    cache, logits = decode_step(params, cache, nxt,
-                                jnp.asarray([10, 10]), heads=4)
+    zeros = jnp.zeros((2,), jnp.float32)
+    cache, emitted = decode_multi(
+        params, cache, nxt[:, None].astype(jnp.int32),
+        jnp.ones((2,), jnp.int32), jnp.asarray([10, 10], jnp.int32), zeros,
+        jnp.zeros((2,), jnp.int32), zeros + 1, jax.random.PRNGKey(0),
+        heads=4, k=1)
     ext = jnp.concatenate([toks, nxt[:, None]], axis=1)
     full_ext = np.asarray(lm_forward(params, ext, 4, _full_attn))
-    np.testing.assert_allclose(np.asarray(logits), full_ext[:, -1],
-                               atol=1e-4, rtol=1e-3)
+    best = np.sort(full_ext[:, -1], axis=-1)
+    assert (best[:, -1] - best[:, -2] > 1e-3).all()      # a clear winner
+    np.testing.assert_array_equal(np.asarray(emitted)[:, 0],
+                                  full_ext[:, -1].argmax(-1))
+    ext_cache, _ = prefill(params, ext, jnp.asarray([11, 11]), heads=4,
+                           max_len=16)
+    for got, want in zip(cache, ext_cache):
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[name])[..., :11],
+                np.asarray(want[name])[..., :11], atol=1e-4, rtol=1e-3)
 
 
 def test_biasfree_gpt2_schema_passes_strict_and_mismatch_raises():
