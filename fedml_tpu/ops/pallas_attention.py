@@ -8,13 +8,27 @@ score block resident in VMEM, so HBM traffic stays O(T·D) — the standard
 TPU treatment of the one genuinely bandwidth-bound matmul-adjacent op
 (/opt/skills/guides/pallas_guide.md).
 
-Semantics match `parallel.ring_attention.reference_attention` exactly
-(same masking convention).  Dispatch:
+Schedule: a grid step takes one head's q tile (512 queries where T allows)
+against the head's K and V, held in VMEM as one block that is fetched once
+a head, and walks them `block_k` keys at a pass in a loop that ends at the
+tile's causal diagonal: a block above it costs neither a fetch nor a step.
+K and V too long for the VMEM budget go on the grid in large blocks.  The
+scores of a pass are held [keys, queries], so the softmax state and the
+residuals are lane-dense rows.  The two products take bfloat16 operands and
+accumulate in float32 (XLA's default precision for the rest of a float32
+program on a TPU); the softmax state, the residuals and what reaches HBM
+keep float32 / the caller's dtypes.
+
+Masking convention as `parallel.ring_attention.reference_attention`; equal
+to it up to the bfloat16 rounding of the products' operands.  Dispatch:
 
 * on TPU → the pallas kernel;
 * off TPU with ``interpret=True`` (tests) → the same kernel through the
   pallas interpreter;
-* otherwise → a jnp fallback with identical math.
+* otherwise → a jnp fallback computed in float32.
+
+`fedml_attention_traces_total` counts, as calls are traced, which of these
+ran and with which tile.
 """
 
 from __future__ import annotations
@@ -28,9 +42,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.mlops import metrics as _metrics
 from .pallas_ops import _on_tpu
 
 NEG_INF = -1e30
+_LANES = 128
+#: default tile lengths, largest first, for both the queries of a grid step
+#: and the keys of a pass of its loop
+_TILES = (512, 256, 128)
+#: VMEM a grid step may spend on its K and V blocks, both double-buffered by
+#: the pipeline; a head's whole K and V are one block when they fit it
+_KV_VMEM_BUDGET = 8 * 2 ** 20
 
 
 def _reference(q, k, v, causal):
@@ -46,14 +68,29 @@ def _reference(q, k, v, causal):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, o_acc, l_acc, m_acc, *,
-                  block_q: int, block_k: int, t_valid: int, causal: bool,
-                  scale: float, nk: int):
-    """Grid (BH, nq, nk), k innermost: VMEM scratch carries the
-    online-softmax accumulators across k steps, so only one [bq, D] q tile
-    and one [bk, D] k/v tile are VMEM-resident at a time (scales to any T)."""
-    qi = pl.program_id(1)
-    j = pl.program_id(2)
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
+                  o_acc, l_acc, m_acc, *, block_k: int, t_valid: int,
+                  causal: bool, scale: float):
+    """One grid step of grid (BH, q tiles, K/V blocks): a [block_q, D] q
+    tile against the step's K/V block, ``block_k`` keys at a pass in a
+    `fori_loop` that stops at the tile's own causal diagonal.
+
+    The K/V block is the head's whole K and V when they fit the VMEM
+    budget (`_kv_block`): its index then does not change over the q axis
+    and it is fetched once a head.  Otherwise the K/V axis has several
+    steps, the scratch carries the online-softmax state across them, and a
+    step wholly above the diagonal finds its loop empty (its block index is
+    clamped to the last live one, so it fetches nothing either).
+
+    Scores are held transposed, [keys, queries]: the softmax state (row max
+    m, row sum l) is then one lane-dense row [1, block_q], its reductions
+    run down the sublanes, and the residuals leave as rows.  The two
+    products take bfloat16 operands (q already scaled) and accumulate in
+    float32; the state, the residuals and the output accumulator
+    ([D, block_q], turned once at the end) are float32."""
+    block_q, block_kv = q_ref.shape[1], k_ref.shape[1]
+    subs = block_kv // block_k
+    i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -61,58 +98,57 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, o_acc, l_acc, m_acc, *,
         l_acc[:] = jnp.zeros_like(l_acc)
         m_acc[:] = jnp.full_like(m_acc, NEG_INF)
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, 1), 0)
-    k_pos = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    # blocks fully above the causal diagonal contribute nothing — skip the
-    # compute (their DMA still happens; grid steps can't be elided)
-    live = (j * block_k <= qi * block_q + block_q - 1) if causal else (j >= 0)
+    # key sub-blocks are numbered over the whole key axis.  The first
+    # `n_full` need no mask (every key valid and, under `causal`, at or
+    # below the tile's first query); those up to `n_live` hold a key some
+    # query of the tile attends to; the rest are never visited.
+    n_full, n_live = t_valid // block_k, -(-t_valid // block_k)
+    if causal:
+        n_full = jnp.minimum(n_full, (i * block_q + 1) // block_k)
+        n_live = jnp.minimum(n_live, (i * block_q + block_q - 1) // block_k + 1)
+    first = j * subs
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(jnp.bfloat16)
+    q_pos = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_q), 1)
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale                # [bq, D]
-        k_blk = k_ref[0].astype(jnp.float32)                    # [bk, D]
-        v_blk = v_ref[0].astype(jnp.float32)
+    def step(masked, sub, carry):
+        o, l, m = carry                         # [D, bq], [1, bq], [1, bq]
+        rows = pl.ds(pl.multiple_of(sub * block_k, block_k), block_k)
         s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # [bq, bk]
-        mask = k_pos < t_valid                                  # pad keys out
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        s = jnp.where(mask, s, NEG_INF)
-        m = m_acc[:]
-        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            k_ref[0, rows, :].astype(jnp.bfloat16), q,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [bk, bq]
+        if masked:
+            k_pos = (first + sub) * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            mask = k_pos < t_valid                              # pad keys out
+            if causal:
+                mask = mask & (q_pos >= k_pos)
+            s = jnp.where(mask, s, NEG_INF)
+        # key 0 is valid and visible to every query and its sub-block comes
+        # first, so `new_m` is a real score and a masked p is exp(-1e30) = 0
+        new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - new_m)
-        p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m - new_m)
-        l_acc[:] = l_acc[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_acc[:] = o_acc[:] * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_acc[:] = new_m
+        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+        o = o * alpha + jax.lax.dot_general(
+            v_ref[0, rows, :].astype(jnp.bfloat16), p.astype(jnp.bfloat16),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return o, l, new_m
 
-    @pl.when(j == nk - 1)
+    carry = (o_acc[:], l_acc[:], m_acc[:])
+    full_end = jnp.clip(n_full - first, 0, subs)
+    carry = jax.lax.fori_loop(
+        0, full_end, functools.partial(step, False), carry)
+    carry = jax.lax.fori_loop(
+        full_end, jnp.clip(n_live - first, 0, subs),
+        functools.partial(step, True), carry)
+    o_acc[:], l_acc[:], m_acc[:] = carry
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:], 1e-12)).astype(
-            o_ref.dtype)
-
-
-def _flash_kernel_residuals(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
-                            o_acc, l_acc, m_acc, *, block_q: int,
-                            block_k: int, t_valid: int, causal: bool,
-                            scale: float, nk: int):
-    """Same as `_flash_kernel` but also emits the softmax residuals
-    (row sum l and row max m) so partial results over disjoint key sets can
-    be merged exactly (`merge_attention_partials`) — the ring-attention
-    building block."""
-    j = pl.program_id(2)
-    _flash_kernel(q_ref, k_ref, v_ref, o_ref, o_acc, l_acc, m_acc,
-                  block_q=block_q, block_k=block_k, t_valid=t_valid,
-                  causal=causal, scale=scale, nk=nk)
-
-    @pl.when(j == nk - 1)
-    def _emit_residuals():
+        o = o_acc[:] / jnp.maximum(l_acc[:], 1e-12)             # [D, bq]
+        o_ref[0] = o.T.astype(o_ref.dtype)
         l_ref[0] = l_acc[:]
         m_ref[0] = m_acc[:]
 
@@ -154,64 +190,121 @@ def merge_attention_partials(a, b):
     return o.astype(o_a.dtype), l, new_m
 
 
+def _pick_block(n: int, block: Optional[int]) -> int:
+    """A block length for an axis of n: the caller's, else the largest of
+    `_TILES` that divides n (one block of the whole axis where n is shorter
+    than the smallest)."""
+    if block is not None:
+        return min(block, max(n, 1))
+    return next((s for s in _TILES if n % s == 0), min(n, _TILES[-1]))
+
+
+def _kv_block(tk: int, block_k: int, d: int, itemsize: int) -> int:
+    """Keys a grid step holds in VMEM: the whole axis when K and V fit
+    `_KV_VMEM_BUDGET`, else the most whole sub-blocks that do and that
+    divide the axis."""
+    per_key = 4 * -(-d // _LANES) * _LANES * itemsize
+    subs = tk // block_k
+    fit = max(1, _KV_VMEM_BUDGET // (per_key * block_k))
+    return block_k * max(n for n in range(1, subs + 1)
+                         if subs % n == 0 and n <= fit)
+
+
+def _note_trace(path: str, block_q: int = 0, block_k: int = 0,
+                kv_resident: bool = False) -> None:
+    """Counts, as a call is traced, which path it took and with which
+    tile (docs/OBSERVABILITY.md)."""
+    _metrics.counter(
+        "fedml_attention_traces_total",
+        "flash-attention calls traced, by the path and tile they took",
+        labels=("path", "block_q", "block_k", "kv_resident"),
+    ).labels(path=path, block_q=block_q, block_k=block_k,
+             kv_resident=str(kv_resident).lower()).inc()
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "block_q", "block_k", "block_kv", "t_valid", "interpret"))
+def _flash_call(q, k, v, *, causal: bool, block_q: int, block_k: int,
+                block_kv: int, t_valid: int, interpret: bool):
+    """`_flash_kernel` over [B, H, T, D].  Under its own `jit`: a program
+    that calls it once a layer, and again under remat and autodiff, traces
+    and lowers the kernel once (PERF.md section 6, PRs 25 and 31)."""
+    b, h, t, d = q.shape
+    tk = k.shape[2]
+
+    def q_map(bi, i, j):
+        return bi, i, 0
+
+    def row_map(bi, i, j):      # the residuals: one lane-dense row a head
+        return bi, 0, i
+
+    def kv_map(bi, i, j):
+        if causal:      # a step above the diagonal keeps the last live block
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_kv)
+        return bi, j, 0
+
+    out, l, m = pl.pallas_call(
+        functools.partial(_flash_kernel, block_k=block_k, t_valid=t_valid,
+                          causal=causal, scale=1.0 / float(d) ** 0.5),
+        grid=(b * h, t // block_q, tk // block_kv),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_kv, d), kv_map),
+            pl.BlockSpec((1, block_kv, d), kv_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, 1, block_q), row_map),
+            pl.BlockSpec((1, 1, block_q), row_map),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32),
+                        pltpu.VMEM((1, block_q), jnp.float32),
+                        pltpu.VMEM((1, block_q), jnp.float32)],
+        interpret=interpret,
+        # the kernel's name in a device trace; the blockwise backward is
+        # plain jnp and has none there
+        name="flash_fwd",
+    )(q.reshape(b * h, t, d), k.reshape(b * h, tk, d),
+      v.reshape(b * h, tk, d))
+    return (out.reshape(b, h, t, d), l.reshape(b, h, t),
+            m.reshape(b, h, t))
+
+
 def flash_attention_residuals(q: jnp.ndarray, k: jnp.ndarray,
                               v: jnp.ndarray, causal: bool = True,
-                              block_q: int = 128, block_k: int = 128,
+                              block_q: Optional[int] = None,
+                              block_k: Optional[int] = None,
                               interpret: Optional[bool] = None,
                               t_valid: Optional[int] = None):
     """Like `flash_attention` but also returns the softmax residuals
     (l, m) [B, H, T] so callers can merge partial attentions over disjoint
     key sets (`merge_attention_partials`) — the ring-attention block op.
     Requires block-aligned lengths (ring blocks are); the key length may
-    differ from the query length for non-causal partials."""
-    b, h, t, d = q.shape
-    tk = k.shape[2]
+    differ from the query length for non-causal partials.
+
+    `block_q` is the queries of a grid step and `block_k` the keys of one
+    pass of its inner loop; left out, both follow the shape."""
+    t, tk = q.shape[2], k.shape[2]
     if t_valid is None:
         t_valid = tk
-    if interpret is None:
-        if not _on_tpu():
-            return _reference_residuals(q, k, v, causal, t_valid)
+    if interpret is None and _on_tpu():
         interpret = False
-
-    block_q = min(block_q, max(t, 1))
-    block_k = min(block_k, max(tk, 1))
-    if t % block_q or tk % block_k or (causal and tk != t):
+    block_q = _pick_block(t, block_q)
+    block_k = _pick_block(tk, block_k)
+    if (interpret is None or t % block_q or tk % block_k
+            or (causal and tk != t)):
+        _note_trace("reference")
         return _reference_residuals(q, k, v, causal, t_valid)
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, tk, d)
-    vf = v.reshape(b * h, tk, d)
-    nk = tk // block_k
-    kernel = functools.partial(
-        _flash_kernel_residuals, block_q=block_q, block_k=block_k,
-        t_valid=t_valid, causal=causal, scale=1.0 / float(d) ** 0.5, nk=nk)
-    out, l, m = pl.pallas_call(
-        kernel,
-        grid=(b * h, t // block_q, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bi, i, j: (bi, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bi, i, j: (bi, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bi, i, j: (bi, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32)],
-        interpret=interpret,
-        # the kernel's name in a device trace; the blockwise backward is
-        # plain jnp and has none there
-        name="flash_fwd",
-    )(qf, kf, vf)
-    return (out.reshape(b, h, t, d), l.reshape(b, h, t),
-            m.reshape(b, h, t))
+    block_kv = _kv_block(tk, block_k, q.shape[3], q.dtype.itemsize)
+    _note_trace("kernel", block_q, block_k, kv_resident=block_kv == tk)
+    return _flash_call(q, k, v, causal=causal, block_q=block_q,
+                       block_k=block_k, block_kv=block_kv, t_valid=t_valid,
+                       interpret=interpret)
 
 
 def flash_mha(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -269,7 +362,7 @@ def _flash_backward_blockwise(q, k, v, o, l, m, do, causal: bool,
 
 @functools.lru_cache(maxsize=64)
 def _flash_core(causal: bool, block_q: int, block_k: int,
-                interpret: Optional[bool], t_valid: int):
+                interpret: bool, t_valid: int):
     """custom_vjp-wrapped flash attention on block-aligned [B, H, T, D]:
     pallas kernel forward (saves softmax residuals), blockwise-jnp exact
     backward — so the kernel path is trainable (ulysses/ring local steps).
@@ -292,21 +385,24 @@ def _flash_core(causal: bool, block_q: int, block_k: int,
 
     def bwd(res, do):
         q, k, v, o, l, m = res
+        # the backward's scores are [B, H, T, block] arrays in HBM: it keeps
+        # 128-key blocks whatever the forward's loop takes at a pass
         return _flash_backward_blockwise(
             q, k, v, o, l, m, do, causal=causal, t_valid=t_valid,
-            block_k=min(block_k, k.shape[2]))
+            block_k=_LANES if block_k % _LANES == 0 else block_k)
 
     f.defvjp(fwd, bwd)
     return f
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    causal: bool = True, block_q: int = 128,
-                    block_k: int = 128,
+                    causal: bool = True, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Exact attention on [B, H, T, D] via the flash recurrence.
 
-    T is padded internally to the block size; padded keys are masked out and
+    T is padded internally to the block size (left out, the blocks follow
+    the shape: `flash_attention_residuals`); padded keys are masked out and
     padded query rows sliced off, so any T works.  Differentiable: the
     forward runs the pallas kernel, the backward is the exact blockwise
     recomputation (`_flash_backward_blockwise`).
@@ -314,11 +410,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     b, h, t, d = q.shape
     if interpret is None:
         if not _on_tpu():
+            _note_trace("reference")
             return _reference(q, k, v, causal)
         interpret = False
 
-    block_q = min(block_q, max(t, 1))
-    block_k = min(block_k, max(t, 1))
+    # default blocks are chosen over whole 128-position tiles of a long T
+    t_tiles = t if t <= _LANES else -(-t // _LANES) * _LANES
+    block_q = _pick_block(t_tiles, block_q)
+    block_k = _pick_block(t_tiles, block_k)
     t_pad = -(-t // block_q) * block_q
     t_pad = -(-t_pad // block_k) * block_k
     pad = t_pad - t

@@ -85,3 +85,40 @@ def make_args(**kw):
 @pytest.fixture
 def args_factory():
     return make_args
+
+
+def rounded_flash_reference(q, k, v, causal, block_k, t_valid=None):
+    """The flash recurrence in plain jnp with the kernel's rounding, for the
+    tight comparisons: q x scale, k, v and p reach the two products as
+    bfloat16, everything else is float32, and the keys are taken `block_k`
+    at a pass (p is rounded against the running max, so the pass length is
+    part of the arithmetic).  Whole [B, H, T, D] arrays, no tiling of the
+    queries, no skipped pass.  Returns (o, l, m)."""
+    import jax.numpy as jnp
+
+    t, tk, d = q.shape[2], k.shape[2], q.shape[3]
+    t_valid = tk if t_valid is None else t_valid
+    bf, f32 = jnp.bfloat16, jnp.float32
+    qs = (q.astype(f32) * (1.0 / float(d) ** 0.5)).astype(bf)
+    o = jnp.zeros(q.shape, f32)
+    l = jnp.zeros(q.shape[:3] + (1,), f32)
+    m = jnp.full(q.shape[:3] + (1,), -1e30, f32)
+    q_pos = jnp.arange(t)[:, None]
+    for start in range(0, tk, block_k):
+        rows = slice(start, start + block_k)
+        k_pos = jnp.arange(start, start + block_k)[None, :]
+        mask = k_pos < t_valid
+        if causal:
+            mask = mask & (q_pos >= k_pos)
+        s = jnp.einsum("bhqd,bhkd->bhqk", qs, k[:, :, rows].astype(bf),
+                       preferred_element_type=f32)
+        s = jnp.where(mask, s, -1e30)
+        new_m = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - new_m), 0.0)
+        alpha = jnp.exp(m - new_m)
+        l = l * alpha + p.sum(-1, keepdims=True)
+        o = o * alpha + jnp.einsum(
+            "bhqk,bhkd->bhqd", p.astype(bf), v[:, :, rows].astype(bf),
+            preferred_element_type=f32)
+        m = new_m
+    return (o / jnp.maximum(l, 1e-12)).astype(q.dtype), l[..., 0], m[..., 0]

@@ -15,6 +15,7 @@ library, and every xdist worker imports every test file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,8 +73,15 @@ def _assert_kernel(text):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_flash_attention_compiles_for_v5e(one_chip, grad):
-    """GPT-2-small attention at the SFT smoke's shape: [4, 12, 1024, 64]."""
+@pytest.mark.parametrize("qkv", [
+    ((4, 12, 1024, 64), jnp.bfloat16),    # GPT-2 small, the SFT smoke
+    ((4, 20, 1024, 64), jnp.float32),     # GPT-2 large, the cell `sft.lora_1k`
+], ids=["small-bf16", "large-f32"])
+def test_flash_attention_compiles_for_v5e(one_chip, qkv, grad):
+    """The training attention at the smoke's and at the cell's shape.  The
+    program holds one kind of Mosaic kernel and it is `flash_fwd`: the
+    benchmark's `flash_fwd_ms_per_step` sums every `tpu_custom_call` of the
+    epoch program, so a second kernel must not slip in unnoticed."""
     attn = functools.partial(flash_attention, causal=True, interpret=False)
     fn = attn
     if grad:
@@ -81,8 +89,11 @@ def test_flash_attention_compiles_for_v5e(one_chip, grad):
             return jax.grad(
                 lambda *a: attn(*a).astype(jnp.float32).sum(),
                 argnums=(0, 1, 2))(q, k, v)
-    qkv = ((4, 12, 1024, 64), jnp.bfloat16)
-    _assert_kernel(_compile_text(fn, one_chip, qkv, qkv, qkv))
+    text = _compile_text(fn, one_chip, qkv, qkv, qkv)
+    kernels = {m.group(1) for m in re.finditer(
+        r"%([\w.-]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+    assert len(kernels) == 1 and "flash_fwd" in kernels.pop(), kernels
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
