@@ -147,6 +147,32 @@ def create(args: Any, output_dim: Optional[int] = None) -> ModelBundle:
             heads=int(getattr(args, "lm_heads", 4) or 4),
             max_len=int(getattr(args, "lm_max_len", 256) or 256))
         task = TASK_LM
+    elif name == "routed_lm":
+        # the same functional LM under another description: RMSNorm, grouped
+        # heads, rotary or no positions and a window by layer, routed ReGLU
+        # experts of which this chip holds `lm_experts_held` from
+        # `lm_first_held` on, an untied head.  The two layouts give a layer
+        # 1 where it rotates q and k / looks back `lm_window` positions only
+        from ..ops.routed_experts import Experts
+        from .functional_lm import Layer, RoutedLMModule
+
+        experts = Experts(
+            total=int(args.lm_experts), held=int(args.lm_experts_held),
+            first_held=int(getattr(args, "lm_first_held", 0) or 0),
+            top_k=int(args.lm_top_k))
+        module = RoutedLMModule(
+            vocab=num_classes, dim=int(args.lm_dim),
+            heads=int(args.lm_heads), ffn=int(args.lm_ffn),
+            layers=[Layer(
+                norm="rmsnorm", eps=float(args.lm_norm_eps),
+                kv_heads=int(args.lm_kv_heads),
+                head_dim=int(args.lm_head_dim),
+                rope_theta=float(args.lm_rope_theta) if rotates else None,
+                window=int(args.lm_window) if windowed else None,
+                experts=experts)
+                for rotates, windowed in zip(args.lm_rope_layout,
+                                             args.lm_window_layout)])
+        task = TASK_LM
     elif name in ("vit", "vit_tiny", "vit-tiny"):
         module = ViT(num_classes=num_classes, dtype=dtype,
                      layers=int(getattr(args, "vit_layers", 6)))

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ...core.mlops import metrics as _metrics
 from ...core.mlops import tracing
 from ...ml.engine.model_bundle import ModelBundle, masked_loss
 from .lora import _path_str, apply_lora, count_trainable, init_lora
@@ -88,6 +89,22 @@ def pack_sequences(token_ids: np.ndarray, seq_len: int,
 def format_prompt(instruction: str, response: str = "") -> str:
     """Alpaca-style template (reference `dataset_utils.py` prompt format)."""
     return (f"### Instruction:\n{instruction}\n\n### Response:\n{response}")
+
+
+def _note_picks(counted: Dict[str, Any]) -> None:
+    """A routed model's counts of one epoch program, onto the process's
+    counters (docs/OBSERVABILITY.md)."""
+    for key, name, what in (
+            ("picks", "fedml_moe_picks_total",
+             "expert picks routed, over tokens, layers and steps"),
+            ("picks_held", "fedml_moe_picks_held_total",
+             "expert picks that landed on an expert this chip holds"),
+            ("expert_picks_max", "fedml_moe_expert_picks_max",
+             "picks of the heaviest held expert of each step, summed")):
+        if key in counted:
+            # on the host already: `train` fetched it with the loss
+            _metrics.counter(name, what).inc(
+                float(counted[key]))  # fedml: noqa[JAX003]
 
 
 class LLMTrainer:
@@ -157,13 +174,20 @@ class LLMTrainer:
         tx = self.tx
         mesh = self.mesh
 
+        # a model whose [B, T, V] logits are too large to make takes its own
+        # loss (in row blocks), and hands back what it counted on the way
+        own_loss = getattr(bundle.module, "loss", None)
+
         def loss_fn(trainable, base_params, model_state, batch, rng):
             params = (apply_lora(base_params, trainable, cfg.lora_alpha)
                       if use_lora else trainable)
             variables = dict(model_state, params=params)
+            if own_loss is not None:
+                return own_loss(variables, batch["x"], batch["y"],
+                                batch["mask"])
             logits, _ = bundle.apply(variables, batch["x"], train=True,
                                      rng=rng)
-            return masked_loss("lm", logits, batch["y"], batch["mask"])
+            return masked_loss("lm", logits, batch["y"], batch["mask"]), {}
 
         # the name is the program's in a device trace: ``jit_sft_epoch``
         def sft_epoch(trainable, opt_state, base_params, model_state,
@@ -198,15 +222,22 @@ class LLMTrainer:
                 trainable, opt_state, rng = carry
                 rng, sub = jax.random.split(rng)
                 batch = jax.tree_util.tree_map(lambda b: b[i], batches)
-                loss, grads = jax.value_and_grad(loss_fn)(
-                    trainable, base_params, model_state, batch, sub)
+                (loss, counted), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(
+                        trainable, base_params, model_state, batch, sub)
                 updates, opt_state = tx.update(grads, opt_state, trainable)
                 trainable = optax.apply_updates(trainable, updates)
-                return (trainable, opt_state, rng), loss
+                return (trainable, opt_state, rng), (loss, counted)
 
-            (trainable, opt_state, _), losses = jax.lax.scan(
+            (trainable, opt_state, _), (losses, counted) = jax.lax.scan(
                 step, (trainable, opt_state, rng), jnp.arange(nb))
-            return trainable, opt_state, jnp.mean(losses)
+            loss = jnp.mean(losses)
+            if counted:
+                # what the model counted rides with the loss, summed over
+                # the call's steps: one fetch, as for the loss alone
+                loss = dict(jax.tree_util.tree_map(jnp.sum, counted),
+                            loss=loss)
+            return trainable, opt_state, loss
 
         return sft_epoch
 
@@ -282,7 +313,11 @@ class LLMTrainer:
             # one deliberate sync per EPOCH (not per step): the scalar gates
             # logging/checkpointing, and the scan above has already retired
             with phase("fedml.sft.loss_fetch") as fetch:
-                loss_host = float(loss)  # fedml: noqa[JAX003] — epoch boundary
+                got = jax.device_get(loss)  # fedml: noqa[JAX003] — epoch boundary
+            if isinstance(got, dict):
+                _note_picks(got)
+                got = got["loss"]
+            loss_host = float(got)  # fedml: noqa[JAX003] — fetched above
             history.append(loss_host)
             logging.info("llm epoch %d: loss %.4f (%.1fs)", ep, loss_host,
                          epoch.dur_s + fetch.dur_s)

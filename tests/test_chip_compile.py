@@ -276,6 +276,57 @@ def test_kv_store_compiles_at_ragged_lengths_for_v5e(one_chip, monkeypatch,
     _assert_kernel(text)
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_grouped_window_attention_compiles_for_v5e(one_chip, window, grad):
+    """The attention of the cell `sft.smallthinker_lora_16k`: 7 query heads
+    over 1 key/value head of 128 at 16,384 positions, fully causal and under
+    a 4096 window.  K and V of that length stay in VMEM as one block (32 MiB
+    double-buffered, over the compiler's default limit, which the call
+    raises); the backward is the tiled one."""
+    attn = functools.partial(flash_attention, causal=True, interpret=False,
+                             window=window)
+    fn = attn
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: attn(*a).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+    q, kv = ((1, 7, 16384, 128), jnp.float32), ((1, 1, 16384, 128),
+                                                jnp.float32)
+    text = _compile_text(fn, one_chip, q, kv, kv)
+    assert "flash_fwd" in text
+    _assert_kernel(text)
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("k,n", [(2560, 1536), (768, 2560)],
+                         ids=["gate_up", "down"])
+def test_expert_products_compile_for_v5e(one_chip, k, n, transposed):
+    """`moe_experts` at the same cell's size: the worst case's rows (every
+    pick of 16,384 tokens on the 16 held experts, in tiles of 256) against
+    float32 [16, K, N] matrices, one whole matrix a grid step in VMEM with
+    its bfloat16 copy."""
+    from fedml_tpu.ops import routed_experts as rex
+
+    experts = rex.Experts(total=64, held=16, first_held=0, top_k=6)
+    plan = jax.eval_shape(lambda p: rex.plan_rows(p, experts),
+                          jax.ShapeDtypeStruct((16384, 6), jnp.int32))
+    rows = plan.real.shape[0]
+    assert rows == (16384 * 6 + 16 * 256) + 256
+
+    def fn(x, w, tile_expert, live_tiles):
+        return rex._experts_call(x, w, tile_expert, live_tiles,
+                                 transposed=transposed, interpret=False)
+
+    text = _compile_text(
+        fn, one_chip, ((rows, n if transposed else k), jnp.bfloat16),
+        ((16, k, n), jnp.float32), (plan.tile_expert.shape, jnp.int32),
+        ((1,), jnp.int32))
+    assert ("moe_experts_t" if transposed else "moe_experts") in text
+    _assert_kernel(text)
+
+
 def test_resnet56_constants_match_the_model():
     """The shapes above are ResNet-56's, not a guess: every leaf shape of
     the model is in the list and the flat size is the model's."""
