@@ -145,10 +145,12 @@ def _expert_mlp(y, h_in, blk, experts: Experts):
     the experts read ``y``.  Also how the layer's picks fell."""
     picks, weights = route(h_in.reshape(-1, h_in.shape[-1]), blk["router"],
                            experts.top_k)
-    out, counts = held_experts(y.reshape(-1, y.shape[-1]), picks, weights,
-                               blk["w_gate_up"], blk["w_down"], experts)
+    out, counts, rows_passed = held_experts(
+        y.reshape(-1, y.shape[-1]), picks, weights, blk["w_gate_up"],
+        blk["w_down"], experts)
     stats = {"picks": jnp.asarray(picks.size, jnp.int32),
              "picks_held": jnp.sum(counts),
+             "rows_passed": rows_passed,
              "expert_picks_max": jnp.max(counts)}
     return out.reshape(y.shape).astype(y.dtype), stats
 
@@ -417,8 +419,9 @@ class RoutedLMModule:
              ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """Masked mean next-token loss of [B, T] tokens ``x`` against ``y``,
         and how the picks fell: ``picks`` (all of them), ``picks_held``
-        (those on held experts), ``expert_picks_max`` (the heaviest held
-        expert of any layer)."""
+        (those on held experts), ``rows_passed`` (the rows the expert
+        layers' passes went over for them), ``expert_picks_max`` (the
+        heaviest held expert of any layer)."""
         params = variables["params"]
         b, t = x.shape
         kept = len(self.layers) * _KEPT_PER_BLOCK * b * t * self.dim * 4

@@ -6,19 +6,29 @@ experts, computes the weighted outputs of experts ``first_held ..
 first_held + held`` for the tokens that picked them, and adds nothing for
 picks that landed elsewhere (on one chip the layer runs without its
 exchange; nothing here stands in for absent chips).  No capacity, no dropped
-token: the rows are laid out for the worst case, every pick of every token
-on a held expert.
+token.
 
 How: the picks that landed here are sorted by expert (`plan_rows`), each
 expert's rows start at a tile boundary, and one Pallas kernel,
 ``moe_experts``, multiplies every tile of rows by its expert's matrix
 (`grouped_matmul`): a grid step a tile, the expert's matrix fetched when the
-expert changes and rounded to bfloat16 once, dead tiles (the worst case's
-room) neither fetched nor computed.  Rows are gathered in and out by index,
-forward and backward alike (a gather's transpose is written as the other
-gather, never as a scatter).  The router's product and softmax are float32
-at ``highest`` precision, so that picks differ from a float32 reference's
-only where the residual streams do.
+expert changes and rounded to bfloat16 once.  Rows are gathered in and out
+by index, forward and backward alike (a gather's transpose is written as the
+other gather, never as a scatter).  The router's product and softmax are
+float32 at ``highest`` precision, so that picks differ from a float32
+reference's only where the residual streams do.
+
+What is laid out for the worst case, every pick of every token on a held
+expert: the allocation of every array of rows (``(tiles + 1) * TILE`` of
+them, a static shape) and the kernels' grid, whose dead steps neither fetch
+nor compute.  What follows the count: every pass over rows outside the
+kernels (the plan's own row-wise part, the gather that lays tokens out, the
+elementwise work between the products) is a loop over the chunks of
+`CHUNK_TILES` tiles that hold a landed row (`_over_live_chunks`), written in
+place into a buffer of the worst case's shape whose dead part nothing
+writes and nothing reads (`_row_buffer`).  When every pick lands the loop
+goes over every chunk: the worst case is the same computation, not another
+path.
 
 Paths as the other kernels of `fedml_tpu.ops`: on TPU the kernel; off TPU
 with ``interpret=True`` the same kernel through the Pallas interpreter;
@@ -47,6 +57,12 @@ _OPERAND = jnp.bfloat16
 #: expert's matrix, and the boundary an expert's rows start at
 TILE = 256
 
+#: tiles of a chunk: what one trip of a row-wise pass goes over.  Half a
+#: chunk a pass goes over for nothing, and a trip costs next to nothing
+#: beside its 2,048 rows; at 16 tiles and more XLA sums a row's dot in
+#: another order than it does over the whole layout
+CHUNK_TILES = 8
+
 
 class Experts(NamedTuple):
     """The routed-expert layer of a model, and this chip's share of it."""
@@ -60,7 +76,9 @@ class Experts(NamedTuple):
 class Plan(NamedTuple):
     """Where each pick that landed on a held expert is computed.  Rows are
     those of the sorted, tile-padded layout, ``(tiles + 1) * TILE`` of them:
-    the last tile is never live and takes the dead steps' output."""
+    the last tile is never live and takes the dead steps' output.  The
+    arrays over rows are written up to the last live chunk and zero beyond
+    it, which is what a row that computes nothing reads anyway."""
 
     token_of_row: jax.Array     # [M] the token a row computes (0 where none)
     pick_of_row: jax.Array      # [M] its pick, flat over [N, top_k]
@@ -85,6 +103,80 @@ def route(h: jax.Array, w_router: jax.Array,
     return picks, jax.nn.softmax(top, axis=-1)
 
 
+# ---------------------------------------------------------------------------
+# passes over rows: the chunks that hold a landed row, in place
+# ---------------------------------------------------------------------------
+
+def _row_buffer(shape, dtype):
+    """Room for the worst case's rows, written by nobody yet: a pass writes
+    the live chunks of it and the rest is never read, so making it costs no
+    pass over the worst case."""
+    return jax.lax.empty(shape, dtype)
+
+
+def _chunk(a, start, rows: int):
+    return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
+
+
+def _put(buf, start, chunk):
+    """``chunk`` into ``buf`` from row ``start`` on.  Rows of some width are
+    kept by tiles, [tiles, TILE, width], and updated by whole tiles: an
+    offset on the leading axis is one the compiler can write at in place,
+    where a row offset it cannot prove aligned costs a copy of the chunk."""
+    if buf.ndim == 3:
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, chunk.reshape((-1,) + buf.shape[1:]), start // buf.shape[1],
+            0)
+    return jax.lax.dynamic_update_slice_in_dim(buf, chunk, start, 0)
+
+
+def _live_chunks(live_tiles, m: int, tile: int):
+    """The rows of a chunk, and how many chunks of a layout of ``m`` rows
+    it takes to hold every live tile."""
+    rows = min(m, CHUNK_TILES * tile)
+    return rows, -(-live_tiles[0] * tile // rows)
+
+
+def _over_live_chunks(live_tiles, m: int, tile: int, body, bufs):
+    """``body(start, rows, bufs) -> bufs`` once for every chunk of ``rows``
+    rows, of ``m`` laid out, that holds a live tile; ``bufs`` are arrays of
+    ``m`` rows that the body updates in place at ``start``.  The last
+    chunk of the worst case is moved back to end with the layout (a row's
+    value depends on the row alone, so the rows it passes over twice are
+    written twice the same)."""
+    rows, chunks = _live_chunks(live_tiles, m, tile)
+
+    def trip(c, bufs):
+        return body(jnp.minimum(c * rows, m - rows), rows, bufs)
+
+    return jax.lax.fori_loop(0, chunks, trip, bufs)
+
+
+def _layout(plan: Plan):
+    """A plan's ``(live_tiles, rows laid out, rows of a tile)``."""
+    m = plan.real.shape[0]
+    return plan.live_tiles, m, m // plan.tile_expert.shape[0]
+
+
+def _pass_over(plan: Plan, width: int, dtype, chunk_of):
+    """[M, width]: ``chunk_of(start, rows)`` [rows, width] for every chunk up
+    to the last live one, nothing defined beyond."""
+    _, m, tile = _layout(plan)
+
+    def write(start, rows, buf):
+        return _put(buf, start, chunk_of(start, rows))
+
+    return _over_live_chunks(*_layout(plan), write, _row_buffer(
+        (m // tile, tile, width), dtype)).reshape(m, width)
+
+
+def rows_passed(plan: Plan):
+    """The rows each pass over a plan's rows goes over: whole chunks up to
+    the last that holds a landed row."""
+    rows, chunks = _live_chunks(*_layout(plan))
+    return rows * chunks
+
+
 def plan_rows(picks: jax.Array, experts: Experts, tile: int = TILE) -> Plan:
     """Sort the picks that landed on held experts by expert and give each
     expert whole tiles.  Everything is an index computation on
@@ -107,17 +199,32 @@ def plan_rows(picks: jax.Array, experts: Experts, tile: int = TILE) -> Plan:
     tile_expert = jnp.minimum(jnp.searchsorted(
         ends, jnp.arange(tiles + 1) * tile, side="right"), held - 1).astype(
             jnp.int32)
-    row = jnp.arange((tiles + 1) * tile, dtype=jnp.int32)
-    e = tile_expert[row // tile]
-    within = row - begins[e]
-    real = (within < counts[e]) & (row < ends[-1])
-    pick_of_row = jnp.where(
-        real, order[jnp.clip(starts[e] + within, 0, n * k - 1)], 0)
+    # a tile's rows are consecutive sorted picks: row r of tile t computes
+    # sorted pick r + shift[t] while r < limit[t]
+    shift = (starts[:-1] - begins)[tile_expert]
+    limit = jnp.minimum((begins + counts)[tile_expert], ends[-1])
+    m = (tiles + 1) * tile
+    live_tiles = (ends[-1:] // tile).astype(jnp.int32)
+
+    def lay(start, rows, laid):
+        token_of_row, pick_of_row, real = laid
+        row = start + jnp.arange(rows, dtype=jnp.int32)
+        of_tile = lambda a: jnp.repeat(
+            _chunk(a, start // tile, rows // tile), tile)
+        live = row < of_tile(limit)
+        pick = jnp.where(live, order[jnp.clip(
+            row + of_tile(shift), 0, n * k - 1)], 0)
+        return (_put(token_of_row, start, pick // k),
+                _put(pick_of_row, start, pick), _put(real, start, live))
+
+    token_of_row, pick_of_row, real = _over_live_chunks(
+        live_tiles, m, tile, lay,
+        (jnp.zeros((m,), jnp.int32),) * 2 + (jnp.zeros((m,), bool),))
     e_pick = jnp.minimum(key, held - 1)
     row_of_pick = jnp.where(landed, begins[e_pick] + place - starts[e_pick], 0)
-    return Plan(pick_of_row // k, pick_of_row, real,
+    return Plan(token_of_row, pick_of_row, real,
                 row_of_pick.reshape(n, k), landed.reshape(n, k), tile_expert,
-                (ends[-1:] // tile).astype(jnp.int32), group_rows, counts)
+                live_tiles, group_rows, counts)
 
 
 # ---------------------------------------------------------------------------
@@ -218,8 +325,10 @@ def _matrices_grad(a, b, plan: Plan):
 # ---------------------------------------------------------------------------
 
 def _rows_of(y, plan: Plan):
-    """[N, D] -> [M, D]: each row its token's vector."""
-    return jnp.take(y, plan.token_of_row, axis=0, mode="clip")
+    """[N, D] -> [M, D]: each row, up to the last live chunk, its token's
+    vector."""
+    return _pass_over(plan, y.shape[1], y.dtype, lambda start, rows: jnp.take(
+        y, _chunk(plan.token_of_row, start, rows), axis=0, mode="clip"))
 
 
 def _sum_picks(rows, plan: Plan, weights=None):
@@ -252,16 +361,25 @@ def _held_share(y, weights, w_gate_up, w_down, plan: Plan,
     return _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret)[0]
 
 
-def _hidden(gate_up):
+def _relu_glu(gate_up):
     gate, up = jnp.split(gate_up, 2, axis=1)
     return (jax.nn.relu(gate) * up).astype(_OPERAND)
+
+
+def _hidden(gate_up, plan: Plan):
+    """[M, 2F] -> [M, F]: ``relu(gate) * up`` of the rows up to the last
+    live chunk."""
+    return _pass_over(
+        plan, gate_up.shape[1] // 2, _OPERAND,
+        lambda start, rows: _relu_glu(_chunk(gate_up, start, rows)))
 
 
 def _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret):
     # rounded once here: every product's operands are bfloat16
     x = _rows_of(y.astype(_OPERAND), plan)
     gate_up = grouped_matmul(x, w_gate_up, plan, False, interpret)
-    rows = grouped_matmul(_hidden(gate_up), w_down, plan, False, interpret)
+    rows = grouped_matmul(_hidden(gate_up, plan), w_down, plan, False,
+                          interpret)
     return (_sum_picks(rows, plan, weights),
             (x, gate_up, weights, w_gate_up, w_down, plan))
 
@@ -272,25 +390,42 @@ def _held_share_bwd(interpret, res, d_out):
     [M, D] rows of the forward need not be kept: a pick's weight meets its
     row only through ``<d_out, row> = <d_out D^T, hidden>``."""
     x, gate_up, weights, w_gate_up, w_down, plan = res
-    gate, up = jnp.split(gate_up, 2, axis=1)
+    m, f = gate_up.shape[0], gate_up.shape[1] // 2
     d_rows = _rows_of(d_out.astype(_OPERAND), plan)
     by_down = grouped_matmul(d_rows, w_down, plan, True, interpret)  # [M, F]
-    hidden = _hidden(gate_up)
-    dots = jnp.where(plan.real, jnp.sum(by_down * hidden, axis=-1), 0.0)
+
+    def through_hidden(start, rows, bufs):
+        dots, w_row, d_gate_up = bufs
+        real = _chunk(plan.real, start, rows)
+        by, gu = _chunk(by_down, start, rows), _chunk(gate_up, start, rows)
+        gate, up = jnp.split(gu, 2, axis=1)
+        dot = jnp.where(real, jnp.sum(by * _relu_glu(gu), axis=-1), 0.0)
+        w = jnp.where(real, jnp.take(
+            weights.reshape(-1), _chunk(plan.pick_of_row, start, rows),
+            mode="clip"), 0.0)
+        d_hidden = by * w[:, None]
+        d = jnp.concatenate(
+            [jnp.where(gate > 0, d_hidden * up, 0.0),
+             d_hidden * jax.nn.relu(gate)], axis=1).astype(_OPERAND)
+        return (_put(dots, start, dot), _put(w_row, start, w),
+                _put(d_gate_up, start, d))
+
+    # a row's dot and weight are read by index and by the matrices'
+    # gradient: zero where nothing is written
+    _, _, tile = _layout(plan)
+    dots, w_row, d_gate_up = _over_live_chunks(
+        *_layout(plan), through_hidden,
+        (jnp.zeros((m,), jnp.float32),) * 2 + (
+            _row_buffer((m // tile, tile, 2 * f), _OPERAND),))
+    d_gate_up = d_gate_up.reshape(m, 2 * f)
     d_weights = jnp.where(plan.landed, jnp.take(
         dots, plan.row_of_pick.reshape(-1), mode="clip").reshape(
             weights.shape), 0.0)
-    w_row = jnp.where(plan.real, jnp.take(
-        weights.reshape(-1), plan.pick_of_row, mode="clip"), 0.0)
-    d_hidden = by_down * w_row[:, None]
-    d_gate_up = jnp.concatenate(
-        [jnp.where(gate > 0, d_hidden * up, 0.0),
-         d_hidden * jax.nn.relu(gate)], axis=1).astype(_OPERAND)
     d_x = grouped_matmul(d_gate_up, w_gate_up, plan, True, interpret)
     return (_sum_picks(d_x, plan), d_weights.astype(weights.dtype),
             _matrices_grad(x, d_gate_up, plan).astype(w_gate_up.dtype),
-            _matrices_grad(hidden, d_rows * w_row[:, None].astype(
-                _OPERAND), plan).astype(w_down.dtype), None)
+            _matrices_grad(_hidden(gate_up, plan), d_rows * w_row[
+                :, None].astype(_OPERAND), plan).astype(w_down.dtype), None)
 
 
 _held_share.defvjp(_held_share_fwd, _held_share_bwd)
@@ -303,8 +438,9 @@ def held_experts(y, picks, weights, w_gate_up, w_down, experts: Experts,
     `route`, ``w_gate_up`` [held, D, 2F] (an expert's gate columns, then
     its up columns), ``w_down`` [held, F, D].  Returns ``sum over picks e
     held here of w_e * (relu(y G_e) * (y U_e)) D_e`` [N, D] float32, and the
-    picks that landed on each held expert [held].  Differentiable to ``y``,
-    the weights and the matrices."""
-    plan = plan_rows(picks, experts)
+    picks that landed on each held expert [held], and the rows each of the
+    layer's passes went over.  Differentiable to ``y``, the weights and the
+    matrices."""
+    plan = plan_rows(picks, experts, TILE)
     return (_held_share(y.astype(jnp.float32), weights, w_gate_up, w_down,
-                        plan, interpret), plan.counts)
+                        plan, interpret), plan.counts, rows_passed(plan))

@@ -99,6 +99,8 @@ def _note_picks(counted: Dict[str, Any]) -> None:
              "expert picks routed, over tokens, layers and steps"),
             ("picks_held", "fedml_moe_picks_held_total",
              "expert picks that landed on an expert this chip holds"),
+            ("rows_passed", "fedml_moe_rows_passed_total",
+             "rows the expert layers' passes went over for the landed picks"),
             ("expert_picks_max", "fedml_moe_expert_picks_max",
              "picks of the heaviest held expert of each step, summed")):
         if key in counted:

@@ -319,8 +319,8 @@ def test_no_token_is_dropped_when_all_pick_one_expert(
     picks = jnp.stack([jnp.full((n,), 5), 4 + 2 * (jnp.arange(n) % 2)], -1)
     weights = jnp.asarray(np.random.RandomState(9).dirichlet([1, 1], n),
                           jnp.float32)
-    got, counts = rex.held_experts(y, picks, weights, w_gate_up, w_down,
-                                   experts, interpret)
+    got, counts, _ = rex.held_experts(y, picks, weights, w_gate_up, w_down,
+                                      experts, interpret)
     assert counts.tolist() == [n // 2, n, n // 2, 0]
     want = _dense_share(y, picks, weights, w_gate_up, w_down, experts)
     assert float(jnp.max(jnp.abs(got - want))) < 1e-5 * float(
@@ -354,6 +354,131 @@ def test_held_share_and_its_gradients_match_the_dense_layer(
     for g, w in zip(got, want):
         assert float(jnp.max(jnp.abs(g - w))) < 1e-4 * float(
             jnp.max(jnp.abs(w)))
+
+
+def _picks_that_fall(how, n, experts):
+    """[n, top_k] picks for a case of the test below; ``chunk`` rows a
+    chunk, ``TILE`` 16 and 4 held experts of 16 from the fourth on."""
+    k, first, held = experts.top_k, experts.first_held, experts.held
+    chunk = rex.CHUNK_TILES * rex.TILE
+    picks = np.zeros((n, k), np.int64)           # expert 0: held by another
+    if how == "none":
+        return picks
+    if how == "a quarter":
+        return np.random.RandomState(13).randint(0, experts.total, (n, k))
+    if how == "all on one":
+        picks[:] = np.arange(k) + (first + held) % experts.total
+        picks[:, 0] = first + 1
+        return picks
+    if how == "every pick":
+        return first + np.stack([np.random.RandomState(i).permutation(held)[:k]
+                                 for i in range(n)])
+    # one expert's rows: a whole number of chunks, or one row more
+    picks[:chunk + (how == "a row past a chunk"), 0] = first + 2
+    return picks
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["ragged", "kernel"])
+@pytest.mark.parametrize("how", ["none", "a quarter", "a whole chunk",
+                                 "a row past a chunk", "all on one",
+                                 "every pick"])
+def test_share_and_gradients_match_the_dense_layer_however_the_picks_fall(
+        monkeypatch, interpret, how, float32_experts):
+    """The passes follow the rows that landed: nothing landed, a quarter,
+    live rows that end on a chunk and one row past it, every token on one
+    held expert, and the worst case the rows are laid out for (every pick
+    of every token held here).  Output and the gradients to the tokens, the
+    weights and the matrices, against every held expert over every token."""
+    monkeypatch.setattr(rex, "TILE", 16)
+    monkeypatch.setattr(rex, "CHUNK_TILES", 4)
+    every = how == "every pick"
+    experts = (rex.Experts(total=4, held=4, first_held=0, top_k=3) if every
+               else rex.Experts(total=16, held=4, first_held=4, top_k=3))
+    y, w_gate_up, w_down = _layer(14)
+    n = y.shape[0]
+    picks = jnp.asarray(_picks_that_fall(how, n, experts))
+    weights = jnp.asarray(np.random.RandomState(14).dirichlet([1] * 3, n),
+                          jnp.float32)
+    landed = int(np.sum((np.asarray(picks) >= experts.first_held)
+                        & (np.asarray(picks) < experts.first_held + 4)))
+    assert landed == {"none": 0, "a whole chunk": 64, "a row past a chunk": 65,
+                      "all on one": n, "every pick": 3 * n}.get(how, landed)
+
+    def run(share, y, weights, w_gate_up, w_down):
+        return jnp.sum(jnp.sin(share(y, picks, weights, w_gate_up, w_down)))
+
+    program = lambda *a: rex.held_experts(*a, experts, interpret)[0]
+    dense = lambda *a: _dense_share(*a, experts)
+    args = (y, weights, w_gate_up, w_down)
+    _, counts, passed = rex.held_experts(y, picks, weights, w_gate_up, w_down,
+                                         experts, interpret)
+    assert int(counts.sum()) == landed
+    if how in ("none", "a whole chunk", "a row past a chunk"):
+        assert int(passed) == {"none": 0, "a whole chunk": 64,
+                               "a row past a chunk": 128}[how]
+    got = jax.grad(functools.partial(run, program), argnums=range(4))(*args)
+    want = jax.grad(functools.partial(run, dense), argnums=range(4))(*args)
+    assert abs(float(run(program, *args) - run(dense, *args))) < 1e-3
+    for g, w in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * float(
+            jnp.max(jnp.abs(w)))
+
+
+@pytest.mark.parametrize("interpret", [None, True], ids=["ragged", "kernel"])
+def test_rows_that_hold_nothing_never_reach_a_result(monkeypatch, interpret,
+                                                     float32_experts):
+    """The buffers of rows are made without a value and written up to the
+    last live chunk: with NaN where nothing is written, the output and every
+    gradient are finite and the same."""
+    monkeypatch.setattr(rex, "TILE", 16)
+    monkeypatch.setattr(rex, "CHUNK_TILES", 4)
+    experts = rex.Experts(total=16, held=4, first_held=4, top_k=3)
+    y, w_gate_up, w_down = _layer(15)
+    n = y.shape[0]
+    picks = jnp.asarray(np.random.RandomState(15).randint(0, 16, (n, 3)))
+    weights = jnp.asarray(np.random.RandomState(15).dirichlet([1] * 3, n),
+                          jnp.float32)
+
+    def run(y, weights, w_gate_up, w_down):
+        out = rex.held_experts(y, picks, weights, w_gate_up, w_down, experts,
+                               interpret)[0]
+        return jnp.sum(jnp.sin(out)), out
+
+    def readings():
+        jax.clear_caches()
+        grads, out = jax.grad(run, argnums=range(4), has_aux=True)(
+            y, weights, w_gate_up, w_down)
+        return [np.asarray(a) for a in (out,) + grads]
+
+    made = []
+
+    def poisoned(shape, dtype):
+        made.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
+    clean = readings()
+    monkeypatch.setattr(rex, "_row_buffer", poisoned)
+    dirty = readings()
+    # x, hidden (forward); d_rows, d_gate_up, hidden again (backward)
+    assert len(made) >= 4
+    for a, b in zip(clean, dirty):
+        assert np.isfinite(b).all() and np.array_equal(a, b)
+
+
+def test_rows_passed_are_whole_chunks_over_the_landed_picks():
+    """`rows_passed` is what the counted picks call for, chunk by chunk,
+    not the layout's worst case."""
+    experts = rex.Experts(total=64, held=16, first_held=16, top_k=6)
+    picks = jnp.asarray(np.random.RandomState(16).randint(0, 64, (512, 6)))
+    plan = rex.plan_rows(picks, experts, tile=8)
+    chunk = rex.CHUNK_TILES * 8
+    live = int(plan.group_rows.sum())
+    assert int(plan.counts.sum()) <= live < int(plan.counts.sum()) + 16 * 8
+    assert int(rex.rows_passed(plan)) == -(-live // chunk) * chunk
+    assert int(rex.rows_passed(plan)) < plan.real.shape[0] // 3
+    # beyond them the plan's rows are as rows that compute nothing
+    assert not np.asarray(plan.real)[live:].any()
+    assert not np.asarray(plan.token_of_row)[live:].any()
 
 
 def test_plan_gives_every_landed_pick_a_row_of_its_experts_tiles():
@@ -436,6 +561,7 @@ def test_llm_trainer_trains_the_routed_family_and_counts_its_picks():
 
     before = {n: count(n) for n in ("fedml_moe_picks_total",
                                     "fedml_moe_picks_held_total",
+                                    "fedml_moe_rows_passed_total",
                                     "fedml_moe_expert_picks_max")}
     stream = np.tile(np.random.RandomState(0).randint(0, 211, 16), 13)[:T * 6 + 1]
     losses = [trainer.train(stream)["train_loss"] for _ in range(6)]
@@ -444,6 +570,10 @@ def test_llm_trainer_trains_the_routed_family_and_counts_its_picks():
     held = count("fedml_moe_picks_held_total") - before[
         "fedml_moe_picks_held_total"]
     assert picks == 6 * 3 * 8 * 2 * T * 3 and 0 < held < picks
+    # whole chunks over the landed picks, of a layout that holds all picks
+    passed = count("fedml_moe_rows_passed_total") - before[
+        "fedml_moe_rows_passed_total"]
+    assert held <= passed and passed % rex.TILE == 0
     assert count("fedml_moe_expert_picks_max") > before[
         "fedml_moe_expert_picks_max"]
     # the epoch program's third result: the loss, the counts beside it
@@ -453,4 +583,5 @@ def test_llm_trainer_trains_the_routed_family_and_counts_its_picks():
         {k: jnp.zeros((2, 2, T), jnp.int32 if k != "mask" else jnp.float32)
          for k in ("x", "y", "mask")}, jax.random.PRNGKey(0))
     trainer.lora = out[0]
-    assert set(out[2]) == {"loss", "picks", "picks_held", "expert_picks_max"}
+    assert set(out[2]) == {"loss", "picks", "picks_held", "rows_passed",
+                           "expert_picks_max"}
