@@ -17,28 +17,62 @@ stacks literally share the pytree.
 
 What a block is made of comes from a description, `Layer`: its norm, whether
 it rotates q and k, the shape of its attention (query heads, key/value heads,
-head size, window) and its MLP (dense GELU, or routed experts of which this
-chip holds a share).  GPT-2 is the default description; a model of another
-family (`routed_lm`: RMSNorm, rotary or no positions by layer, grouped heads,
-windows by layer, routed ReGLU experts, an untied head) is another, through
-the same `block`, `embed`, `head` and `lm_forward`.
+head size, window; or latent attention, `Latent`: q through a low rank, keys
+and values through one shared latent beside one rotary key) and its MLP
+(dense GELU, dense SwiGLU, or routed experts of which this chip holds a
+share, with or without a shared expert beside them).  GPT-2 is the default
+description; a model of another family (`routed_lm`: RMSNorm, rotary or no
+positions by layer, grouped heads or latent attention, windows by layer,
+routed experts behind a softmax or a group-limited sigmoid router, dense
+layers ahead of the routed ones, an untied head, a second head that predicts
+one token further) is another, through the same `block`, `embed`, `head` and
+`lm_forward`.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.routed_experts import Experts, held_experts, route
+from ..ops.routed_experts import (Experts, held_experts, route,
+                                  route_in_groups)
 
 #: LayerNorm epsilon — 1e-5 matches the HF GPT-2 default so imported
 #: checkpoints (`train/llm/weight_import.py`) reproduce reference logits
 LN_EPS = 1e-5
+
+
+class Latent(NamedTuple):
+    """Latent attention: q through rank ``q_rank`` (a norm between its two
+    matrices), keys and values through one latent of ``kv_rank`` (normed)
+    from which every head's ``nope`` key numbers and ``v`` values are made,
+    and one rotary key of ``rope`` numbers that all heads share.  A head's
+    q and k are ``nope + rope`` wide; the rotation turns the last ``rope``
+    of them, by YaRN's frequencies where ``factor`` is over 1."""
+
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    #: YaRN: positions stretched ``factor`` times over ``original`` trained
+    #: ones; pairs that turn more than ``beta_fast`` times in ``original``
+    #: positions keep their frequency, those under ``beta_slow`` turns have
+    #: it divided by ``factor``, a linear ramp between
+    factor: float = 1.0
+    original: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +93,12 @@ class Layer:
     window: Optional[int] = None
     #: routed experts in place of the dense GELU MLP, and this chip's share
     experts: Optional[Experts] = None
+    #: latent attention in place of the three projections ``wq wk wv``
+    latent: Optional[Latent] = None
+    #: width of a dense SwiGLU MLP in place of GELU's two matrices
+    swiglu: Optional[int] = None
+    #: width of a SwiGLU expert that every token crosses, beside the routed
+    shared: Optional[int] = None
 
 
 GPT2 = Layer()
@@ -101,15 +141,76 @@ def _norm(x, g, layer: Layer = GPT2):
     return (x - mu) * jax.lax.rsqrt(var + layer.eps) * g["scale"] + g["bias"]
 
 
-def _rotate(x, theta: float):
-    """Rotary positions on [..., T, H, Dh], position = index along T: the
-    two halves of a head are the pairs' first and second members."""
+def _rope_freq(theta: float, half: int):
+    return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def _rotate(x, freq):
+    """Rotary positions on [..., T, H, Dh], position = index along T, pair i
+    turning ``freq[i]`` a position: the two halves of a head are the pairs'
+    first and second members."""
     t, half = x.shape[-3], x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freq(la: Latent) -> np.ndarray:
+    """The ``rope // 2`` frequencies of a latent layer's rotation (Peng et
+    al. 2023, as the published implementations of this attention have it):
+    ``theta^(-2i/rope)`` up to the pair that turns ``beta_fast`` times in
+    the ``original`` positions (its number rounded down), that over
+    ``factor`` from the pair that turns ``beta_slow`` times (rounded up) on,
+    a linear ramp between."""
+    half = la.rope // 2
+    plain = la.theta ** (-np.arange(half, dtype=np.float64) / half)
+    if la.factor <= 1:
+        return plain.astype(np.float32)
+
+    def pair_that_turns(turns: float) -> float:
+        return la.rope * math.log(la.original / (turns * 2 * math.pi)) / (
+            2 * math.log(la.theta))
+
+    low = max(math.floor(pair_that_turns(la.beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(la.beta_slow)), la.rope - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / la.factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def yarn_softmax_scale(la: Latent) -> float:
+    """What a latent layer's scores are scaled by: ``(nope + rope)^-0.5``
+    times the square of YaRN's ``mscale_all_dim`` term."""
+    return (la.nope + la.rope) ** -0.5 * _yarn_mscale(
+        la.factor, la.mscale_all_dim) ** 2
+
+
+def _latent_qkv(y, blk, heads: int, layer: Layer):
+    """q, k [..., H, nope + rope] and v [..., H, v] of a latent layer from
+    its normed input: the one rotary key lies under every head, and q
+    carries what the layer's scale is over ``(nope + rope)^-0.5``, which is
+    all that ``attend`` applies."""
+    la, lead = layer.latent, y.shape[:-1]
+    q = (_norm(y @ blk["wq_a"], blk["q_norm"], layer) @ blk["wq_b"]).reshape(
+        *lead, heads, la.nope + la.rope)
+    down = y @ blk["wkv_a"]
+    kv = (_norm(down[..., :la.kv_rank], blk["kv_norm"], layer)
+          @ blk["wkv_b"]).reshape(*lead, heads, la.nope + la.v)
+    freq = jnp.asarray(yarn_freq(la))
+    # cos and sin carry mscale / mscale_all_dim's term
+    turn = _yarn_mscale(la.factor, la.mscale) / _yarn_mscale(
+        la.factor, la.mscale_all_dim)
+    q_rope = _rotate(q[..., la.nope:], freq) * turn
+    k_rope = _rotate(down[..., None, la.kv_rank:], freq) * turn
+    fold = yarn_softmax_scale(la) * math.sqrt(la.nope + la.rope)
+    q = jnp.concatenate([q[..., :la.nope], q_rope], axis=-1) * fold
+    k = jnp.concatenate([kv[..., :la.nope], jnp.broadcast_to(
+        k_rope, (*lead, heads, la.rope))], axis=-1)
+    return q, k, kv[..., la.nope:]
 
 
 def _bias(z, blk, key):
@@ -139,12 +240,25 @@ def _dense_mlp(y, blk):
         jax.nn.gelu(_bias(y @ blk["w1"], blk, "b1")) @ blk["w2"], blk, "b2")
 
 
+def _swiglu(y, w_gate_up, w_down):
+    """``(silu(y G) * (y U)) D``, gate and up columns side by side."""
+    gate, up = jnp.split(y @ w_gate_up, 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ w_down
+
+
 def _expert_mlp(y, h_in, blk, experts: Experts):
     """The held experts' share of the routed layer (`ops/routed_experts`).
-    The router reads ``h_in``, the block's input ahead of its first norm;
-    the experts read ``y``.  Also how the layer's picks fell."""
-    picks, weights = route(h_in.reshape(-1, h_in.shape[-1]), blk["router"],
-                           experts.top_k)
+    The router reads what ``experts`` says: ``h_in``, the block's input
+    ahead of its first norm, or ``y``, which the experts read.  Also how the
+    layer's picks fell, and the picks themselves [N, top_k]."""
+    src = y if experts.reads == "normed" else h_in
+    src = src.reshape(-1, src.shape[-1])
+    kept = None
+    if experts.scores == "sigmoid":
+        picks, weights, kept = route_in_groups(
+            src, blk["router"], blk["router_bias"], experts)
+    else:
+        picks, weights = route(src, blk["router"], experts.top_k)
     out, counts, rows_passed = held_experts(
         y.reshape(-1, y.shape[-1]), picks, weights, blk["w_gate_up"],
         blk["w_down"], experts)
@@ -152,13 +266,18 @@ def _expert_mlp(y, h_in, blk, experts: Experts):
              "picks_held": jnp.sum(counts),
              "rows_passed": rows_passed,
              "expert_picks_max": jnp.max(counts)}
-    return out.reshape(y.shape).astype(y.dtype), stats
+    if kept is not None:
+        # tokens that kept the group the held experts lie in
+        stats["tokens_in_held_group"] = jnp.sum(
+            kept[:, experts.first_held // (experts.total // experts.groups)],
+            dtype=jnp.int32)
+    return out.reshape(y.shape).astype(y.dtype), stats, picks
 
 
 def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
           attend: Callable, layer: Layer = GPT2,
-          note: Optional[Callable[[Dict[str, jnp.ndarray]], None]] = None
-          ) -> jnp.ndarray:
+          note: Optional[Callable[[Dict[str, jnp.ndarray], jnp.ndarray],
+                                  None]] = None) -> jnp.ndarray:
     """One pre-norm block over ``h`` [..., D], made as ``layer`` says.
     ``attend(q, k, v)`` takes the three projections as [..., H, Dh] (k and
     v [..., Hk, Dh] under grouped heads) and returns the attention's output
@@ -167,7 +286,9 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
     decode (one position against a cache).  A rotation, where the layer
     has one, takes a row's position from its index along the axis before
     the heads'.  The MLP gets the block's input beside its own (a router
-    reads the former); a routed layer hands ``note`` how its picks fell."""
+    may read either); a routed layer hands ``note`` how its picks fell, and
+    the picks.  A matrix kept below the stream's type is taken up to it at
+    its product."""
     dim = h.shape[-1]
     dh = layer.head_dim or dim // heads
     y = _norm(h, blk["ln1"], layer)
@@ -175,19 +296,25 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
     def proj(w, b, n):
         z = _bias(y @ blk[w], blk, b).reshape(*y.shape[:-1], n, dh)
         if layer.rope_theta is not None and w != "wv":
-            z = _rotate(z, layer.rope_theta)
+            z = _rotate(z, _rope_freq(layer.rope_theta, dh // 2))
         return z
 
-    kv = layer.kv_heads or heads
-    o = attend(proj("wq", "bq", heads), proj("wk", "bk", kv),
-               proj("wv", "bv", kv))
-    a = h + _bias(o.reshape(*h.shape[:-1], heads * dh) @ blk["wo"], blk, "bo")
+    if layer.latent is not None:
+        o = attend(*_latent_qkv(y, blk, heads, layer))
+    else:
+        kv = layer.kv_heads or heads
+        o = attend(proj("wq", "bq", heads), proj("wk", "bk", kv),
+                   proj("wv", "bv", kv))
+    a = h + _bias(o.reshape(*h.shape[:-1], -1) @ blk["wo"], blk, "bo")
     y = _norm(a, blk["ln2"], layer)
     if layer.experts is None:
-        return a + _dense_mlp(y, blk)
-    out, stats = _expert_mlp(y, h, blk, layer.experts)
+        return a + (_dense_mlp(y, blk) if layer.swiglu is None else
+                    _swiglu(y, blk["w_gate_up"], blk["w_down"]))
+    out, stats, picks = _expert_mlp(y, h, blk, layer.experts)
     if note is not None:
-        note(stats)
+        note(stats, picks)
+    if layer.shared is not None:
+        out = out + _swiglu(y, blk["shared_gate_up"], blk["shared_down"])
     return a + out
 
 
@@ -223,6 +350,29 @@ def _over_sequence(attn_fn, layer: Layer) -> Callable:
     return attend
 
 
+def blocks_over(h: jnp.ndarray, blocks: Sequence[Dict[str, Any]], heads: int,
+                attn_fn, remat: bool, layers: Sequence[Layer]
+                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The residual stream [B, T, D] through ``blocks``, whole sequences
+    from position 0, and how the picks of the routed ones fell (nothing
+    where there is none).  A layer's window reaches ``attn_fn`` as
+    ``window=``; ``remat``: each block is made again in the backward pass."""
+
+    def run(h, blk, layer):
+        seen = []
+        h = block(h, blk, heads, _over_sequence(attn_fn, layer), layer,
+                  lambda stats, picks: seen.append(stats))
+        return h, (seen[0] if seen else {})
+
+    if remat:
+        run = jax.checkpoint(run, static_argnums=(2,))
+    stats = {}
+    for blk, layer in zip(blocks, layers):
+        h, seen = run(h, blk, layer)
+        stats = _add_stats(stats, seen)
+    return h, stats
+
+
 def lm_hidden(params: Dict[str, Any], tokens: jnp.ndarray, heads: int,
               attn_fn, remat: bool = False,
               layers: Optional[Sequence[Layer]] = None
@@ -230,22 +380,10 @@ def lm_hidden(params: Dict[str, Any], tokens: jnp.ndarray, heads: int,
     """[B, T] int tokens → the residual stream [B, T, D] after the last
     block, and how the picks of the routed layers fell (nothing for a model
     without any).  ``layers``: each block's description; left out, GPT-2's
-    for all.  A layer's window reaches ``attn_fn`` as ``window=``."""
+    for all."""
     layers = layers or (GPT2,) * len(params["blocks"])
-
-    def run(h, blk, layer):
-        seen = []
-        h = block(h, blk, heads, _over_sequence(attn_fn, layer), layer,
-                  seen.append)
-        return h, (seen[0] if seen else {})
-
-    if remat:
-        run = jax.checkpoint(run, static_argnums=(2,))
-    h, stats = embed(params, tokens), {}
-    for blk, layer in zip(params["blocks"], layers):
-        h, seen = run(h, blk, layer)
-        stats = _add_stats(stats, seen)
-    return h, stats
+    return blocks_over(embed(params, tokens), params["blocks"], heads,
+                       attn_fn, remat, layers)
 
 
 def lm_forward(params: Dict[str, Any], tokens: jnp.ndarray, heads: int,
@@ -306,8 +444,8 @@ class FunctionalLMModule:
 
 
 # ---------------------------------------------------------------------------
-# the routed family: RMSNorm, positions and windows by layer, grouped heads,
-# routed ReGLU experts, an untied head
+# the routed family: RMSNorm, positions and windows by layer, grouped heads or
+# latent attention, routed experts, an untied head, a second head
 # ---------------------------------------------------------------------------
 
 #: rows of the residual stream whose logits are alive at a time when the
@@ -346,37 +484,89 @@ def loss_in_row_blocks(h: jnp.ndarray, w_out: jnp.ndarray, y: jnp.ndarray,
     return total / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-@partial(jax.jit, static_argnames=("vocab", "dim", "heads", "ffn", "layers"))
+def _draws(layer: Layer) -> int:
+    """Matrices `init_routed_params` draws for a block."""
+    mlp = 2 if layer.experts is None else 3 + 2 * (layer.shared is not None)
+    return (5 if layer.latent is not None else 4) + mlp
+
+
+@partial(jax.jit, static_argnames=("vocab", "dim", "heads", "ffn", "layers",
+                                   "mtp", "store"))
 def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
-                       ffn: int, layers: Tuple[Layer, ...]
+                       ffn: int, layers: Tuple[Layer, ...],
+                       mtp: Optional[Layer] = None, store: str = "float32"
                        ) -> Dict[str, Any]:
     """The routed family's parameter pytree, drawn in one program.  A block:
-    ``wq`` [D, H Dh], ``wk``/``wv`` [D, Hk Dh], ``wo`` [H Dh, D], ``router``
-    [D, experts], ``w_gate_up`` [held, D, 2 F] (an expert's gate columns,
-    then its up columns), ``w_down`` [held, F, D], two norms' scales; then
-    the final norm and the untied head ``w_out`` [D, V]."""
-    ks = iter(jax.random.split(key, 2 + 7 * len(layers)))
+    ``wq`` [D, H Dh], ``wk``/``wv`` [D, Hk Dh], ``wo`` [H Dh, D] (a latent
+    layer: ``wq_a`` [D, q_rank], ``wq_b`` [q_rank, H (nope + rope)],
+    ``wkv_a`` [D, kv_rank + rope], ``wkv_b`` [kv_rank, H (nope + v)], ``wo``
+    [H v, D] and the two latents' norms), ``router`` [D, experts] (with a
+    ``router_bias`` [experts] where it scores by sigmoid), ``w_gate_up``
+    [held, D, 2 F] (an expert's gate columns, then its up columns),
+    ``w_down`` [held, F, D], a shared expert's ``shared_gate_up`` [D, 2 F]
+    and ``shared_down`` [F, D] (a dense SwiGLU layer: ``w_gate_up`` [D, 2 W]
+    and ``w_down`` [W, D] alone), two norms' scales; then the final norm and
+    the untied head ``w_out`` [D, V].  ``mtp``: the block of a second head
+    that predicts one token further, the last of ``blocks``, with its
+    joining matrix ``w_eh`` [2 D, D] and three norms under ``"mtp"``.
+    ``store``: the type the matrices
+    that training leaves frozen or merges factors into are kept in; norms'
+    scales, routers and their biases are float32 whatever it is."""
+    every = tuple(layers) + ((mtp,) if mtp is not None else ())
+    ks = iter(jax.random.split(
+        key, 2 + sum(map(_draws, every)) + (mtp is not None)))
 
-    def normal(shape, fan_in):
-        return jax.random.normal(next(ks), shape) / np.sqrt(fan_in)
+    def normal(shape, fan_in, dtype=jnp.dtype(store)):
+        return (jax.random.normal(next(ks), shape)
+                / np.sqrt(fan_in)).astype(dtype)
 
-    blocks = []
-    for layer in layers:
-        dh, kv, ex = layer.head_dim, layer.kv_heads, layer.experts
-        blocks.append({
-            "ln1": {"scale": jnp.ones((dim,))},
-            "wq": normal((dim, heads * dh), dim),
-            "wk": normal((dim, kv * dh), dim),
-            "wv": normal((dim, kv * dh), dim),
-            "wo": normal((heads * dh, dim), heads * dh),
-            "ln2": {"scale": jnp.ones((dim,))},
-            "router": normal((dim, ex.total), dim),
-            "w_gate_up": normal((ex.held, dim, 2 * ffn), dim),
-            "w_down": normal((ex.held, ffn, dim), ffn),
-        })
-    return {"embed": jax.random.normal(next(ks), (vocab, dim)) * 0.02,
-            "blocks": blocks, "ln_f": {"scale": jnp.ones((dim,))},
-            "w_out": normal((dim, vocab), dim)}
+    def scale(n=dim):
+        return {"scale": jnp.ones((n,))}
+
+    def make(layer):
+        dh, kv, ex, la = (layer.head_dim, layer.kv_heads, layer.experts,
+                          layer.latent)
+        blk = {"ln1": scale()}
+        if la is None:
+            blk.update(wq=normal((dim, heads * dh), dim),
+                       wk=normal((dim, kv * dh), dim),
+                       wv=normal((dim, kv * dh), dim),
+                       wo=normal((heads * dh, dim), heads * dh))
+        else:
+            blk.update(
+                wq_a=normal((dim, la.q_rank), dim), q_norm=scale(la.q_rank),
+                wq_b=normal((la.q_rank, heads * (la.nope + la.rope)),
+                            la.q_rank),
+                wkv_a=normal((dim, la.kv_rank + la.rope), dim),
+                kv_norm=scale(la.kv_rank),
+                wkv_b=normal((la.kv_rank, heads * (la.nope + la.v)),
+                             la.kv_rank),
+                wo=normal((heads * la.v, dim), heads * la.v))
+        blk["ln2"] = scale()
+        if ex is None:
+            blk.update(w_gate_up=normal((dim, 2 * layer.swiglu), dim),
+                       w_down=normal((layer.swiglu, dim), layer.swiglu))
+            return blk
+        blk.update(router=normal((dim, ex.total), dim, jnp.float32),
+                   w_gate_up=normal((ex.held, dim, 2 * ffn), dim),
+                   w_down=normal((ex.held, ffn, dim), ffn))
+        if ex.scores == "sigmoid":
+            blk["router_bias"] = jnp.zeros((ex.total,))
+        if layer.shared is not None:
+            blk.update(
+                shared_gate_up=normal((dim, 2 * layer.shared), dim),
+                shared_down=normal((layer.shared, dim), layer.shared))
+        return blk
+
+    params = {"blocks": [make(layer) for layer in every]}
+    if mtp is not None:
+        params["mtp"] = {"norm_e": scale(), "norm_h": scale(),
+                         "w_eh": normal((2 * dim, dim), 2 * dim),
+                         "ln_f": scale()}
+    return dict(params, ln_f=scale(),
+                embed=(jax.random.normal(next(ks), (vocab, dim))
+                       * 0.02).astype(store),
+                w_out=normal((dim, vocab), dim))
 
 
 class RoutedLMModule:
@@ -384,18 +574,33 @@ class RoutedLMModule:
     things more: `loss`, which `train/llm` takes in place of logits and
     `masked_loss` (the vocabulary's loss in row blocks, each block
     rematerialised where the sizes ask for it, the picks' counts beside
-    it), and `picks`, the experts every token picked in every layer."""
+    it), and `picks`, the experts every token picked in every routed layer.
+
+    ``mtp``: the description of one more block, behind the trunk, whose head
+    predicts token i + 2 at position i from the trunk's last stream and the
+    embedding of token i + 1; its loss is added ``mtp_weight`` times.
+    ``store``: the type the frozen matrices are drawn in."""
 
     def __init__(self, vocab: int, dim: int, heads: int, ffn: int,
-                 layers: Sequence[Layer]) -> None:
+                 layers: Sequence[Layer], mtp: Optional[Layer] = None,
+                 mtp_weight: float = 0.0, store: str = "float32") -> None:
         self.vocab, self.dim, self.heads = int(vocab), int(dim), int(heads)
         self.ffn = int(ffn)
         self.layers = tuple(layers)
+        self.mtp, self.mtp_weight = mtp, float(mtp_weight)
+        self.store = str(store)
+        for layer in self.layers + ((mtp,) if mtp is not None else ()):
+            la = layer.latent
+            if la is not None and la.nope + la.rope != la.v:
+                raise ValueError(
+                    f"latent attention with keys of {la.nope + la.rope} and "
+                    f"values of {la.v}: one kernel takes one head size")
 
     def init(self, rngs: Any, x, train: bool = False) -> Dict[str, Any]:
         key = rngs["params"] if isinstance(rngs, dict) else rngs
         return {"params": init_routed_params(
-            key, self.vocab, self.dim, self.heads, self.ffn, self.layers)}
+            key, self.vocab, self.dim, self.heads, self.ffn, self.layers,
+            self.mtp, self.store)}
 
     @staticmethod
     def _attention():
@@ -403,9 +608,25 @@ class RoutedLMModule:
 
         return partial(flash_attention, causal=True)
 
+    @staticmethod
+    def _embed(params, tokens):
+        # the stream is float32 whatever the table is kept in
+        return embed(params, tokens).astype(jnp.float32)
+
     def _hidden(self, params, x, remat: bool = False):
-        return lm_hidden(params, x, self.heads, self._attention(), remat,
-                         self.layers)
+        # the trunk's blocks: a second head's block lies behind them
+        return blocks_over(self._embed(params, x),
+                           params["blocks"][:len(self.layers)], self.heads,
+                           self._attention(), remat, self.layers)
+
+    def _joined(self, params, last, y):
+        """What the second head's block reads: the trunk's ``last`` stream
+        [B, T, D] (ahead of its final norm) joined at every position with
+        the embedding of the next token, ``y``."""
+        m = params["mtp"]
+        return jnp.concatenate(
+            [_norm(self._embed(params, y), m["norm_e"], self.mtp),
+             _norm(last, m["norm_h"], self.mtp)], axis=-1) @ m["w_eh"]
 
     def apply(self, variables: Dict[str, Any], x, train: bool = False,
               rngs: Optional[Dict[str, Any]] = None, mutable=None):
@@ -421,23 +642,55 @@ class RoutedLMModule:
         and how the picks fell: ``picks`` (all of them), ``picks_held``
         (those on held experts), ``rows_passed`` (the rows the expert
         layers' passes went over for them), ``expert_picks_max`` (the
-        heaviest held expert of any layer)."""
+        heaviest held expert of any layer), and under a group-limited router
+        ``tokens_in_held_group``.  With a second head the loss is ``main +
+        mtp_weight * mtp`` and both terms are counted beside it
+        (``loss_main``, ``loss_mtp``) with the positions the second was
+        taken over (``mtp_positions``): position i predicts token i + 2, so
+        a row's last position has no target."""
         params = variables["params"]
         b, t = x.shape
-        kept = len(self.layers) * _KEPT_PER_BLOCK * b * t * self.dim * 4
-        h, stats = self._hidden(params, x, remat=kept > _REMAT_OVER)
-        h = _norm(h, params["ln_f"], self.layers[-1])
-        return loss_in_row_blocks(
-            h.reshape(b * t, -1), params["w_out"], y.reshape(-1),
-            jnp.broadcast_to(mask, (b, t)).reshape(-1)), stats
+        blocks = len(self.layers) + (self.mtp is not None)
+        remat = blocks * _KEPT_PER_BLOCK * b * t * self.dim * 4 > _REMAT_OVER
+        last, stats = self._hidden(params, x, remat=remat)
+        mask = jnp.broadcast_to(mask, (b, t))
+        main = loss_in_row_blocks(
+            _norm(last, params["ln_f"], self.layers[-1]).reshape(b * t, -1),
+            params["w_out"], y.reshape(-1), mask.reshape(-1))
+        if self.mtp is None:
+            return main, stats
+        h, seen = blocks_over(
+            self._joined(params, last, y), params["blocks"][-1:],
+            self.heads, self._attention(), remat, (self.mtp,))
 
-    def picks(self, variables: Dict[str, Any], x) -> jnp.ndarray:
-        """[L, B, T, top_k]: the experts each token picked in each layer."""
+        def shifted(z):
+            return jnp.concatenate([z[:, 1:], jnp.zeros_like(z[:, :1])], 1)
+
+        mask = mask * shifted(mask)
+        second = loss_in_row_blocks(
+            _norm(h, params["mtp"]["ln_f"], self.mtp).reshape(b * t, -1),
+            params["w_out"], shifted(y).reshape(-1), mask.reshape(-1))
+        stats = dict(_add_stats(stats, seen), loss_main=main,
+                     loss_mtp=second, mtp_positions=jnp.sum(mask))
+        return main + self.mtp_weight * second, stats
+
+    def picks(self, variables: Dict[str, Any], x, y=None) -> jnp.ndarray:
+        """[L, B, T, top_k]: the experts each token picked in each routed
+        layer, a second head's block last (``y``: the tokens after ``x``'s;
+        left out, ``x`` turned by one, the last position reading the row's
+        first token)."""
         params, out = variables["params"], []
-        h = embed(params, x)
+
+        def note(stats, picks):
+            out.append(picks.reshape(*x.shape, -1))
+
+        h = self._embed(params, x)
         attn = self._attention()
         for blk, layer in zip(params["blocks"], self.layers):
-            out.append(route(h.reshape(-1, self.dim), blk["router"],
-                             layer.experts.top_k)[0].reshape(*x.shape, -1))
-            h = block(h, blk, self.heads, _over_sequence(attn, layer), layer)
+            h = block(h, blk, self.heads, _over_sequence(attn, layer), layer,
+                      note)
+        if self.mtp is not None:
+            y = jnp.roll(x, -1, axis=1) if y is None else y
+            block(self._joined(params, h, y), params["blocks"][-1],
+                  self.heads, _over_sequence(attn, self.mtp), self.mtp, note)
         return jnp.stack(out)

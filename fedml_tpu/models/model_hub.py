@@ -148,30 +148,50 @@ def create(args: Any, output_dim: Optional[int] = None) -> ModelBundle:
             max_len=int(getattr(args, "lm_max_len", 256) or 256))
         task = TASK_LM
     elif name == "routed_lm":
-        # the same functional LM under another description: RMSNorm, grouped
-        # heads, rotary or no positions and a window by layer, routed ReGLU
-        # experts of which this chip holds `lm_experts_held` from
-        # `lm_first_held` on, an untied head.  The two layouts give a layer
-        # 1 where it rotates q and k / looks back `lm_window` positions only
+        # the same functional LM under another description: RMSNorm, an
+        # untied head, routed experts of which this chip holds
+        # `lm_experts_held` from `lm_first_held` on.  As given: grouped
+        # heads, and two layouts that give a layer 1 where it rotates q and
+        # k / looks back `lm_window` positions only, ReGLU experts behind a
+        # softmax router that reads the block's input.  With `lm_latent` (a
+        # `functional_lm.Latent`'s fields): latent attention in every layer;
+        # with `lm_router` (`scores groups kept_groups scale act reads` of
+        # `routed_experts.Experts`): another router or activation; with
+        # `lm_dense_layers` / `lm_dense_ffn`: leading dense SwiGLU layers;
+        # `lm_shared_ffn`: a shared expert in the routed ones; `lm_mtp` /
+        # `lm_mtp_weight`: a second head one token further; `lm_store`: the
+        # type the frozen matrices are kept in
         from ..ops.routed_experts import Experts
-        from .functional_lm import Layer, RoutedLMModule
+        from .functional_lm import Latent, Layer, RoutedLMModule
 
+        get = lambda key, default=None: getattr(args, key, default) or default
         experts = Experts(
             total=int(args.lm_experts), held=int(args.lm_experts_held),
-            first_held=int(getattr(args, "lm_first_held", 0) or 0),
-            top_k=int(args.lm_top_k))
+            first_held=int(get("lm_first_held", 0)),
+            top_k=int(args.lm_top_k), **dict(get("lm_router", {})))
+        shape = dict(norm="rmsnorm", eps=float(args.lm_norm_eps))
+        if get("lm_latent"):
+            shape["latent"] = Latent(**dict(args.lm_latent))
+            attention = [shape] * int(args.lm_layers)
+        else:
+            shape.update(kv_heads=int(args.lm_kv_heads),
+                         head_dim=int(args.lm_head_dim))
+            attention = [dict(
+                shape, rope_theta=float(args.lm_rope_theta) if rotates
+                else None, window=int(args.lm_window) if windowed else None)
+                for rotates, windowed in zip(args.lm_rope_layout,
+                                             args.lm_window_layout)]
+        routed = dict(experts=experts, shared=get("lm_shared_ffn"))
+        dense = int(get("lm_dense_layers", 0))
         module = RoutedLMModule(
             vocab=num_classes, dim=int(args.lm_dim),
             heads=int(args.lm_heads), ffn=int(args.lm_ffn),
-            layers=[Layer(
-                norm="rmsnorm", eps=float(args.lm_norm_eps),
-                kv_heads=int(args.lm_kv_heads),
-                head_dim=int(args.lm_head_dim),
-                rope_theta=float(args.lm_rope_theta) if rotates else None,
-                window=int(args.lm_window) if windowed else None,
-                experts=experts)
-                for rotates, windowed in zip(args.lm_rope_layout,
-                                             args.lm_window_layout)])
+            layers=[Layer(**a, **(dict(swiglu=int(args.lm_dense_ffn))
+                                  if i < dense else routed))
+                    for i, a in enumerate(attention)],
+            mtp=Layer(**attention[-1], **routed) if get("lm_mtp") else None,
+            mtp_weight=float(get("lm_mtp_weight", 0.0)),
+            store=str(get("lm_store", "float32")))
         task = TASK_LM
     elif name in ("vit", "vit_tiny", "vit-tiny"):
         module = ViT(num_classes=num_classes, dtype=dtype,

@@ -262,16 +262,17 @@ def _kv_block(tk: int, block_k: int, d: int, itemsize: int) -> int:
                          if subs % n == 0 and n <= fit)
 
 
-def _note_trace(path: str, block_q: int = 0, block_k: int = 0,
+def _note_trace(path: str, head_dim: int, block_q: int = 0, block_k: int = 0,
                 kv_resident: bool = False) -> None:
-    """Counts, as a call is traced, which path it took and with which
-    tile (docs/OBSERVABILITY.md)."""
+    """Counts, as a call is traced, which path it took, at which head size
+    and with which tile (docs/OBSERVABILITY.md)."""
     _metrics.counter(
         "fedml_attention_traces_total",
-        "flash-attention calls traced, by the path and tile they took",
-        labels=("path", "block_q", "block_k", "kv_resident"),
+        "flash-attention calls traced, by the path, head size and tile they "
+        "took",
+        labels=("path", "block_q", "block_k", "kv_resident", "head_dim"),
     ).labels(path=path, block_q=block_q, block_k=block_k,
-             kv_resident=str(kv_resident).lower()).inc()
+             kv_resident=str(kv_resident).lower(), head_dim=head_dim).inc()
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -370,10 +371,11 @@ def flash_attention_residuals(q: jnp.ndarray, k: jnp.ndarray,
         raise ValueError("a window is defined under causal attention only")
     if (interpret is None or t % block_q or tk % block_k
             or (causal and tk != t)):
-        _note_trace("reference")
+        _note_trace("reference", q.shape[3])
         return _reference_residuals(q, k, v, causal, t_valid, window)
     block_kv = _kv_block(tk, block_k, q.shape[3], k.dtype.itemsize)
-    _note_trace("kernel", block_q, block_k, kv_resident=block_kv == tk)
+    _note_trace("kernel", q.shape[3], block_q, block_k,
+                kv_resident=block_kv == tk)
     return _flash_call(q, k, v, causal=causal, block_q=block_q,
                        block_k=block_k, block_kv=block_kv, t_valid=t_valid,
                        interpret=interpret, window=window)
@@ -573,7 +575,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError("a window is defined under causal attention only")
     if interpret is None:
         if not _on_tpu():
-            _note_trace("reference")
+            _note_trace("reference", d)
             return _reference(q, k, v, causal, window)
         interpret = False
 

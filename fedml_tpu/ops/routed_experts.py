@@ -1,5 +1,6 @@
-"""Routed experts: each token picks its top-k of E experts by softmax, and
-this chip computes the share of the result that the experts it holds give.
+"""Routed experts: each token picks its top-k of E experts (by softmax over
+all of them, or by sigmoid scores within the best groups of them), and this
+chip computes the share of the result that the experts it holds give.
 
 The layer is told ``(total, held, first_held)``: it routes over all ``total``
 experts, computes the weighted outputs of experts ``first_held ..
@@ -12,11 +13,14 @@ How: the picks that landed here are sorted by expert (`plan_rows`), each
 expert's rows start at a tile boundary, and one Pallas kernel,
 ``moe_experts``, multiplies every tile of rows by its expert's matrix
 (`grouped_matmul`): a grid step a tile, the expert's matrix fetched when the
-expert changes and rounded to bfloat16 once.  Rows are gathered in and out
-by index, forward and backward alike (a gather's transpose is written as the
-other gather, never as a scatter).  The router's product and softmax are
-float32 at ``highest`` precision, so that picks differ from a float32
-reference's only where the residual streams do.
+expert changes and, where it is kept in float32, rounded to bfloat16 once (a
+matrix kept in bfloat16 is multiplied as it is).  A matrix too large for a
+grid step's VMEM is taken in blocks of its columns, each fetched once an
+expert (`_column_blocks`).  Rows are gathered in and out by index, forward
+and backward alike (a gather's transpose is written as the other gather,
+never as a scatter).  The router's product and scores are float32 at
+``highest`` precision, so that picks differ from a float32 reference's only
+where the residual streams do.
 
 What is laid out for the worst case, every pick of every token on a held
 expert: the allocation of every array of rows (``(tiles + 1) * TILE`` of
@@ -65,12 +69,27 @@ CHUNK_TILES = 8
 
 
 class Experts(NamedTuple):
-    """The routed-expert layer of a model, and this chip's share of it."""
+    """The routed-expert layer of a model, and this chip's share of it.  The
+    defaults are a softmax router ahead of the block's first norm over ReGLU
+    experts."""
 
     total: int          # experts the router chooses among
     held: int           # experts whose matrices this chip holds
     first_held: int     # the first of them, in the router's numbering
     top_k: int          # experts a token picks
+    #: "softmax": the top_k largest logits, weighted by their softmax;
+    #: "sigmoid": sigmoid scores, chosen with a per-expert bias added and
+    #: within the ``kept_groups`` best of ``groups`` groups, weighted by the
+    #: picked scores normalised to 1, times ``scale`` (`route_in_groups`)
+    scores: str = "softmax"
+    groups: int = 1
+    kept_groups: int = 1
+    scale: float = 1.0
+    #: the gate's activation: "relu" (ReGLU) | "silu" (SwiGLU)
+    act: str = "relu"
+    #: what the router reads: "input", the block's input ahead of its first
+    #: norm | "normed", what the experts read
+    reads: str = "input"
 
 
 class Plan(NamedTuple):
@@ -101,6 +120,33 @@ def route(h: jax.Array, w_router: jax.Array,
                         precision=jax.lax.Precision.HIGHEST)
     top, picks = jax.lax.top_k(logits, top_k)
     return picks, jax.nn.softmax(top, axis=-1)
+
+
+def route_in_groups(h: jax.Array, w_router: jax.Array, bias: jax.Array,
+                    experts: Experts
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``h`` [N, D] -> picks and weights [N, top_k] of a sigmoid router
+    limited to groups: scores ``sigmoid(h W)``; choosing reads ``scores +
+    bias`` and nothing else does; a group (``total / groups`` consecutive
+    experts) scores the sum of its two best, the ``kept_groups`` best groups
+    stay, and a token picks its ``top_k`` best experts of those; weights are
+    the picked *scores* normalised to 1, times ``scale``.  Also [N, groups]
+    bool: the groups a token kept.  Float32 at ``highest`` precision; ties
+    go to the lower number, as `jax.lax.top_k` breaks them."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    choice = scores + bias.astype(jnp.float32)
+    n, g = choice.shape[0], experts.groups
+    by_group = choice.reshape(n, g, experts.total // g)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, experts.kept_groups)
+    kept = jnp.any(best[:, :, None] == jnp.arange(g)[None, None, :], axis=1)
+    _, picks = jax.lax.top_k(jnp.where(
+        kept[:, :, None], by_group, -jnp.inf).reshape(n, -1), experts.top_k)
+    picked = jnp.take_along_axis(scores, picks, axis=-1)
+    weights = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    return picks, weights * experts.scale, kept
 
 
 # ---------------------------------------------------------------------------
@@ -231,23 +277,43 @@ def plan_rows(picks: jax.Array, experts: Experts, tile: int = TILE) -> Plan:
 # the grouped product
 # ---------------------------------------------------------------------------
 
-def _experts_kernel(tile_expert, live_tiles, x_ref, w_ref, o_ref, w_bf16, *,
-                    transposed: bool):
-    """One grid step: a tile of rows times its expert's matrix, bfloat16
-    operands, float32 accumulation.  The matrix is rounded into ``w_bf16``
-    when the expert changes, not every step; a dead step does nothing (its
-    blocks are the last live step's, so nothing is fetched for it either)."""
-    i = pl.program_id(0)
+#: bytes of an expert's matrix a grid step holds at once: the pipeline keeps
+#: two such blocks, and beside a float32 one its bfloat16 copy
+_W_BLOCK_BYTES = 16 * 2 ** 20
+
+
+def _column_blocks(n: int, column_bytes: int) -> int:
+    """Blocks the ``n`` columns of an expert's matrix are taken in: one
+    where the whole matrix fits `_W_BLOCK_BYTES`, else the fewest blocks of
+    whole 128-column lane tiles that do."""
+    if n * column_bytes <= _W_BLOCK_BYTES:
+        return 1
+    return next(b for b in range(2, n // 128 + 1)
+                if n % (b * 128) == 0
+                and n // b * column_bytes <= _W_BLOCK_BYTES)
+
+
+def _experts_kernel(tile_expert, live_tiles, x_ref, w_ref, o_ref, *w_bf16,
+                    transposed: bool, tile_axis: int):
+    """One grid step: a tile of rows times (a block of the columns of) its
+    expert's matrix, bfloat16 operands, float32 accumulation.  A float32
+    matrix is rounded into ``w_bf16`` when the expert changes, not every
+    step; a bfloat16 one is multiplied as it is.  A dead step does nothing
+    (its blocks are the last live step's, so nothing is fetched for it
+    either)."""
+    i = pl.program_id(tile_axis)
 
     @pl.when(i < live_tiles[0])
     def _live():
-        @pl.when((i == 0)
-                 | (tile_expert[i] != tile_expert[jnp.maximum(i - 1, 0)]))
-        def _round():
-            w_bf16[...] = w_ref[0].astype(w_bf16.dtype)
+        if w_bf16:
+            @pl.when((i == 0)
+                     | (tile_expert[i] != tile_expert[jnp.maximum(i - 1, 0)]))
+            def _round():
+                w_bf16[0][...] = w_ref[0].astype(_OPERAND)
 
         o_ref[...] = jax.lax.dot_general(
-            x_ref[...].astype(w_bf16.dtype), w_bf16[...],
+            x_ref[...].astype(_OPERAND),
+            w_bf16[0][...] if w_bf16 else w_ref[0],
             (((1,), (1 if transposed else 0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -257,36 +323,55 @@ def _experts_call(x, w, tile_expert, live_tiles, *, transposed: bool,
                   interpret: bool):
     """`_experts_kernel` over [M, K] rows and [E, K, N] matrices ([E, N, K]
     ``transposed``).  Under its own `jit`, as the other kernels of the
-    epoch program: traced and lowered once, called once a product."""
+    epoch program: traced and lowered once, called once a product.  The
+    grid is the tiles; where a matrix is taken in column blocks they are the
+    outer axis, so that a block is fetched once an expert and a tile of rows
+    once a block."""
     m, k = x.shape
     n = w.shape[1] if transposed else w.shape[2]
     tiles = tile_expert.shape[0]
     tile = m // tiles
+    blocks = _column_blocks(n, k * w.dtype.itemsize)
+    bn = n // blocks
+    grid = (tiles,) if blocks == 1 else (blocks, tiles)
+
+    def ids(a):
+        """(tile, column block, tile_expert, live_tiles) of an index map's
+        arguments: the grid's indices, then the two prefetched arrays."""
+        return a[len(grid) - 1], (0 if blocks == 1 else a[0]), a[-2], a[-1]
 
     def last_live(i, live):
         return jnp.minimum(i, jnp.maximum(live[0] - 1, 0))
 
+    def x_map(*a):
+        i, _, _, live = ids(a)
+        return last_live(i, live), 0
+
+    def w_map(*a):
+        i, j, te, live = ids(a)
+        e = te[last_live(i, live)]
+        return (e, j, 0) if transposed else (e, 0, j)
+
+    def o_map(*a):      # a dead step's output is the last tile, which
+        i, j, _, live = ids(a)                          # nothing reads
+        return jnp.where(i < live[0], i, tiles - 1), j
+
+    w_block = (1, bn, k) if transposed else (1, k, bn)
     return pl.pallas_call(
-        functools.partial(_experts_kernel, transposed=transposed),
+        functools.partial(_experts_kernel, transposed=transposed,
+                          tile_axis=len(grid) - 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(tiles,),
-            in_specs=[
-                pl.BlockSpec((tile, k),
-                             lambda i, te, live: (last_live(i, live), 0)),
-                pl.BlockSpec((1,) + w.shape[1:],
-                             lambda i, te, live: (te[last_live(i, live)],
-                                                  0, 0)),
-            ],
-            # a dead step's output is the last tile, which nothing reads
-            out_specs=pl.BlockSpec(
-                (tile, n), lambda i, te, live: (
-                    jnp.where(i < live[0], i, tiles - 1), 0)),
-            scratch_shapes=[pltpu.VMEM(w.shape[1:], _OPERAND)]),
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[pl.BlockSpec((tile, k), x_map),
+                      pl.BlockSpec(w_block, w_map)],
+            out_specs=pl.BlockSpec((tile, bn), o_map),
+            scratch_shapes=[] if w.dtype == _OPERAND else [
+                pltpu.VMEM(w_block[1:], _OPERAND)]),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            # an expert's matrix, double-buffered, and its bfloat16 copy
-            vmem_limit_bytes=3 * w[0].size * w.dtype.itemsize
-            + 16 * 2 ** 20),
+            # a block of an expert's matrix, double-buffered, and its
+            # bfloat16 copy
+            vmem_limit_bytes=3 * bn * k * w.dtype.itemsize + 16 * 2 ** 20),
         interpret=interpret,
         name="moe_experts_t" if transposed else "moe_experts",
     )(tile_expert, live_tiles, x, w)
@@ -355,36 +440,41 @@ def _sum_picks(rows, plan: Plan, weights=None):
 # the layer
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _held_share(y, weights, w_gate_up, w_down, plan: Plan,
-                interpret: Optional[bool]):
-    return _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret)[0]
+                interpret: Optional[bool], act: str):
+    return _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret,
+                           act)[0]
 
 
-def _relu_glu(gate_up):
+def _gate(gate, act: str):
+    return jax.nn.relu(gate) if act == "relu" else jax.nn.silu(gate)
+
+
+def _glu(gate_up, act: str):
     gate, up = jnp.split(gate_up, 2, axis=1)
-    return (jax.nn.relu(gate) * up).astype(_OPERAND)
+    return (_gate(gate, act) * up).astype(_OPERAND)
 
 
-def _hidden(gate_up, plan: Plan):
-    """[M, 2F] -> [M, F]: ``relu(gate) * up`` of the rows up to the last
+def _hidden(gate_up, plan: Plan, act: str):
+    """[M, 2F] -> [M, F]: ``act(gate) * up`` of the rows up to the last
     live chunk."""
     return _pass_over(
         plan, gate_up.shape[1] // 2, _OPERAND,
-        lambda start, rows: _relu_glu(_chunk(gate_up, start, rows)))
+        lambda start, rows: _glu(_chunk(gate_up, start, rows), act))
 
 
-def _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret):
+def _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret, act):
     # rounded once here: every product's operands are bfloat16
     x = _rows_of(y.astype(_OPERAND), plan)
     gate_up = grouped_matmul(x, w_gate_up, plan, False, interpret)
-    rows = grouped_matmul(_hidden(gate_up, plan), w_down, plan, False,
+    rows = grouped_matmul(_hidden(gate_up, plan, act), w_down, plan, False,
                           interpret)
     return (_sum_picks(rows, plan, weights),
             (x, gate_up, weights, w_gate_up, w_down, plan))
 
 
-def _held_share_bwd(interpret, res, d_out):
+def _held_share_bwd(interpret, act, res, d_out):
     """By hand, so that every movement of rows is a gather (autodiff would
     transpose each into a scatter), every cotangent stays float32, and the
     [M, D] rows of the forward need not be kept: a pick's weight meets its
@@ -399,14 +489,18 @@ def _held_share_bwd(interpret, res, d_out):
         real = _chunk(plan.real, start, rows)
         by, gu = _chunk(by_down, start, rows), _chunk(gate_up, start, rows)
         gate, up = jnp.split(gu, 2, axis=1)
-        dot = jnp.where(real, jnp.sum(by * _relu_glu(gu), axis=-1), 0.0)
+        dot = jnp.where(real, jnp.sum(by * _glu(gu, act), axis=-1), 0.0)
         w = jnp.where(real, jnp.take(
             weights.reshape(-1), _chunk(plan.pick_of_row, start, rows),
             mode="clip"), 0.0)
         d_hidden = by * w[:, None]
-        d = jnp.concatenate(
-            [jnp.where(gate > 0, d_hidden * up, 0.0),
-             d_hidden * jax.nn.relu(gate)], axis=1).astype(_OPERAND)
+        if act == "relu":
+            d_gate = jnp.where(gate > 0, d_hidden * up, 0.0)
+        else:
+            sig = jax.nn.sigmoid(gate)
+            d_gate = d_hidden * up * (sig * (1.0 + gate * (1.0 - sig)))
+        d = jnp.concatenate([d_gate, d_hidden * _gate(gate, act)],
+                            axis=1).astype(_OPERAND)
         return (_put(dots, start, dot), _put(w_row, start, w),
                 _put(d_gate_up, start, d))
 
@@ -424,7 +518,7 @@ def _held_share_bwd(interpret, res, d_out):
     d_x = grouped_matmul(d_gate_up, w_gate_up, plan, True, interpret)
     return (_sum_picks(d_x, plan), d_weights.astype(weights.dtype),
             _matrices_grad(x, d_gate_up, plan).astype(w_gate_up.dtype),
-            _matrices_grad(_hidden(gate_up, plan), d_rows * w_row[
+            _matrices_grad(_hidden(gate_up, plan, act), d_rows * w_row[
                 :, None].astype(_OPERAND), plan).astype(w_down.dtype), None)
 
 
@@ -433,14 +527,16 @@ _held_share.defvjp(_held_share_fwd, _held_share_bwd)
 
 def held_experts(y, picks, weights, w_gate_up, w_down, experts: Experts,
                  interpret: Optional[bool] = None):
-    """The held experts' share of a ReGLU expert layer.  ``y`` [N, D] the
+    """The held experts' share of a gated expert layer.  ``y`` [N, D] the
     layer's normed input (float32), ``picks``/``weights`` [N, top_k] from
-    `route`, ``w_gate_up`` [held, D, 2F] (an expert's gate columns, then
-    its up columns), ``w_down`` [held, F, D].  Returns ``sum over picks e
-    held here of w_e * (relu(y G_e) * (y U_e)) D_e`` [N, D] float32, and the
+    the router, ``w_gate_up`` [held, D, 2F] (an expert's gate columns, then
+    its up columns), ``w_down`` [held, F, D], float32 or bfloat16.  Returns
+    ``sum over picks e held here of w_e * (act(y G_e) * (y U_e)) D_e``
+    [N, D] float32 (``act`` relu or silu, as ``experts`` says), and the
     picks that landed on each held expert [held], and the rows each of the
     layer's passes went over.  Differentiable to ``y``, the weights and the
     matrices."""
     plan = plan_rows(picks, experts, TILE)
     return (_held_share(y.astype(jnp.float32), weights, w_gate_up, w_down,
-                        plan, interpret), plan.counts, rows_passed(plan))
+                        plan, interpret, experts.act), plan.counts,
+            rows_passed(plan))

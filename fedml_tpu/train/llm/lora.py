@@ -19,8 +19,9 @@ import jax.numpy as jnp
 DEFAULT_TARGETS = (r".*attention.*kernel", r".*(query|key|value|out).*kernel",
                    r".*Dense_\d+.*kernel",
                    # functional-LM layout (models/functional_lm.py):
-                   # per-block attention/MLP matmuls
-                   r".*/w[qkvo]", r".*/w[12]")
+                   # per-block attention/MLP matmuls; a latent layer's two
+                   # matrices each for q and for keys and values
+                   r".*/w[qkvo]", r".*/w[12]", r".*/w(q|kv)_[ab]")
 
 
 def _path_str(path) -> str:
@@ -61,7 +62,9 @@ def init_lora(params: Any, rank: int = 8, targets: Sequence[str] = None,
 
 def apply_lora(params: Any, lora: Dict[str, Any], alpha: float = 16.0
                ) -> Any:
-    """Effective params: W' = W + (alpha/r)·A@B for targeted kernels."""
+    """Effective params: W' = W + (alpha/r)·A@B for targeted kernels.  A
+    kernel kept below the factors' type gets the sum formed in theirs and
+    rounded once, elementwise: no copy of it in the higher type is made."""
     if not lora:
         return params
     some = next(iter(lora.values()))
@@ -70,8 +73,10 @@ def apply_lora(params: Any, lora: Dict[str, Any], alpha: float = 16.0
     def update(path, leaf):
         p = _path_str(path)
         if p in lora:
-            ab = (lora[p]["a"] @ lora[p]["b"]).astype(leaf.dtype)
-            return leaf + scale * ab
+            ab = lora[p]["a"] @ lora[p]["b"]
+            if jnp.promote_types(leaf.dtype, ab.dtype) == leaf.dtype:
+                return leaf + scale * ab.astype(leaf.dtype)
+            return (leaf.astype(ab.dtype) + scale * ab).astype(leaf.dtype)
         return leaf
 
     return jax.tree_util.tree_map_with_path(update, params)

@@ -102,7 +102,12 @@ def _note_picks(counted: Dict[str, Any]) -> None:
             ("rows_passed", "fedml_moe_rows_passed_total",
              "rows the expert layers' passes went over for the landed picks"),
             ("expert_picks_max", "fedml_moe_expert_picks_max",
-             "picks of the heaviest held expert of each step, summed")):
+             "picks of the heaviest held expert of each step, summed"),
+            ("tokens_in_held_group", "fedml_moe_tokens_in_held_group_total",
+             "tokens, over layers and steps, whose kept groups of experts "
+             "include the group this chip's experts lie in"),
+            ("mtp_positions", "fedml_sft_mtp_positions_total",
+             "positions the second head's loss was taken over")):
         if key in counted:
             # on the host already: `train` fetched it with the loss
             _metrics.counter(name, what).inc(
@@ -291,7 +296,7 @@ class LLMTrainer:
                 trainable = jax.device_put(trainable, repl)
                 opt_state = jax.device_put(opt_state, repl)
         rng = jax.random.PRNGKey(1)
-        history = []
+        history, terms = [], {}
         ckpt = None
         if cfg.checkpoint_dir:
             from ...utils.checkpoint import RoundCheckpointer
@@ -318,6 +323,9 @@ class LLMTrainer:
                 got = jax.device_get(loss)  # fedml: noqa[JAX003] — epoch boundary
             if isinstance(got, dict):
                 _note_picks(got)
+                # a model's own loss terms were summed over the steps
+                terms = {k: float(v) / batches["x"].shape[0]  # fedml: noqa[JAX003]
+                         for k, v in got.items() if k.startswith("loss_")}
                 got = got["loss"]
             loss_host = float(got)  # fedml: noqa[JAX003] — fetched above
             history.append(loss_host)
@@ -330,8 +338,10 @@ class LLMTrainer:
             self.lora = trainable
         else:
             self.variables = dict(self.variables, params=trainable)
+        # the last epoch's loss, and beside it the terms a model with more
+        # than one makes it of (``loss_main``, ``loss_mtp``)
         return {"train_loss": history[-1] if history else float("nan"),
-                "loss_history": history}
+                "loss_history": history, **terms}
 
     def generate(self, prompt_ids: np.ndarray, max_new: int = 20,
                  temperature: float = 0.0) -> np.ndarray:

@@ -340,3 +340,50 @@ def test_resnet56_constants_match_the_model():
         jax.random.PRNGKey(0)))
     assert sum(int(np.prod(x.shape)) for x in leaves) == RESNET56_FLAT
     assert {x.shape for x in leaves} == set(RESNET56_LEAVES)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_latent_attention_compiles_for_v5e(one_chip, grad):
+    """The attention of the cell `sft.gigachat_lora_8k`: 64 heads of 192
+    (one and a half lane tiles) at 4,096 positions, q, k and v alike; the
+    backward is the tiled one."""
+    attn = functools.partial(flash_attention, causal=True, interpret=False)
+    fn = attn
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: attn(*a).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+    qkv = ((1, 64, 4096, 192), jnp.float32)
+    text = _compile_text(fn, one_chip, qkv, qkv, qkv)
+    assert "flash_fwd" in text
+    _assert_kernel(text)
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("k,n,blocks", [(7168, 4096, 4), (2048, 7168, 2)],
+                         ids=["gate_up", "down"])
+def test_expert_products_in_column_blocks_compile_for_v5e(one_chip, k, n,
+                                                          blocks, transposed):
+    """`moe_experts` at the same cell's size: the worst case's rows (every
+    pick of 4,096 tokens on the 8 held experts) against bfloat16 [8, K, N]
+    matrices of 59 and 29 MB, which no grid step holds whole: multiplied as
+    they are, in blocks of their columns."""
+    from fedml_tpu.ops import routed_experts as rex
+
+    experts = rex.Experts(total=256, held=8, first_held=0, top_k=8)
+    plan = jax.eval_shape(lambda p: rex.plan_rows(p, experts),
+                          jax.ShapeDtypeStruct((4096, 8), jnp.int32))
+    rows = plan.real.shape[0]
+    w = (8, n, k) if transposed else (8, k, n)
+    assert rex._column_blocks(n, k * 2) == blocks
+
+    def fn(x, w, tile_expert, live_tiles):
+        return rex._experts_call(x, w, tile_expert, live_tiles,
+                                 transposed=transposed, interpret=False)
+
+    text = _compile_text(
+        fn, one_chip, ((rows, k), jnp.bfloat16), (w, jnp.bfloat16),
+        (plan.tile_expert.shape, jnp.int32), ((1,), jnp.int32))
+    assert ("moe_experts_t" if transposed else "moe_experts") in text
+    _assert_kernel(text)

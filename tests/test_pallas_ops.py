@@ -100,13 +100,13 @@ def test_flash_attention_off_tpu_fallback_matches():
 
     q, k, v = _qkv(4, 24, 8, b=1, h=2)
     before = _traces(path="reference", block_q=0, block_k=0,
-                     kv_resident="false")
+                     kv_resident="false", head_dim=8)
     out = flash_attention(q, k, v, causal=True)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
     assert _traces(path="reference", block_q=0, block_k=0,
-                   kv_resident="false") == before + 1
+                   kv_resident="false", head_dim=8) == before + 1
 
 
 def _assert_partial(got, want):
@@ -140,7 +140,7 @@ def test_flash_schedule_kv_resident_or_on_the_grid(monkeypatch, causal,
         _budget_of_two_passes(monkeypatch)
     q, k, v = _qkv(7, 64, 16)
     labels = dict(path="kernel", block_q=16, block_k=8,
-                  kv_resident=str(resident).lower())
+                  kv_resident=str(resident).lower(), head_dim=16)
     before = _traces(**labels)
     got = pa.flash_attention_residuals(q, k, v, causal=causal, block_q=16,
                                        block_k=8, interpret=True)
@@ -200,7 +200,8 @@ def test_flash_attention_default_blocks_follow_the_shape(t, bq, bk):
     from fedml_tpu.parallel.ring_attention import reference_attention
 
     q, k, v = _qkv(10, t, 16, b=1, h=2)
-    labels = dict(path="kernel", block_q=bq, block_k=bk, kv_resident="true")
+    labels = dict(path="kernel", block_q=bq, block_k=bk, kv_resident="true",
+                  head_dim=16)
     before = _traces(**labels)
     out = flash_attention(q, k, v, causal=True, interpret=True)
     assert _traces(**labels) == before + 1

@@ -211,12 +211,13 @@ def _share(params, s):
                 w_down=blk["w_down"][4 * s:4 * s + 4])]}
 
 
-def test_four_shares_add_up_to_the_uncut_layer():
-    """Each chip's part of the attention term, of the expert term and of the
-    logits, summed (the logits: laid side by side), is the uncut layer of the
-    reference; the residual, the norms and the router, which all compute
-    alike, are counted once.  A part is isolated by zeroing the other's
-    output matrix, since the expert term reads the attention's."""
+def _four_shares_of_the_grouped_layer():
+    """(got, want) of the softmax-routed family: each chip's part of the
+    attention term, of the expert term and of the logits, summed (the
+    logits: laid side by side), against the uncut layer of the reference;
+    the residual, the norms and the router, which all compute alike, are
+    counted once.  A part is isolated by zeroing the other's output matrix,
+    since the expert term reads the attention's."""
     from fedml_tpu.ops.pallas_attention import flash_attention
 
     whole = _uncut()
@@ -250,8 +251,53 @@ def test_four_shares_add_up_to_the_uncut_layer():
             else:
                 got_experts = got_experts + out
         logits.append(flm.head(h, mine, module.layers[0]))
-    for got, want in ((got_attn, want_attn), (got_experts, want_experts),
-                      (jnp.concatenate(logits, -1), want_logits)):
+    return ((got_attn, want_attn), (got_experts, want_experts),
+            (jnp.concatenate(logits, -1), want_logits))
+
+
+def _thirty_two_shares_of_the_latent_layer():
+    """(got, want) of the latent-attention family, whose deployment cuts the
+    experts alone: the 32 chips' routed terms (one expert each, isolated as
+    the layer with its experts less the layer without) on top of attention
+    and the shared expert counted once, against the uncut routed layer of
+    the reference; and the dense layer, which no chip cuts, as it is."""
+    from chipbench.reference import gigachat3
+    from fedml_tpu.ops.pallas_attention import flash_attention
+    from mla_tiny import CFG as LATENT, module as latent_module
+
+    whole = dict(LATENT, n_routed_experts=32, experts_first_held=0)
+    z = gigachat3.sizes(whole)
+    params = gigachat3.init_params(whole, 7)
+    h = jnp.asarray(np.random.RandomState(7).randn(T, 32), jnp.float32)
+    dense, routed = params["blocks"][0], params["blocks"][1]
+    want = gigachat3._block(h, routed, z, "float32")[0]
+    want_dense = gigachat3._block(h, dense, z, "float32")[0]
+
+    attn = functools.partial(flash_attention, causal=True)
+
+    def run(module, blk, i):
+        layer = module.layers[i]
+        return flm.block(h[None], blk, module.heads,
+                         flm._over_sequence(attn, layer), layer)[0]
+
+    got = 0.0
+    for s in range(32):
+        module = latent_module(dict(LATENT, n_routed_experts=1,
+                                    experts_first_held=s))
+        mine = dict(routed, w_gate_up=routed["w_gate_up"][s:s + 1],
+                    w_down=routed["w_down"][s:s + 1])
+        without = run(module, dict(mine, w_down=jnp.zeros_like(
+            mine["w_down"])), 1)
+        got = got + run(module, mine, 1) - without
+    return ((got + without, want), (run(module, dense, 0), want_dense))
+
+
+@pytest.mark.parametrize("family", ["grouped heads, softmax router",
+                                    "latent attention, sigmoid router"])
+def test_the_shares_add_up_to_the_uncut_layer(family):
+    pairs = (_four_shares_of_the_grouped_layer() if family.startswith("group")
+             else _thirty_two_shares_of_the_latent_layer())
+    for got, want in pairs:
         assert float(jnp.max(jnp.abs(got - want))) < 1e-2 * float(
             jnp.max(jnp.abs(want)))
 
