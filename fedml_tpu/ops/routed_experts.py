@@ -16,11 +16,16 @@ expert's rows start at a tile boundary, and one Pallas kernel,
 expert changes and, where it is kept in float32, rounded to bfloat16 once (a
 matrix kept in bfloat16 is multiplied as it is).  A matrix too large for a
 grid step's VMEM is taken in blocks of its columns, each fetched once an
-expert (`_column_blocks`).  Rows are gathered in and out by index, forward
-and backward alike (a gather's transpose is written as the other gather,
-never as a scatter).  The router's product and scores are float32 at
-``highest`` precision, so that picks differ from a float32 reference's only
-where the residual streams do.
+expert (`_column_blocks`).  Rows are laid out by a gather by index, forward
+and backward alike (a gather's transpose is written as the other movement
+of rows, never as a scatter).  The way back, the combine, is a second
+kernel, ``moe_sum_picks`` (`_sum_picks`): a grid step a block of tokens,
+whose sums start at zero in VMEM and take each row one of the block's
+picks landed on, fetched from HBM in chunks of `_SUM_CHUNK` rows (the rows
+a block has on one expert are consecutive, since within an expert's rows
+the tokens ascend) and never rounded: float32 rows, float32 sums.  The
+router's product and scores are float32 at ``highest`` precision, so that
+picks differ from a float32 reference's only where the residual streams do.
 
 What is laid out for the worst case, every pick of every token on a held
 expert: the allocation of every array of rows (``(tiles + 1) * TILE`` of
@@ -32,16 +37,22 @@ elementwise work between the products) is a loop over the chunks of
 place into a buffer of the worst case's shape whose dead part nothing
 writes and nothing reads (`_row_buffer`).  When every pick lands the loop
 goes over every chunk: the worst case is the same computation, not another
-path.
+path.  The combine follows the count more closely still: it fetches the
+chunks of rows that hold a landed row of the block's tokens, lists them
+from the plan (`_chunks_of_blocks`), and adds the rows whose pick is one
+of the block's; a pick that did not land reads nothing, and a token none of
+whose picks landed gets zeros.
 
-Paths as the other kernels of `fedml_tpu.ops`: on TPU the kernel; off TPU
-with ``interpret=True`` the same kernel through the Pallas interpreter;
-otherwise `jax.lax.ragged_dot` over the same layout.
+Paths as the other kernels of `fedml_tpu.ops`: on TPU the kernels; off TPU
+with ``interpret=True`` the same kernels through the Pallas interpreter;
+otherwise `jax.lax.ragged_dot` over the same layout, and for the combine a
+`scan` of one gather a pick (`_sum_picks_scan`).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -50,6 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.mlops import metrics as _metrics
 from .pallas_ops import _on_tpu
 
 #: what the operands of every expert product are rounded to (float32
@@ -232,7 +244,9 @@ def plan_rows(picks: jax.Array, experts: Experts, tile: int = TILE) -> Plan:
     local = picks.reshape(-1).astype(jnp.int32) - experts.first_held
     landed = (local >= 0) & (local < held)
     key = jnp.where(landed, local, held)              # elsewhere sorts last
-    order = jnp.argsort(key).astype(jnp.int32)        # sorted -> flat pick
+    # stable, over the expert alone: within an expert's rows the flat picks,
+    # which are token-major, ascend (`_chunks_of_blocks` counts on it)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # sorted -> flat
     place = jnp.argsort(order).astype(jnp.int32)      # flat pick -> sorted
     starts = jnp.searchsorted(key[order], jnp.arange(held + 1)).astype(
         jnp.int32)
@@ -416,10 +430,10 @@ def _rows_of(y, plan: Plan):
         y, _chunk(plan.token_of_row, start, rows), axis=0, mode="clip"))
 
 
-def _sum_picks(rows, plan: Plan, weights=None):
-    """[M, D] -> [N, D]: over a token's picks that landed, its rows (times
-    their weights).  One gather a pick and one at a time (a `scan`), so that
-    no [N, top_k, D] array is made; a row nothing computed is never read
+def _sum_picks_scan(rows, plan: Plan, weights=None):
+    """`_sum_picks` off the TPU, and what the tests hold the kernel to: one
+    gather a pick and one at a time (a `scan`), so that no [N, top_k, D]
+    array is made; a row nothing computed is gathered and thrown away
     (`where`, not a product by 0)."""
     n, k = plan.landed.shape
     if weights is None:
@@ -434,6 +448,201 @@ def _sum_picks(rows, plan: Plan, weights=None):
         one, jnp.zeros((n, rows.shape[1]), jnp.float32),
         (plan.row_of_pick.T, plan.landed.T, weights.astype(jnp.float32).T))
     return out
+
+
+#: bytes of the block of tokens a grid step of ``moe_sum_picks`` sums into
+#: (the pipeline keeps two such blocks), and the most tokens of one whatever
+#: their width: its list of chunks and its picks' weights are held in SMEM
+_SUM_BLOCK_BYTES = 16 * 2 ** 20
+_SUM_BLOCK_TOKENS = 1024
+
+#: rows a copy of ``moe_sum_picks`` fetches, and copies it keeps under way.
+#: An expert's rows start at a tile, so a chunk that divides the tile holds
+#: one expert's rows, in which tokens ascend
+_SUM_CHUNK = 16
+_SUM_COPIES = 8
+
+#: elements of a slice of a 1-D array that Mosaic copies from HBM to SMEM:
+#: a whole tile of the array's layout there
+_SMEM_SLICE = 1024
+
+
+def _sum_block(n: int, d: int) -> int:
+    """Tokens a grid step of ``moe_sum_picks`` sums: the largest power of
+    two whose float32 rows of width ``d`` fit `_SUM_BLOCK_BYTES`, no more
+    than `_SUM_BLOCK_TOKENS` nor than the ``n`` there are (in whole
+    sublanes of 8)."""
+    fit = max(8, _SUM_BLOCK_BYTES // (4 * d))
+    return min(1 << (fit.bit_length() - 1), _SUM_BLOCK_TOKENS,
+               -(-n // 8) * 8)
+
+
+def _chunks_of_blocks(plan: Plan, block: int, chunk: int):
+    """What each block of ``block`` tokens fetches: ``[blocks, stride]``
+    int32, a block's count of chunks and then the chunks themselves (of
+    ``chunk`` rows of the layout), padded to whole slices of `_SMEM_SLICE`.
+    The rows a block's tokens have on one held expert are consecutive
+    (`plan_rows` sorts picks by expert alone and stably, and a flat pick
+    index is token-major), so a block needs at most ``held`` ranges of
+    rows; their chunks are listed expert by expert, and a chunk on the
+    border of two blocks' ranges by both.  Index arithmetic on
+    ``N * top_k`` integers."""
+    n, k = plan.landed.shape
+    held = plan.counts.shape[0]
+    blocks = -(-n // block)
+    begins = jnp.cumsum(plan.group_rows) - plan.group_rows
+    row = jnp.pad(jnp.where(plan.landed, plan.row_of_pick, -1),
+                  ((0, blocks * block - n), (0, 0)), constant_values=-1)
+    # compared, not looked up: a gather of N * top_k numbers costs more
+    # than the kernel
+    on = (row[:, :, None] >= begins) & (row[:, :, None]
+                                         < begins + plan.group_rows)
+    rows = jnp.sum(on.reshape(blocks, block * k, held), axis=1,
+                   dtype=jnp.int32)                           # [blocks, held]
+    first_row = begins + jnp.cumsum(rows, axis=0) - rows
+    first = first_row // chunk
+    count = jnp.where(rows > 0, (first_row + rows - 1) // chunk - first + 1, 0)
+    ends = jnp.cumsum(count, axis=1)
+    # every range may start and end inside a chunk
+    most = block * min(k, held) // chunk + 2 * held
+    slot = jnp.arange(most, dtype=jnp.int32)[None, :, None]
+    begun = (ends - count)[:, None, :]
+    listed = jnp.sum(jnp.where(
+        (slot >= begun) & (slot < ends[:, None, :]),
+        first[:, None, :] + slot - begun, 0), axis=-1)
+    return jnp.pad(jnp.concatenate([ends[:, -1:], listed], axis=1).astype(
+        jnp.int32), ((0, 0), (0, -(most + 1) % _SMEM_SLICE)))
+
+
+def _sum_picks_kernel(chunks, *refs, block: int, chunk: int, k: int,
+                      weighted: bool):
+    """One grid step: a block of tokens' sums.  The block starts at zero in
+    VMEM; its chunks of rows are copied in from HBM, `_SUM_COPIES` under way
+    at once, each with the slice of ``pick`` that says whose its rows are;
+    a row of one of the block's picks is added (times the pick's weight) to
+    its token's row, and no other row of a chunk is looked at (what a row
+    past the landed ones holds is copied and never loaded)."""
+    w_ref = refs[0] if weighted else None
+    rows_hbm, pick_hbm, o_ref, buf, pick, sem, pick_sem = refs[weighted:]
+    first_pick = pl.program_id(0) * block * k
+    count = chunks[0]
+
+    def copies(i):
+        slot = i % _SUM_COPIES
+        row = chunks[1 + i] * chunk
+        start = pl.multiple_of(row // _SMEM_SLICE * _SMEM_SLICE, _SMEM_SLICE)
+        return (pltpu.make_async_copy(
+            rows_hbm.at[pl.ds(pl.multiple_of(row, chunk), chunk)],
+            buf.at[slot], sem.at[slot]), pltpu.make_async_copy(
+                pick_hbm.at[pl.ds(start, _SMEM_SLICE)],
+                pick.at[pl.ds(pl.multiple_of(slot * _SMEM_SLICE, _SMEM_SLICE),
+                              _SMEM_SLICE)], pick_sem.at[slot]))
+
+    def start(i):
+        @pl.when(i < count)
+        def _():
+            for copy in copies(i):
+                copy.start()
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    for i in range(_SUM_COPIES - 1):
+        start(i)
+
+    def one_chunk(i, _):
+        start(i + _SUM_COPIES - 1)
+        for copy in copies(i):
+            copy.wait()
+        slot = i % _SUM_COPIES
+        at = slot * _SMEM_SLICE + chunks[1 + i] * chunk % _SMEM_SLICE
+        for r in range(chunk):
+            p = pick[at + r] - first_pick
+
+            @pl.when((p >= 0) & (p < block * k))
+            def _ours():
+                got = buf[slot, r:r + 1, :]
+                if weighted:
+                    got = got * w_ref[p]
+                token = pl.ds(p // k, 1)
+                o_ref[token, :] = o_ref[token, :] + got
+        return 0
+
+    jax.lax.fori_loop(0, count, one_chunk, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "chunk", "k",
+                                             "interpret"))
+def _sum_picks_call(rows, pick, chunks, weights, *, block: int, chunk: int,
+                    k: int, interpret: bool):
+    """`_sum_picks_kernel` over [M, D] rows: [blocks * block, D].  Under its
+    own `jit`, as `_experts_call`.  ``pick`` [M'] is a row's flat pick, -1
+    where it computes none; ``chunks`` [blocks, stride] from
+    `_chunks_of_blocks`; ``weights`` [blocks, >= block * k] or None."""
+    d = rows.shape[1]
+    blocks, stride = chunks.shape
+    weighted = weights is not None
+    smem = lambda width: pl.BlockSpec((width,), lambda b: (b,),
+                                      memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_sum_picks_kernel, block=block, chunk=chunk,
+                          k=k, weighted=weighted),
+        grid=(blocks,),
+        in_specs=[smem(stride)] + (
+            [smem(weights.shape[1])] if weighted else []) + [hbm, hbm],
+        out_specs=pl.BlockSpec((block, d), lambda b: (b, 0)),
+        scratch_shapes=[pltpu.VMEM((_SUM_COPIES, chunk, d), jnp.float32),
+                        pltpu.SMEM((_SUM_COPIES * _SMEM_SLICE,), jnp.int32),
+                        pltpu.SemaphoreType.DMA((_SUM_COPIES,)),
+                        pltpu.SemaphoreType.DMA((_SUM_COPIES,))],
+        out_shape=jax.ShapeDtypeStruct((blocks * block, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            # the block of sums, double-buffered, and the chunks under way
+            vmem_limit_bytes=4 * d * (2 * block + _SUM_COPIES * chunk)
+            + 16 * 2 ** 20),
+        interpret=interpret,
+        name="moe_sum_picks",
+    )(chunks.reshape(-1), *([weights.reshape(-1)] if weighted else []),
+      rows, pick)
+
+
+def _note_combine(path: str, width: int, block_tokens: int = 0) -> None:
+    """Counts, as a call of the combine is traced, which path it took, over
+    rows of which width and in blocks of how many tokens
+    (docs/OBSERVABILITY.md)."""
+    _metrics.counter(
+        "fedml_moe_combine_traces_total",
+        "calls of the routed layer's combine traced, by the path, the rows' "
+        "width and the tokens of a block they took",
+        labels=("path", "width", "block_tokens"),
+    ).labels(path=path, width=width, block_tokens=block_tokens).inc()
+
+
+def _sum_picks(rows, plan: Plan, weights=None,
+               interpret: Optional[bool] = None):
+    """[M, D] -> [N, D] float32: over a token's picks that landed, its rows
+    (times their weights), never rounded; a row nothing computed never
+    reaches a sum, and a pick that did not land reads nothing.  On TPU (or
+    interpreted) the kernel ``moe_sum_picks``, else `_sum_picks_scan`."""
+    n, k = plan.landed.shape
+    d = rows.shape[1]
+    if interpret is None and not _on_tpu():
+        _note_combine("jnp", d)
+        return _sum_picks_scan(rows, plan, weights)
+    _, m, tile = _layout(plan)
+    block, chunk = _sum_block(n, d), math.gcd(_SUM_CHUNK, tile)
+    _note_combine("interpret" if interpret else "kernel", d, block)
+    chunks = _chunks_of_blocks(plan, block, chunk)
+    padded = chunks.shape[0] * block
+    pick = jnp.pad(jnp.where(plan.real, plan.pick_of_row, -1),
+                   (0, -m % _SMEM_SLICE), constant_values=-1)
+    if weights is not None:
+        weights = jnp.pad(weights.astype(jnp.float32),
+                          ((0, padded - n), (0, 0))).reshape(-1, block * k)
+        weights = jnp.pad(weights, ((0, 0), (0, -(block * k) % _SMEM_SLICE)))
+    out = _sum_picks_call(rows.astype(jnp.float32), pick, chunks, weights,
+                          block=block, chunk=chunk, k=k,
+                          interpret=bool(interpret))
+    return out if padded == n else out[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +679,7 @@ def _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret, act):
     gate_up = grouped_matmul(x, w_gate_up, plan, False, interpret)
     rows = grouped_matmul(_hidden(gate_up, plan, act), w_down, plan, False,
                           interpret)
-    return (_sum_picks(rows, plan, weights),
+    return (_sum_picks(rows, plan, weights, interpret),
             (x, gate_up, weights, w_gate_up, w_down, plan))
 
 
@@ -516,7 +725,8 @@ def _held_share_bwd(interpret, act, res, d_out):
         dots, plan.row_of_pick.reshape(-1), mode="clip").reshape(
             weights.shape), 0.0)
     d_x = grouped_matmul(d_gate_up, w_gate_up, plan, True, interpret)
-    return (_sum_picks(d_x, plan), d_weights.astype(weights.dtype),
+    return (_sum_picks(d_x, plan, None, interpret),
+            d_weights.astype(weights.dtype),
             _matrices_grad(x, d_gate_up, plan).astype(w_gate_up.dtype),
             _matrices_grad(_hidden(gate_up, plan, act), d_rows * w_row[
                 :, None].astype(_OPERAND), plan).astype(w_down.dtype), None)

@@ -387,3 +387,37 @@ def test_expert_products_in_column_blocks_compile_for_v5e(one_chip, k, n,
         (plan.tile_expert.shape, jnp.int32), ((1,), jnp.int32))
     assert ("moe_experts_t" if transposed else "moe_experts") in text
     _assert_kernel(text)
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("n,d,experts,block", [
+    (16384, 2560, dict(total=64, held=16, first_held=0, top_k=6), 1024),
+    (4096, 7168, dict(total=256, held=8, first_held=0, top_k=8), 512),
+    (1500, 2560, dict(total=64, held=16, first_held=0, top_k=6), 1024),
+], ids=["smallthinker", "gigachat", "a part block"])
+def test_the_combine_compiles_for_v5e(one_chip, monkeypatch, n, d, experts,
+                                      block, weighted):
+    """`moe_sum_picks` at the two routed cells' sizes, with the forward's
+    weights and without, as the backward calls it, and over tokens that
+    are no whole number of blocks: the plan, the list of chunks
+    and the kernel, whose token block follows from the rows' width.  It
+    copies slices of a 1-D array from HBM to SMEM, which the interpreter
+    takes at any size and Mosaic only at whole tiles of 1,024."""
+    from fedml_tpu.ops import routed_experts as rex
+
+    monkeypatch.setattr(rex, "_on_tpu", lambda: True)
+    experts = rex.Experts(**experts)
+    assert rex._sum_block(n, d) == block
+    plan = jax.eval_shape(lambda p: rex.plan_rows(p, experts),
+                          jax.ShapeDtypeStruct((n, experts.top_k), jnp.int32))
+
+    def fn(rows, weights, *plan):       # the plan's sorts are not the point
+        return rex._sum_picks(rows, rex.Plan(*plan),
+                              weights if weighted else None)
+
+    text = _compile_text(
+        fn, one_chip, ((plan.real.shape[0], d), jnp.float32),
+        ((n, experts.top_k), jnp.float32),
+        *((leaf.shape, leaf.dtype) for leaf in plan))
+    assert "moe_sum_picks" in text
+    _assert_kernel(text)

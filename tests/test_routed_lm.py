@@ -511,6 +511,90 @@ def test_rows_that_hold_nothing_never_reach_a_result(monkeypatch, interpret,
         assert np.isfinite(b).all() and np.array_equal(a, b)
 
 
+def _combine_case(how, n, width, block_tokens, monkeypatch):
+    """A plan over ``n`` tokens for a case of the combine's tests, rows of
+    ``width`` with NaN wherever no pick computed one, and weights; the
+    combine's block is set to ``block_tokens``.  ``TILE`` 16, 4 held
+    experts of 16 from the fourth on."""
+    monkeypatch.setattr(rex, "_SUM_BLOCK_BYTES", block_tokens * 4 * width)
+    experts = rex.Experts(total=16, held=4, first_held=4, top_k=3)
+    rng = np.random.RandomState(17)
+    plan = rex.plan_rows(jnp.asarray(_picks_that_fall(how, n, experts)),
+                         experts, 16)
+    rows = jnp.where(plan.real[:, None], jnp.asarray(
+        rng.randn(plan.real.shape[0], width), jnp.float32), jnp.nan)
+    return plan, rows, jnp.asarray(rng.rand(n, 3), jnp.float32)
+
+
+@pytest.mark.parametrize("width", [128, 384])
+@pytest.mark.parametrize("n", [96, 100], ids=["whole blocks", "a part block"])
+@pytest.mark.parametrize("how", ["none", "all on one", "a quarter"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weights", "no weights"])
+def test_the_combine_kernel_is_the_scan(monkeypatch, weighted, how, n, width):
+    """``moe_sum_picks`` through the interpreter against the `jnp` scan: with
+    and without weights; nothing landed, every token on one held expert, a
+    mixed draw of which a quarter lands; tokens a whole number of the kernel's blocks of 32 or not;
+    rows of one lane tile and of three.  Float32 sums of float32 rows in
+    another order: equal to rounding.  Every row no pick computed is NaN,
+    as are the rows of dead tiles: none reaches a sum."""
+    plan, rows, weights = _combine_case(how, n, width, 32, monkeypatch)
+    weights = weights if weighted else None
+    want = rex._sum_picks_scan(rows, plan, weights)
+    got = rex._sum_picks(rows, plan, weights, interpret=True)
+    assert got.shape == want.shape == (n, width)
+    assert got.dtype == jnp.float32
+    landed = int(plan.landed.sum())
+    assert landed == {"none": 0, "all on one": n}.get(how, landed)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6,
+                               atol=1e-6)
+    if how == "none":
+        assert not np.asarray(got).any()
+
+
+def test_the_combine_reads_no_row_that_did_not_land(monkeypatch):
+    """Every row past the landed ones of its expert, and every row of a
+    dead tile, is NaN: the kernel's sums hold none, and they are the sums of
+    the same plan over rows that are zero there."""
+    plan, rows, weights = _combine_case("a quarter", 100, 128, 32, monkeypatch)
+    real = np.asarray(plan.real)
+    assert 0 < real.sum() < real.size // 2
+    assert np.isnan(np.asarray(rows)[~real]).all()
+    got = np.asarray(rex._sum_picks(rows, plan, weights, interpret=True))
+    clean = np.asarray(rex._sum_picks(
+        jnp.where(plan.real[:, None], rows, 0.0), plan, weights,
+        interpret=True))
+    assert np.isfinite(got).all() and np.array_equal(got, clean)
+    # a token none of whose picks landed sums to zero
+    nothing = ~np.asarray(plan.landed).any(axis=1)
+    assert nothing.any() and not got[nothing].any()
+
+
+@pytest.mark.parametrize("interpret,path,block", [
+    (None, "jnp", 0), (True, "interpret", 32)])
+def test_a_traced_combine_is_counted_once_with_its_path(monkeypatch,
+                                                        interpret, path,
+                                                        block):
+    from fedml_tpu.core.mlops import metrics
+
+    plan, rows, weights = _combine_case("a quarter", 96, 128, 32, monkeypatch)
+
+    def count():
+        m = metrics.REGISTRY.collect().get("fedml_moe_combine_traces_total")
+        return {p: m.labels(path=p, width=128, block_tokens=b).value
+                if m else 0
+                for p, b in (("jnp", 0), ("interpret", 32), ("kernel", 32))}
+
+    jax.clear_caches()
+    before = count()
+    summed = jax.jit(lambda r, w: rex._sum_picks(r, plan, w, interpret))
+    summed(rows, weights)
+    summed(rows, weights)           # a call of a traced program counts nothing
+    after = count()
+    assert {p: after[p] - before[p] for p in after} == {
+        p: int(p == path) for p in after}
+
+
 def test_rows_passed_are_whole_chunks_over_the_landed_picks():
     """`rows_passed` is what the counted picks call for, chunk by chunk,
     not the layout's worst case."""
