@@ -21,7 +21,9 @@ OpenTelemetry-shaped identity on top of the existing mlops JSONL pipeline:
   with XLA events in a captured profiler trace;
 * `phase()` is the light tier for hot loops (an engine iteration, a
   ``train()`` call): a clock pair, the same annotation and the same
-  histogram, and none of the identity above.
+  histogram, and none of the identity above;
+* `scope()` is the device-side tier: a name on the instructions traced under
+  it, which a profiler trace's own HLO carries back.
 
 Everything is stdlib; JAX involvement is strictly optional.
 """
@@ -347,6 +349,19 @@ def phase(name: str) -> Phase:
     or has one suffix from a small set (a dispatch length, a prefill
     bucket): readers match names exactly and every name is a label."""
     return Phase(name)
+
+
+def scope(name: str) -> Any:
+    """``with scope("attn"): ...`` (or ``@scope("norm")`` on a function)
+    around JAX code as it is traced: the device-side tier.  It is
+    ``jax.named_scope("fedml." + name)`` and nothing else: no clock, no
+    histogram, nothing at run time.  The name reaches the ``op_name`` of
+    every instruction traced under it, and so the optimized HLO a profiler
+    trace carries (docs/OBSERVABILITY.md, "Device time by scope").  Metadata
+    only: the compiled program and its compilation-cache key do not change."""
+    import jax
+
+    return jax.named_scope("fedml." + name)
 
 
 #: `note_iteration` speaks up for an iteration longer than this many

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.mlops import tracing
 from ..ops.routed_experts import (Experts, held_experts, route,
                                   route_in_groups)
 
@@ -131,6 +132,7 @@ def init_lm_params(key: jax.Array, vocab: int, dim: int = 64,
     return p
 
 
+@tracing.scope("norm")
 def _norm(x, g, layer: Layer = GPT2):
     if layer.norm == "rmsnorm":
         return x * jax.lax.rsqrt(
@@ -219,6 +221,7 @@ def _bias(z, blk, key):
     return z + blk[key] if key in blk else z
 
 
+@tracing.scope("embed")
 def embed(params: Dict[str, Any], tokens: jnp.ndarray,
           pos: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Token plus position embedding.  ``tokens`` [B, T] with no ``pos``
@@ -299,25 +302,30 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
             z = _rotate(z, _rope_freq(layer.rope_theta, dh // 2))
         return z
 
-    if layer.latent is not None:
-        o = attend(*_latent_qkv(y, blk, heads, layer))
-    else:
-        kv = layer.kv_heads or heads
-        o = attend(proj("wq", "bq", heads), proj("wk", "bk", kv),
-                   proj("wv", "bv", kv))
-    a = h + _bias(o.reshape(*h.shape[:-1], -1) @ blk["wo"], blk, "bo")
+    kv = layer.kv_heads or heads
+    with tracing.scope("attn.qkv"):
+        qkv = (_latent_qkv(y, blk, heads, layer) if layer.latent is not None
+               else (proj("wq", "bq", heads), proj("wk", "bk", kv),
+                     proj("wv", "bv", kv)))
+    with tracing.scope("attn"):
+        o = attend(*qkv)
+    with tracing.scope("attn.out"):
+        a = h + _bias(o.reshape(*h.shape[:-1], -1) @ blk["wo"], blk, "bo")
     y = _norm(a, blk["ln2"], layer)
     if layer.experts is None:
-        return a + (_dense_mlp(y, blk) if layer.swiglu is None else
-                    _swiglu(y, blk["w_gate_up"], blk["w_down"]))
+        with tracing.scope("mlp"):
+            return a + (_dense_mlp(y, blk) if layer.swiglu is None else
+                        _swiglu(y, blk["w_gate_up"], blk["w_down"]))
     out, stats, picks = _expert_mlp(y, h, blk, layer.experts)
     if note is not None:
         note(stats, picks)
     if layer.shared is not None:
-        out = out + _swiglu(y, blk["shared_gate_up"], blk["shared_down"])
+        with tracing.scope("mlp.shared"):
+            out = out + _swiglu(y, blk["shared_gate_up"], blk["shared_down"])
     return a + out
 
 
+@tracing.scope("head")
 def head(h: jnp.ndarray, params: Dict[str, Any],
          layer: Layer = GPT2) -> jnp.ndarray:
     """Final norm and the output projection."""
@@ -458,6 +466,7 @@ _KEPT_PER_BLOCK = 16
 _REMAT_OVER = 2 * 2 ** 30
 
 
+@tracing.scope("loss")
 def loss_in_row_blocks(h: jnp.ndarray, w_out: jnp.ndarray, y: jnp.ndarray,
                        mask: jnp.ndarray, rows: int = LOSS_ROWS
                        ) -> jnp.ndarray:
@@ -474,7 +483,8 @@ def loss_in_row_blocks(h: jnp.ndarray, w_out: jnp.ndarray, y: jnp.ndarray,
     @jax.checkpoint
     def some(args):
         hb, yb, mb = args
-        logits = (hb @ w_out).astype(jnp.float32)
+        with tracing.scope("head"):
+            logits = (hb @ w_out).astype(jnp.float32)
         gold = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
         return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - gold) * mb)
 
@@ -619,6 +629,7 @@ class RoutedLMModule:
                            params["blocks"][:len(self.layers)], self.heads,
                            self._attention(), remat, self.layers)
 
+    @tracing.scope("mtp.join")
     def _joined(self, params, last, y):
         """What the second head's block reads: the trunk's ``last`` stream
         [B, T, D] (ahead of its final norm) joined at every position with

@@ -51,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.mlops import metrics as _metrics
+from ..core.mlops import tracing
 from .pallas_ops import _on_tpu
 
 NEG_INF = -1e30
@@ -534,6 +535,7 @@ def _flash_core(causal: bool, block_q: int, block_k: int,
             interpret=interpret, t_valid=t_valid, window=window)
         return o, (q, k, v, o, l, m)
 
+    @tracing.scope("attn_bwd")
     def bwd(res, do):
         q, k, v, o, l, m = res
         t = q.shape[2]

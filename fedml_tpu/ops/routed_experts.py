@@ -62,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.mlops import metrics as _metrics
+from ..core.mlops import tracing
 from .pallas_ops import _on_tpu
 
 #: what the operands of every expert product are rounded to (float32
@@ -122,6 +123,7 @@ class Plan(NamedTuple):
     counts: jax.Array           # [held] picks that landed on each expert
 
 
+@tracing.scope("router")
 def route(h: jax.Array, w_router: jax.Array,
           top_k: int) -> Tuple[jax.Array, jax.Array]:
     """``h`` [N, D] -> the ``top_k`` largest of the router's logits a token
@@ -134,6 +136,7 @@ def route(h: jax.Array, w_router: jax.Array,
     return picks, jax.nn.softmax(top, axis=-1)
 
 
+@tracing.scope("router")
 def route_in_groups(h: jax.Array, w_router: jax.Array, bias: jax.Array,
                     experts: Experts
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -235,6 +238,7 @@ def rows_passed(plan: Plan):
     return rows * chunks
 
 
+@tracing.scope("experts.plan")
 def plan_rows(picks: jax.Array, experts: Experts, tile: int = TILE) -> Plan:
     """Sort the picks that landed on held experts by expert and give each
     expert whole tiles.  Everything is an index computation on
@@ -391,6 +395,7 @@ def _experts_call(x, w, tile_expert, live_tiles, *, transposed: bool,
     )(tile_expert, live_tiles, x, w)
 
 
+@tracing.scope("experts.products")
 def grouped_matmul(x, w, plan: Plan, transposed: bool = False,
                    interpret: Optional[bool] = None):
     """Row ``r`` of ``x`` [M, K] times the matrix of its tile's expert,
@@ -405,6 +410,7 @@ def grouped_matmul(x, w, plan: Plan, transposed: bool = False,
                          transposed=transposed, interpret=bool(interpret))
 
 
+@tracing.scope("experts.products")
 def _matrices_grad(a, b, plan: Plan):
     """``sum over an expert's real rows of a_r (x) b_r`` [held, Ka, Kb]: the
     gradient of the matrices themselves, for a caller that trains them.
@@ -477,6 +483,7 @@ def _sum_block(n: int, d: int) -> int:
                -(-n // 8) * 8)
 
 
+@tracing.scope("experts.plan")
 def _chunks_of_blocks(plan: Plan, block: int, chunk: int):
     """What each block of ``block`` tokens fetches: ``[blocks, stride]``
     int32, a block's count of chunks and then the chunks themselves (of
@@ -617,6 +624,7 @@ def _note_combine(path: str, width: int, block_tokens: int = 0) -> None:
     ).labels(path=path, width=width, block_tokens=block_tokens).inc()
 
 
+@tracing.scope("experts.combine")
 def _sum_picks(rows, plan: Plan, weights=None,
                interpret: Optional[bool] = None):
     """[M, D] -> [N, D] float32: over a token's picks that landed, its rows
@@ -673,6 +681,7 @@ def _hidden(gate_up, plan: Plan, act: str):
         lambda start, rows: _glu(_chunk(gate_up, start, rows), act))
 
 
+@tracing.scope("experts.layout")
 def _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret, act):
     # rounded once here: every product's operands are bfloat16
     x = _rows_of(y.astype(_OPERAND), plan)
@@ -683,6 +692,7 @@ def _held_share_fwd(y, weights, w_gate_up, w_down, plan, interpret, act):
             (x, gate_up, weights, w_gate_up, w_down, plan))
 
 
+@tracing.scope("experts.layout")
 def _held_share_bwd(interpret, act, res, d_out):
     """By hand, so that every movement of rows is a gather (autodiff would
     transpose each into a scatter), every cotangent stays float32, and the

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.mlops import tracing
 from ..models.functional_lm import (
     block,
     embed,
@@ -143,10 +144,13 @@ def _decode_core_chunked(params: Dict[str, Any],
             nonlocal kc, vc
             # uniform-position write: every row writes chunk slot j (cheap
             # contiguous dynamic_update_slice, no per-row scatter)
-            kc = jax.lax.dynamic_update_slice(
-                kc, k_new[None, :, None].astype(kc.dtype), (li, 0, j, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v_new[None, :, None].astype(vc.dtype), (li, 0, j, 0, 0))
+            with tracing.scope("cache_write"):
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k_new[None, :, None].astype(kc.dtype),
+                    (li, 0, j, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v_new[None, :, None].astype(vc.dtype),
+                    (li, 0, j, 0, 0))
             return _attend_cache_and_chunk(q, layer, kc[li], vc[li], pos0, j)
 
         h = block(h, blk, heads, attend)
@@ -165,6 +169,7 @@ def _decode_core_chunked(params: Dict[str, Any],
 FILTER_CAP = 128
 
 
+@tracing.scope("sample")
 def _filter_sample(logits: jnp.ndarray, temps: jnp.ndarray,
                    top_k: jnp.ndarray, top_p: jnp.ndarray,
                    key: jax.Array) -> jnp.ndarray:
@@ -224,6 +229,7 @@ def _filter_sample(logits: jnp.ndarray, temps: jnp.ndarray,
 EXACT_FILTER_ITERS = 30
 
 
+@tracing.scope("sample")
 def _exact_filter_sample(logits: jnp.ndarray, temps: jnp.ndarray,
                          top_k: jnp.ndarray, top_p: jnp.ndarray,
                          key: jax.Array) -> jnp.ndarray:
@@ -388,10 +394,11 @@ def _decode_multi(params: Dict[str, Any],
     # chunk slot j of row i is position pos0[i] + j of the cache
     out_cache = []
     for li, layer in enumerate(cache):
-        new_k, new_v = store_positions(
-            [layer["k"], layer["v"]],
-            [kc[li].transpose(0, 2, 3, 1), vc[li].transpose(0, 2, 3, 1)],
-            pos0)
+        with tracing.scope("cache_write"):
+            new_k, new_v = store_positions(
+                [layer["k"], layer["v"]],
+                [kc[li].transpose(0, 2, 3, 1), vc[li].transpose(0, 2, 3, 1)],
+                pos0)
         out_cache.append({"k": new_k, "v": new_v})
     return out_cache, emitted.T                            # [B, k]
 

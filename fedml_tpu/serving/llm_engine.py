@@ -894,7 +894,9 @@ class KVCacheLLMEngine:
             turbo, admit_s = self._admit()
             if self.active_count == 0:
                 try:
-                    req = self._pending.get(timeout=0.5)
+                    # the one wait of the loop with no work offered
+                    with tracing.phase("fedml.serve.empty"):
+                        req = self._pending.get(timeout=0.5)
                 except queue.Empty:
                     continue
                 turbo, admit_s = self._admit_one(0, req)

@@ -16,6 +16,8 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...core.mlops import tracing
+
 DEFAULT_TARGETS = (r".*attention.*kernel", r".*(query|key|value|out).*kernel",
                    r".*Dense_\d+.*kernel",
                    # functional-LM layout (models/functional_lm.py):
@@ -60,6 +62,7 @@ def init_lora(params: Any, rank: int = 8, targets: Sequence[str] = None,
     return lora
 
 
+@tracing.scope("lora")
 def apply_lora(params: Any, lora: Dict[str, Any], alpha: float = 16.0
                ) -> Any:
     """Effective params: W' = W + (alpha/r)·A@B for targeted kernels.  A

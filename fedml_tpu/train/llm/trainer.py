@@ -194,7 +194,9 @@ class LLMTrainer:
                                 batch["mask"])
             logits, _ = bundle.apply(variables, batch["x"], train=True,
                                      rng=rng)
-            return masked_loss("lm", logits, batch["y"], batch["mask"]), {}
+            with tracing.scope("loss"):
+                return masked_loss("lm", logits, batch["y"],
+                                   batch["mask"]), {}
 
         # the name is the program's in a device trace: ``jit_sft_epoch``
         def sft_epoch(trainable, opt_state, base_params, model_state,
@@ -232,8 +234,10 @@ class LLMTrainer:
                 (loss, counted), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(
                         trainable, base_params, model_state, batch, sub)
-                updates, opt_state = tx.update(grads, opt_state, trainable)
-                trainable = optax.apply_updates(trainable, updates)
+                with tracing.scope("opt"):
+                    updates, opt_state = tx.update(grads, opt_state,
+                                                   trainable)
+                    trainable = optax.apply_updates(trainable, updates)
                 return (trainable, opt_state, rng), (loss, counted)
 
             (trainable, opt_state, _), (losses, counted) = jax.lax.scan(

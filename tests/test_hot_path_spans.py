@@ -11,6 +11,7 @@ import jax
 import numpy as np
 import pytest
 
+from chipbench.harness import scopes
 from fedml_tpu.core.mlops import metrics, tracing
 
 
@@ -343,3 +344,195 @@ def test_trainer_logs_the_call_that_stood_still(caplog, monkeypatch):
              if "train() took" in r.getMessage()]
     assert line.startswith("llm-trainer: train() took 10")
     assert "pack 0.0" in line and "loss_fetch 10" in line
+
+
+# -- the device-side tier: `tracing.scope` ------------------------------------
+
+def test_scope_is_a_name_and_nothing_at_run_time(registry):
+    import jax.numpy as jnp
+
+    @tracing.scope("test.decorated")
+    def twice(x):
+        return x * 2.0
+
+    def fn(x):
+        with tracing.scope("test.block"):
+            return jnp.tanh(twice(x))
+
+    text = jax.jit(fn).lower(np.ones(3, np.float32)).compile().as_text()
+    assert "fedml.test.block/fedml.test.decorated/mul" in text
+    fn(np.ones(3, np.float32))
+    # no clock and no histogram: `phase` observes one sample a use
+    assert registry.collect().get("fedml_span_seconds") is None
+    with tracing.phase("test.scope_beside"):
+        pass
+    assert _span_count("test.scope_beside") == 1
+
+
+def _held_scopes(compiled):
+    """What `chipbench.harness.scopes` reads out of a compiled program's own
+    HLO: the scopes of the instructions it holds, by direction."""
+    module = compiled.runtime_executable().hlo_modules()[0]
+    whole = scopes.instructions(module.as_serialized_hlo_module_proto())[
+        scopes.ENTRY].holds
+    by = {}
+    for (scope, direction), n in whole.items():
+        by.setdefault(scope, set()).add(direction)
+    return by
+
+
+_BLOCK = {"fedml.embed", "fedml.norm", "fedml.attn.qkv", "fedml.attn",
+          "fedml.attn_bwd", "fedml.attn.out", "fedml.head", "fedml.loss",
+          "fedml.lora", "fedml.opt"}
+_ROUTED = _BLOCK | {"fedml.router", "fedml.experts.plan",
+                    "fedml.experts.layout", "fedml.experts.products",
+                    "fedml.experts.combine"}
+
+
+def _gpt2_args():
+    return dict(model="functional_lm", dataset="shakespeare", lm_dim=32,
+                lm_layers=2, lm_heads=4, lm_max_len=32), 90, 2
+
+
+def _routed_args():
+    from chipbench.planes.sft_routed import model_args
+
+    return model_args({
+        "hidden_size": 32, "head_dim": 8, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "num_hidden_layers": 4,
+        "moe_ffn_hidden_size": 24, "moe_num_primary_experts": 4,
+        "moe_num_active_primary_experts": 3, "experts_first_held": 8,
+        "published": {"moe_num_primary_experts": 16}, "rms_norm_eps": 1e-6,
+        "rope_theta": 1500000, "sliding_window_size": 12,
+        "rope_layout": [0, 1, 1, 1],
+        "sliding_window_layout": [0, 1, 1, 1]}), 211, 1
+
+
+def _mla_args():
+    from chipbench.planes.sft_mla import model_args
+    from mla_tiny import CFG
+
+    # a dense layer, a routed one, the second head's block
+    return model_args(dict(CFG, num_hidden_layers=2)), CFG["vocab_size"], 1
+
+
+@pytest.mark.parametrize("family, wanted", [
+    (_gpt2_args, _BLOCK | {"fedml.mlp"}),
+    (_routed_args, _ROUTED),
+    (_mla_args, _ROUTED | {"fedml.mlp", "fedml.mlp.shared",
+                           "fedml.mtp.join"})])
+def test_every_scope_reaches_the_epoch_program(monkeypatch, family, wanted):
+    """The epoch programs of the three families, compiled here, name every
+    scope of docs/OBSERVABILITY.md's table in their optimized HLO.  The
+    attention goes through the kernel's interpreter, so that its backward is
+    the `custom_vjp`'s, as on the chip."""
+    import functools
+
+    import fedml_tpu
+    import jax.numpy as jnp
+    from fedml_tpu.ops import pallas_attention
+    from fedml_tpu.train.llm.trainer import (LLMTrainConfig, LLMTrainer,
+                                             pack_sequences)
+
+    monkeypatch.setattr(pallas_attention, "flash_attention", functools.partial(
+        pallas_attention.flash_attention, interpret=True))
+    args, vocab, batch = family()
+    trainer = LLMTrainer(fedml_tpu.model.create(fedml_tpu.Config(**args),
+                                                vocab),
+                         LLMTrainConfig(seq_len=16, batch_size=batch,
+                                        lora_rank=2))
+    batches = jax.tree_util.tree_map(jnp.asarray, pack_sequences(
+        np.arange(16 * batch * 2 + 1) % vocab, 16, batch))
+    by = _held_scopes(trainer._train_epoch.lower(
+        trainer.lora, trainer.tx.init(trainer.lora),
+        trainer.variables["params"], {}, batches,
+        jax.random.PRNGKey(1)).compile())
+    assert set(by) - set(scopes.LOST) == wanted
+    assert by["fedml.attn_bwd"] == {"bwd"} and by["fedml.opt"] == {"fwd"}
+    assert {"fwd", "bwd"} <= by["fedml.norm"] and "bwd" in by["fedml.lora"]
+    # the loss's row blocks are made again in the backward pass
+    if "fedml.router" in wanted:
+        assert "remat" in by["fedml.loss"] and "remat" in by["fedml.head"]
+
+
+def test_every_scope_reaches_the_serving_programs():
+    import jax.numpy as jnp
+    from fedml_tpu.serving import kv_cache_lm
+
+    lm = kv_cache_lm.KVCacheLM.create(jax.random.PRNGKey(0), vocab=40,
+                                      dim=32, layers=1, heads=2, max_len=32)
+    b, k = 2, 2
+    vec = lambda dt, *s: jax.ShapeDtypeStruct((b, *s), dt)
+    decode = _held_scopes(kv_cache_lm.decode_multi.lower(
+        lm.params, lm.init_cache(b), vec(jnp.int32, k), vec(jnp.int32),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+        jax.random.PRNGKey(1), heads=2, k=k, exact_filters=False).compile())
+    dense = {"fedml.embed", "fedml.norm", "fedml.attn.qkv", "fedml.attn",
+             "fedml.attn.out", "fedml.mlp", "fedml.head"}
+    assert set(decode) - set(scopes.LOST) == dense | {"fedml.sample",
+                                                      "fedml.cache_write"}
+    prefill = _held_scopes(kv_cache_lm.prefill.lower(
+        lm.params, vec(jnp.int32, 32), vec(jnp.int32), heads=2,
+        max_len=32).compile())
+    assert set(prefill) - set(scopes.LOST) == dense
+    assert all(d == {"fwd"} for d in {**decode, **prefill}.values())
+
+
+def test_direction_comes_from_the_path_jax_writes():
+    """A `custom_vjp` under `scan` and `checkpoint`: its forward is fwd in
+    the forward pass and remat in the backward's, its backward bwd under a
+    scope of its own, and what autodiff transposes bwd under the scope the
+    forward had."""
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def wave(x):
+        return jnp.sin(x)
+
+    def wave_bwd(x, g):
+        with tracing.scope("test.wave_bwd"):
+            return (g * jnp.cos(x),)
+
+    wave.defvjp(lambda x: (jnp.sin(x), x), wave_bwd)
+
+    @jax.checkpoint
+    def blk(c):
+        with tracing.scope("test.wave"):
+            c = wave(c)
+        with tracing.scope("test.square"):
+            return c * c
+
+    def loss(x):
+        return jnp.sum(jax.lax.scan(lambda c, _: (blk(c), None), x, None,
+                                    length=3)[0])
+
+    by = _held_scopes(jax.jit(jax.grad(loss)).lower(
+        np.ones((4, 4), np.float32)).compile())
+    assert by["fedml.test.wave"] == {"fwd", "remat"}
+    assert by["fedml.test.wave_bwd"] == {"bwd"}
+    # nothing reads the block's last product again: it is not made again
+    assert by["fedml.test.square"] == {"fwd", "bwd"}
+
+
+def test_an_engine_that_stands_empty_says_so_in_a_profiler_trace(tmp_path):
+    import time
+
+    from fedml_tpu.serving.kv_cache_lm import KVCacheLM
+    from fedml_tpu.serving.llm_engine import KVCacheLLMEngine
+
+    lm = KVCacheLM.create(jax.random.PRNGKey(3), vocab=40, dim=32, layers=1,
+                          heads=2, max_len=32)
+    eng = KVCacheLLMEngine(lm, max_batch=2, tokens_per_dispatch=2)
+    try:
+        with _Profiler(tmp_path):
+            # the wait for a request turns every 0.5 s: one wait at least
+            # both starts and ends inside the session
+            time.sleep(1.2)
+    finally:
+        eng.stop()
+    waits = [e for e in _host_events(tmp_path)
+             if e[0] == "fedml.serve.empty"]
+    assert waits and not [e for e in _host_events(tmp_path)
+                          if e[0].startswith("fedml.serve.")
+                          and e[0] != "fedml.serve.empty"]
+    assert _span_count("fedml.serve.empty") >= len(waits)
