@@ -27,6 +27,15 @@ or backward.  Grouped heads (``k``/``v`` of fewer heads than ``q``): the q
 heads of a group read the one K/V head through the block index, so it is
 fetched once a group where it is resident.
 
+The backward is a kernel beside it, `flash_bwd` (`_flash_bwd_kernel`): the
+same tiles, the same walk and the same [keys, queries] scores, recomputed
+from what the forward keeps (o, l, m, and q x scale, k and v rounded to
+bfloat16 as every product takes them: `_rounded`) and spent in VMEM where
+they are made, five products a tile (s, dp, dv, dk, dq) on bfloat16 operands
+into float32.  dK and dV of a K/V head are float32 accumulators
+that stay in VMEM over the q heads of its group and all their q tiles; dQ of
+a q tile is summed over its walk and written once.
+
 Masking convention as `parallel.ring_attention.reference_attention`; equal
 to it up to the bfloat16 rounding of the products' operands.  Dispatch:
 
@@ -36,7 +45,8 @@ to it up to the bfloat16 rounding of the products' operands.  Dispatch:
 * otherwise → a jnp fallback computed in float32.
 
 `fedml_attention_traces_total` counts, as calls are traced, which of these
-ran and with which tile.
+ran and with which tile (``path`` `kernel`, `reference`, and `kernel_bwd`
+once a traced backward).
 """
 
 from __future__ import annotations
@@ -96,6 +106,50 @@ def _reference(q, k, v, causal, window=None):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _pass_bounds(i, block_q: int, block_k: int, t_valid: int, causal: bool,
+                 window: Optional[int]):
+    """Which key sub-blocks of ``block_k``, numbered over the whole key
+    axis, q tile ``i`` walks, forward and backward: (n_dead, n_edge, n_full,
+    n_live).  The walk is [n_dead, n_live): a sub-block before it holds no
+    key the tile's first query sees under the window, one after it none
+    that is valid and, under ``causal``, at or below the tile's last query;
+    neither is ever visited.  Those in [n_edge, n_full) need no mask (every
+    key valid and seen by every query of the tile); up to n_edge a
+    sub-block holds keys the tile's last query no longer sees (without a
+    window there are none, and n_dead and n_edge are None), from n_full keys
+    above the diagonal or past ``t_valid``."""
+    n_dead = n_edge = None
+    n_full, n_live = t_valid // block_k, -(-t_valid // block_k)
+    if causal:
+        n_full = jnp.minimum(n_full, (i * block_q + 1) // block_k)
+        n_live = jnp.minimum(n_live, (i * block_q + block_q - 1) // block_k + 1)
+    if window is not None:
+        n_dead = jnp.maximum(i * block_q - (window - 1), 0) // block_k
+        n_edge = (jnp.maximum(i * block_q + block_q - window, 0)
+                  + block_k - 1) // block_k
+        n_edge = jnp.clip(n_edge, n_dead, n_live)
+        n_full = jnp.clip(n_full, n_edge, n_live)
+    return n_dead, n_edge, n_full, n_live
+
+
+def _walk(step, carry, bounds, first, subs: int):
+    """``step(masked, sub, carry)`` over the sub-blocks of `_pass_bounds`
+    that lie in a K/V block of ``subs`` starting at sub-block ``first``,
+    numbered within the block: the window's edge masked, the full ones not,
+    the diagonal's masked."""
+    n_dead, n_edge, n_full, n_live = (
+        n if n is None else jnp.clip(n - first, 0, subs) for n in bounds)
+    start = 0
+    if n_edge is not None:
+        start = n_edge
+        carry = jax.lax.fori_loop(n_dead, n_edge,
+                                  functools.partial(step, True), carry)
+    carry = jax.lax.fori_loop(start, n_full,
+                              functools.partial(step, False), carry)
+    return jax.lax.fori_loop(n_full, n_live,
+                             functools.partial(step, True), carry)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
                   o_acc, l_acc, m_acc, *, block_k: int, t_valid: int,
                   causal: bool, window: Optional[int], scale: float):
@@ -127,23 +181,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
         l_acc[:] = jnp.zeros_like(l_acc)
         m_acc[:] = jnp.full_like(m_acc, NEG_INF)
 
-    # key sub-blocks are numbered over the whole key axis.  The first
-    # `n_full` need no mask (every key valid and, under `causal`, at or
-    # below the tile's first query); those up to `n_live` hold a key some
-    # query of the tile attends to; the rest are never visited.
-    n_full, n_live = t_valid // block_k, -(-t_valid // block_k)
-    if causal:
-        n_full = jnp.minimum(n_full, (i * block_q + 1) // block_k)
-        n_live = jnp.minimum(n_live, (i * block_q + block_q - 1) // block_k + 1)
-    if window is not None:
-        # under a window the walk starts at `n_dead`, the first sub-block
-        # with a key the tile's first query sees; up to `n_edge` a
-        # sub-block holds keys the tile's last query no longer sees
-        n_dead = jnp.maximum(i * block_q - (window - 1), 0) // block_k
-        n_edge = (jnp.maximum(i * block_q + block_q - window, 0)
-                  + block_k - 1) // block_k
-        n_edge = jnp.clip(n_edge, n_dead, n_live)
-        n_full = jnp.clip(n_full, n_edge, n_live)
+    bounds = _pass_bounds(i, block_q, block_k, t_valid, causal, window)
     first = j * subs
     q = (q_ref[0].astype(jnp.float32) * scale).astype(jnp.bfloat16)
     q_pos = i * block_q + jax.lax.broadcasted_iota(
@@ -179,20 +217,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref,
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         return o, l, new_m
 
-    carry = (o_acc[:], l_acc[:], m_acc[:])
-    full_end = jnp.clip(n_full - first, 0, subs)
-    full_start = 0
-    if window is not None:
-        full_start = jnp.clip(n_edge - first, 0, subs)
-        carry = jax.lax.fori_loop(
-            jnp.clip(n_dead - first, 0, subs), full_start,
-            functools.partial(step, True), carry)
-    carry = jax.lax.fori_loop(
-        full_start, full_end, functools.partial(step, False), carry)
-    carry = jax.lax.fori_loop(
-        full_end, jnp.clip(n_live - first, 0, subs),
-        functools.partial(step, True), carry)
-    o_acc[:], l_acc[:], m_acc[:] = carry
+    o_acc[:], l_acc[:], m_acc[:] = _walk(
+        step, (o_acc[:], l_acc[:], m_acc[:]), bounds, first, subs)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -252,13 +278,12 @@ def _kv_bytes_per_key(d: int, itemsize: int) -> int:
     return 4 * -(-d // _LANES) * _LANES * itemsize
 
 
-def _kv_block(tk: int, block_k: int, d: int, itemsize: int) -> int:
-    """Keys a grid step holds in VMEM: the whole axis when K and V fit
-    `_KV_VMEM_BUDGET`, else the most whole sub-blocks that do and that
-    divide the axis."""
-    per_key = _kv_bytes_per_key(d, itemsize)
+def _kv_block(tk: int, block_k: int, per_key: int, budget: int) -> int:
+    """Keys a grid step holds in VMEM at ``per_key`` bytes each: the whole
+    axis when that fits ``budget``, else the most whole sub-blocks that do
+    and that divide the axis."""
     subs = tk // block_k
-    fit = max(1, _KV_VMEM_BUDGET // (per_key * block_k))
+    fit = max(1, budget // (per_key * block_k))
     return block_k * max(n for n in range(1, subs + 1)
                          if subs % n == 0 and n <= fit)
 
@@ -336,9 +361,7 @@ def _flash_call(q, k, v, *, causal: bool, block_q: int, block_k: int,
                         pltpu.VMEM((1, block_q), jnp.float32),
                         pltpu.VMEM((1, block_q), jnp.float32)],
         interpret=interpret,
-        # the kernel's name in a device trace; the blockwise backward is
-        # plain jnp and has none there
-        name="flash_fwd",
+        name="flash_fwd",       # the kernel's name in a device trace
         **params,
     )(q.reshape(b * h, t, d), k.reshape(b * hk, tk, d),
       v.reshape(b * hk, tk, d))
@@ -374,7 +397,9 @@ def flash_attention_residuals(q: jnp.ndarray, k: jnp.ndarray,
             or (causal and tk != t)):
         _note_trace("reference", q.shape[3])
         return _reference_residuals(q, k, v, causal, t_valid, window)
-    block_kv = _kv_block(tk, block_k, q.shape[3], k.dtype.itemsize)
+    block_kv = _kv_block(
+        tk, block_k, _kv_bytes_per_key(q.shape[3], k.dtype.itemsize),
+        _KV_VMEM_BUDGET)
     _note_trace("kernel", q.shape[3], block_q, block_k,
                 kv_resident=block_kv == tk)
     return _flash_call(q, k, v, causal=causal, block_q=block_q,
@@ -392,132 +417,178 @@ def flash_mha(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return o.transpose(0, 2, 1, 3)
 
 
-#: queries and keys of a tile of the backward of a long sequence
-#: (`_flash_backward_tiled`); a sequence of one such tile has nothing to
-#: skip at that grain and takes `_flash_backward_blockwise`
-_BWD_TILE = 1024
+def _flash_bwd_kernel(q_ref, do_ref, o_ref, l_ref, m_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, *, block_k: int, t_valid: int,
+                      causal: bool, window: Optional[int], scale: float):
+    """One grid step of grid (B x K/V heads, K/V blocks, q heads of the
+    group, q tiles): the gradients a [block_q, D] q tile and the step's K/V
+    block owe each other, ``block_k`` keys at a pass over the sub-blocks
+    `_flash_kernel` walks for the same tile, and no others.
 
+    q (times the scale), k and v arrive rounded to bfloat16 (`_rounded`).
+    A pass recomputes its scores from them and the forward's l and m and
+    spends them where they are: ``p = exp(s - m) / l`` (as ``exp(s - (m + log
+    l))``), ``ds = p * (dp - delta)`` with ``delta = sum(do * o)`` a row of
+    the tile, and the five products ``s = k q^T``, ``dp = v do^T``, ``dv +=
+    p do``, ``dk += ds q``, ``dq^T += k^T ds`` on bfloat16 operands into
+    float32.  Scores are held [keys, queries], so l, m and delta are
+    lane-dense rows.  ``dk_ref`` / ``dv_ref`` are the K/V block's float32
+    accumulators: output blocks whose index changes only with the K/V head
+    and block, resident over the group's q heads and all their q tiles and
+    written to HBM once.  dQ of the tile is summed over the walk ([D,
+    block_q], turned once) and written once; with several K/V blocks each
+    writes its own partial (`_flash_bwd_call` sums them)."""
+    block_q, block_kv = q_ref.shape[1], k_ref.shape[1]
+    subs = block_kv // block_k
+    j, i = pl.program_id(1), pl.program_id(3)
 
-def _flash_backward_tiled(q, k, v, o, l, m, do, window: Optional[int],
-                          t_valid: int, tile: int):
-    """Exact causal attention backward over [tile x tile] score tiles that
-    hold a visible pair, and no others: for each key tile (a `lax.scan`)
-    only the query tiles from its diagonal to the end of its window are
-    walked (a `fori_loop` with those bounds), so a long sequence pays for
-    the half under the diagonal, and a windowed one for the band.  The
-    same recomputation from the saved residuals as
-    `_flash_backward_blockwise`; K and V may have fewer heads than q."""
-    b, h, t, d = q.shape
-    hk, group = k.shape[1], h // k.shape[1]
-    scale = 1.0 / float(d) ** 0.5
-    n = t // tile
-    f32 = jnp.float32
-    # [B, Hk, G, T, ...]: the q heads of a group beside their K/V head
-    qg = q.reshape(b, hk, group, t, d)
-    dog = do.reshape(b, hk, group, t, d)
-    delta = jnp.sum(dog.astype(f32) * o.reshape(qg.shape).astype(f32), -1)
-    lg = jnp.maximum(l, 1e-12).reshape(b, hk, group, t)
-    mg = m.reshape(b, hk, group, t)
-    last = n if window is None else (tile + window - 2) // tile + 1
+    @pl.when((pl.program_id(2) == 0) & (i == 0))
+    def _init():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    def rows(x, i, axis):
-        return jax.lax.dynamic_slice_in_dim(x, i * tile, tile, axis)
+    bounds = _pass_bounds(i, block_q, block_k, t_valid, causal, window)
+    first = j * subs
+    q = q_ref[0]        # times the scale already: dk = ds^T q needs none
+    do = do_ref[0].astype(jnp.float32)
+    delta = jnp.sum((do * o_ref[0].astype(jnp.float32)).T, axis=0,
+                    keepdims=True)                              # [1, bq]
+    do = do.astype(jnp.bfloat16)
+    lse = m_ref[0] + jnp.log(jnp.maximum(l_ref[0], 1e-12))      # [1, bq]
+    q_pos = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_q), 1)
 
-    def key_tile(dq, j):
-        k_j, v_j = rows(k, j, 2).astype(f32), rows(v, j, 2).astype(f32)
-        k_pos = j * tile + jnp.arange(tile)[None, :]
-
-        def query_tile(i, carry):
-            dq, dk_j, dv_j = carry
-            q_i, do_i = rows(qg, i, 3).astype(f32), rows(dog, i, 3).astype(f32)
-            gap = i * tile + jnp.arange(tile)[:, None] - k_pos
-            mask = (gap >= 0) & (k_pos < t_valid)
+    def step(masked, sub, dq):                                  # dq [D, bq]
+        rows = pl.ds(pl.multiple_of(sub * block_k, block_k), block_k)
+        k = k_ref[0, rows, :]
+        s = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [bk, bq]
+        p = jnp.exp(s - lse)
+        if masked:
+            k_pos = (first + sub) * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            mask = k_pos < t_valid
+            if causal:
+                mask = mask & (q_pos >= k_pos)
             if window is not None:
-                mask = mask & (gap < window)
-            s = jnp.einsum("bcgqd,bckd->bcgqk", q_i, k_j) * scale
-            p = jnp.where(mask, jnp.exp(s - rows(mg, i, 3)[..., None]), 0.0)
-            p = p / rows(lg, i, 3)[..., None]
-            dv_j = dv_j + jnp.einsum("bcgqk,bcgqd->bckd", p, do_i)
-            dp = jnp.einsum("bcgqd,bckd->bcgqk", do_i, v_j)
-            ds = p * (dp - rows(delta, i, 3)[..., None])
-            dq_i = jnp.einsum("bcgqk,bckd->bcgqd", ds, k_j) * scale
-            dq = jax.lax.dynamic_update_slice_in_dim(
-                dq, rows(dq, i, 3) + dq_i, i * tile, 3)
-            dk_j = dk_j + jnp.einsum("bcgqk,bcgqd->bckd", ds, q_i) * scale
-            return dq, dk_j, dv_j
+                mask = mask & (q_pos - k_pos < window)
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(
+            v_ref[0, rows, :], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta)).astype(jnp.bfloat16)
+        dv_ref[0, rows, :] += jnp.dot(p.astype(jnp.bfloat16), do,
+                                      preferred_element_type=jnp.float32)
+        dk_ref[0, rows, :] += jnp.dot(ds, q,
+                                      preferred_element_type=jnp.float32)
+        return dq + jax.lax.dot_general(
+            k, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-        zero = jnp.zeros((b, hk, tile, d), f32)
-        dq, dk_j, dv_j = jax.lax.fori_loop(
-            j, jnp.minimum(j + last, n), query_tile, (dq, zero, zero))
-        return dq, (dk_j, dv_j)
-
-    dq, (dk_b, dv_b) = jax.lax.scan(
-        key_tile, jnp.zeros(qg.shape, f32), jnp.arange(n))
-    dk = dk_b.transpose(1, 2, 0, 3, 4).reshape(k.shape)
-    dv = dv_b.transpose(1, 2, 0, 3, 4).reshape(v.shape)
-    return (dq.reshape(q.shape).astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    dq = _walk(step, jnp.zeros(q.shape[::-1], jnp.float32), bounds, first,
+               subs)
+    dq_ref[0, 0] = dq.T * scale
 
 
-def _flash_backward_blockwise(q, k, v, o, l, m, do, causal: bool,
-                              t_valid: int, block_k: int,
-                              window: Optional[int] = None):
-    """Exact attention backward with O(T·block_k) score memory: lax.scan
-    over key blocks recomputing p = exp(s − m)/l from the saved softmax
-    residuals (FlashAttention-2 backward, jnp formulation — XLA fuses it;
-    runs everywhere, no kernel needed for correctness).  K and V of fewer
-    heads than q are spread over their groups, and their gradients summed
-    over them."""
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "window", "block_q", "block_k", "block_kv", "t_valid",
+    "interpret"))
+def _flash_bwd_call(q, k, v, o, l, m, do, *, causal: bool, block_q: int,
+                    block_k: int, block_kv: int, t_valid: int,
+                    interpret: bool, window: Optional[int] = None):
+    """`_flash_bwd_kernel` over what `_flash_core` keeps of a `_flash_call`
+    (q, k and v as `_rounded` leaves them, o, l, m) and the cotangent of
+    its output: float32 (dq, dk, dv).  Under its own `jit`, as `_flash_call`
+    is."""
     b, h, t, d = q.shape
-    kv_shape = k.shape
-    k, v = _spread_heads(q, k, v)
-    tk = k.shape[2]
-    scale = 1.0 / float(d) ** 0.5
-    qf = q.astype(jnp.float32)
-    do_f = do.astype(jnp.float32)
-    delta = jnp.sum(do_f * o.astype(jnp.float32), axis=-1)      # [B,H,T]
-    nk = tk // block_k
-    kb = k.reshape(b, h, nk, block_k, d).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(b, h, nk, block_k, d).transpose(2, 0, 1, 3, 4)
-    q_pos = jnp.arange(t)[:, None]
+    hk, tk = k.shape[1], k.shape[2]
+    group, blocks = h // hk, tk // block_kv
 
-    def body(carry, xs):
-        dq, j = carry[0], carry[1]
-        k_j, v_j = xs
-        k_j = k_j.astype(jnp.float32)
-        v_j = v_j.astype(jnp.float32)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_j) * scale
-        k_pos = j * block_k + jnp.arange(block_k)[None, :]
-        mask = (k_pos < t_valid)
+    def q_head(bi, g):          # q head g of the group of K/V head bi
+        return bi // hk * h + bi % hk * group + g
+
+    def q_tile(bi, j, g, i):
+        # a q tile no key of the K/V block reaches is not fetched: the
+        # index stays on the block's first live tile, or its last
         if causal:
-            mask = mask & (q_pos >= k_pos)
+            i = jnp.maximum(i, j * block_kv // block_q)
         if window is not None:
-            mask = mask & (q_pos - k_pos < window)
-        p = jnp.where(mask[None, None], jnp.exp(s - m[..., None]), 0.0)
-        p = p / jnp.maximum(l[..., None], 1e-12)
-        dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, do_f)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", do_f, v_j)
-        ds = p * (dp - delta[..., None])
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, k_j) * scale
-        dk_j = jnp.einsum("bhqk,bhqd->bhkd", ds, qf) * scale
-        return (dq, j + 1), (dk_j, dv_j)
+            i = jnp.minimum(
+                i, (j * block_kv + block_kv + window - 2) // block_q)
+        return q_head(bi, g), i
 
-    (dq, _), (dk_b, dv_b) = jax.lax.scan(
-        body, (jnp.zeros((b, h, t, d), jnp.float32), 0), (kb, vb))
-    dk = dk_b.transpose(1, 2, 0, 3, 4).reshape(b, h, tk, d)
-    dv = dv_b.transpose(1, 2, 0, 3, 4).reshape(b, h, tk, d)
-    if kv_shape[1] != h:
-        dk, dv = (z.reshape(b, kv_shape[1], -1, tk, d).sum(2)
-                  for z in (dk, dv))
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    def q_map(bi, j, g, i):
+        return (*q_tile(bi, j, g, i), 0)
+
+    def row_map(bi, j, g, i):
+        bi, i = q_tile(bi, j, g, i)
+        return bi, 0, i
+
+    def kv_map(bi, j, g, i):
+        return bi, j, 0
+
+    def dq_map(bi, j, g, i):
+        return j, q_head(bi, g), i, 0
+
+    params = {}
+    kv_vmem = (_kv_bytes_per_key(d, k.dtype.itemsize)
+               + _kv_bytes_per_key(d, 4)) * block_kv
+    if kv_vmem > _KV_VMEM_DEFAULT:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=kv_vmem + 24 * 2 ** 20)
+
+    rows = (b * h, t, d)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_k=block_k,
+                          t_valid=t_valid, causal=causal, window=window,
+                          scale=1.0 / float(d) ** 0.5),
+        grid=(b * hk, blocks, group, t // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, 1, block_q), row_map),
+            pl.BlockSpec((1, 1, block_q), row_map),
+            pl.BlockSpec((1, block_kv, d), kv_map),
+            pl.BlockSpec((1, block_kv, d), kv_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, d), dq_map),
+            pl.BlockSpec((1, block_kv, d), kv_map),
+            pl.BlockSpec((1, block_kv, d), kv_map),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((blocks,) + rows, jnp.float32),
+            jax.ShapeDtypeStruct((b * hk, tk, d), jnp.float32),
+            jax.ShapeDtypeStruct((b * hk, tk, d), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_bwd",
+        **params,
+    )(q.reshape(rows), do.reshape(rows), o.reshape(rows),
+      l.reshape(b * h, 1, t), m.reshape(b * h, 1, t),
+      k.reshape(b * hk, tk, d), v.reshape(b * hk, tk, d))
+    return (dq.sum(0).reshape(q.shape), dk.reshape(k.shape),
+            dv.reshape(v.shape))
+
+
+def _rounded(q, k, v):
+    """q times the softmax scale, k and v as the products of both kernels
+    take them: rounded to bfloat16.  The backward reads nothing else of
+    them, so this is what the forward keeps for it, at half the bytes."""
+    scale = 1.0 / float(q.shape[-1]) ** 0.5
+    return ((q.astype(jnp.float32) * scale).astype(jnp.bfloat16),
+            k.astype(jnp.bfloat16), v.astype(jnp.bfloat16))
 
 
 @functools.lru_cache(maxsize=64)
 def _flash_core(causal: bool, block_q: int, block_k: int,
                 interpret: bool, t_valid: int, window: Optional[int] = None):
     """custom_vjp-wrapped flash attention on block-aligned [B, H, T, D]:
-    pallas kernel forward (saves softmax residuals), blockwise-jnp exact
-    backward — so the kernel path is trainable (ulysses/ring local steps).
+    the kernel `flash_fwd` forward (saves the softmax residuals), the kernel
+    `flash_bwd` backward at the forward's tiles, exact from those residuals
+    — so the kernel path is trainable (ulysses/ring local steps).
     lru-cached per config so long-lived servers with many distinct context
     lengths don't grow an unbounded closure cache (the jit traces behind
     each entry are evicted with it)."""
@@ -533,21 +604,24 @@ def _flash_core(causal: bool, block_q: int, block_k: int,
         o, l, m = flash_attention_residuals(
             q, k, v, causal=causal, block_q=block_q, block_k=block_k,
             interpret=interpret, t_valid=t_valid, window=window)
-        return o, (q, k, v, o, l, m)
+        return o, (*_rounded(q, k, v), o, l, m)
 
     @tracing.scope("attn_bwd")
     def bwd(res, do):
         q, k, v, o, l, m = res
-        t = q.shape[2]
-        if causal and t > _BWD_TILE and t % _BWD_TILE == 0:
-            return _flash_backward_tiled(q, k, v, o, l, m, do, window,
-                                         t_valid, _BWD_TILE)
-        # the backward's scores are [B, H, T, block] arrays in HBM: it keeps
-        # 128-key blocks whatever the forward's loop takes at a pass
-        return _flash_backward_blockwise(
-            q, k, v, o, l, m, do, causal=causal, t_valid=t_valid,
-            block_k=_LANES if block_k % _LANES == 0 else block_k,
-            window=window)
+        d, tk = q.shape[3], k.shape[2]
+        block_kv = _kv_block(
+            tk, block_k,
+            _kv_bytes_per_key(d, k.dtype.itemsize) + _kv_bytes_per_key(d, 4),
+            2 * _KV_VMEM_BUDGET)
+        _note_trace("kernel_bwd", d, block_q, block_k,
+                    kv_resident=block_kv == tk)
+        grads = _flash_bwd_call(
+            q, k, v, o, l, m, do, causal=causal, block_q=block_q,
+            block_k=block_k, block_kv=block_kv, t_valid=t_valid,
+            interpret=interpret, window=window)
+        # `flash_attention` hands q, k and v over in one type, which is o's
+        return tuple(g.astype(o.dtype) for g in grads)
 
     f.defvjp(fwd, bwd)
     return f
@@ -565,10 +639,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     padded query rows sliced off, so any T works.  ``k`` and ``v`` may have
     fewer heads than ``q`` (a divisor of its count: grouped heads).  With a
     ``window``, under ``causal``, query i sees key j iff 0 <= i - j <
-    window.  Differentiable: the forward runs the pallas kernel, the
-    backward is the exact recomputation from its residuals, in blocks
-    (`_flash_backward_blockwise`) or, for a long sequence, in the tiles
-    that hold a visible pair (`_flash_backward_tiled`).
+    window.  Differentiable: the forward runs the kernel `flash_fwd`, the
+    backward the kernel `flash_bwd`, the exact recomputation from the
+    forward's residuals over the same tiles (through the interpreter where
+    the forward goes through it).
     """
     b, h, t, d = q.shape
     if h % k.shape[1]:
@@ -597,5 +671,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     core = _flash_core(causal, block_q, block_k, interpret, t_valid=t,
                        window=window)
-    out = core(qp, kp, vp)
+    out = core(qp, kp.astype(q.dtype), vp.astype(q.dtype))
     return out[:, :, :t, :] if pad else out

@@ -122,3 +122,57 @@ def rounded_flash_reference(q, k, v, causal, block_k, t_valid=None):
             preferred_element_type=f32)
         m = new_m
     return (o / jnp.maximum(l, 1e-12)).astype(q.dtype), l[..., 0], m[..., 0]
+
+
+def rounded_flash_backward(q, k, v, o, l, m, do, causal, t_valid=None,
+                           window=None):
+    """The attention backward in plain jnp with the rounding of the kernel
+    `flash_bwd`, for the tight comparisons: p = exp(s - (m + log l)) from
+    the forward's residuals, delta = sum(do * o), ds = p (dp - delta); q x
+    scale, k, v, do, p and ds reach the five products as bfloat16,
+    everything else is float32.  Whole [B, H, T, D] arrays, no tiling, no
+    skipped tile; K and V of fewer heads are spread over their groups and
+    their gradients summed.  Returns float32 (dq, dk, dv)."""
+    import jax.numpy as jnp
+
+    b, h, t, d = q.shape
+    hk, tk = k.shape[1], k.shape[2]
+    t_valid = tk if t_valid is None else t_valid
+    bf, f32 = jnp.bfloat16, jnp.float32
+    scale = 1.0 / float(d) ** 0.5
+    qs = (q.astype(f32) * scale).astype(bf)
+    kr, vr = (jnp.repeat(z, h // hk, axis=1).astype(bf) for z in (k, v))
+    gap = jnp.arange(t)[:, None] - jnp.arange(tk)[None, :]
+    mask = jnp.broadcast_to(jnp.arange(tk)[None, :] < t_valid, (t, tk))
+    if causal:
+        mask = mask & (gap >= 0)
+    if window is not None:
+        mask = mask & (gap < window)
+
+    def mm(spec, x, y):
+        return jnp.einsum(spec, x, y, preferred_element_type=f32)
+
+    lse = m + jnp.log(jnp.maximum(l, 1e-12))
+    p = jnp.where(mask, jnp.exp(mm("bhqd,bhkd->bhqk", qs, kr)
+                                - lse[..., None]), 0.0)
+    delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)
+    ds = (p * (mm("bhqd,bhkd->bhqk", do.astype(bf), vr)
+               - delta[..., None])).astype(bf)
+    dq = mm("bhqk,bhkd->bhqd", ds, kr) * scale
+    dk = mm("bhqk,bhqd->bhkd", ds, qs)
+    dv = mm("bhqk,bhqd->bhkd", p.astype(bf), do.astype(bf))
+    dk, dv = (z.reshape(b, hk, h // hk, tk, d).sum(2) for z in (dk, dv))
+    return dq, dk, dv
+
+
+def few_bits(seed, shape, step=0.25):
+    """Unit-normal draws rounded to multiples of ``step`` within +-2, as
+    float32: the products of two such numbers summed in float32 are exact
+    in any order, so two computations that round such a sum to bfloat16
+    round it the same way."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    draw = np.random.RandomState(seed).randn(*shape)
+    return jnp.asarray(np.clip(np.round(draw / step) * step, -2, 2),
+                       jnp.float32)

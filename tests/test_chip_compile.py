@@ -72,6 +72,26 @@ def _assert_kernel(text):
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
 
 
+def _kernels(text):
+    """{kernel name: its instruction} of a program's Mosaic kernels."""
+    return {m.group(1): m.group(0) for m in re.finditer(
+        r"%([\w.-]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*", text)}
+
+
+def _assert_attention_kernels(text, grad, gone):
+    """The program's kernels are `flash_fwd` and, under `grad`, `flash_bwd`
+    and no other; the backward's carries the scope `fedml.attn_bwd`, which
+    `attn_bwd_ms_per_step` reads, and no array of the shape ``gone`` (a
+    score tile of the jnp backward this kernel replaced) is left."""
+    kernels = _kernels(text)
+    assert set(kernels) == ({"flash_fwd", "flash_bwd"} if grad
+                            else {"flash_fwd"}), set(kernels)
+    if grad:
+        assert "fedml.attn_bwd" in kernels["flash_bwd"]
+        assert not re.search(gone, text), re.search(gone, text).group(0)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize("qkv", [
     ((4, 12, 1024, 64), jnp.bfloat16),    # GPT-2 small, the SFT smoke
@@ -79,9 +99,11 @@ def _assert_kernel(text):
 ], ids=["small-bf16", "large-f32"])
 def test_flash_attention_compiles_for_v5e(one_chip, qkv, grad):
     """The training attention at the smoke's and at the cell's shape.  The
-    program holds one kind of Mosaic kernel and it is `flash_fwd`: the
-    benchmark's `flash_fwd_ms_per_step` sums every `tpu_custom_call` of the
-    epoch program, so a second kernel must not slip in unnoticed."""
+    program holds `flash_fwd` and, differentiated, `flash_bwd`, and no other
+    Mosaic kernel: the benchmark's `flash_fwd_ms_per_step` sums every
+    `tpu_custom_call` of the epoch program, so a third kernel must not slip
+    in unnoticed.  Nothing of the jnp backward's [B, H, T, 128] scores is
+    left."""
     attn = functools.partial(flash_attention, causal=True, interpret=False)
     fn = attn
     if grad:
@@ -90,10 +112,8 @@ def test_flash_attention_compiles_for_v5e(one_chip, qkv, grad):
                 lambda *a: attn(*a).astype(jnp.float32).sum(),
                 argnums=(0, 1, 2))(q, k, v)
     text = _compile_text(fn, one_chip, qkv, qkv, qkv)
-    kernels = {m.group(1) for m in re.finditer(
-        r"%([\w.-]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
-        text)}
-    assert len(kernels) == 1 and "flash_fwd" in kernels.pop(), kernels
+    _assert_attention_kernels(
+        text, grad, r"f32\[%d,%d,1024,128\]" % qkv[0][:2])
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -283,7 +303,8 @@ def test_grouped_window_attention_compiles_for_v5e(one_chip, window, grad):
     over 1 key/value head of 128 at 16,384 positions, fully causal and under
     a 4096 window.  K and V of that length stay in VMEM as one block (32 MiB
     double-buffered, over the compiler's default limit, which the call
-    raises); the backward is the tiled one."""
+    raises); the backward kernel holds dK and dV of that length beside them
+    (64 MiB together) and no [.., 1024, 1024] score tile is left."""
     attn = functools.partial(flash_attention, causal=True, interpret=False,
                              window=window)
     fn = attn
@@ -295,8 +316,7 @@ def test_grouped_window_attention_compiles_for_v5e(one_chip, window, grad):
     q, kv = ((1, 7, 16384, 128), jnp.float32), ((1, 1, 16384, 128),
                                                 jnp.float32)
     text = _compile_text(fn, one_chip, q, kv, kv)
-    assert "flash_fwd" in text
-    _assert_kernel(text)
+    _assert_attention_kernels(text, grad, r"\[[\d,]*1024,1024\]")
 
 
 @pytest.mark.parametrize("transposed", [False, True], ids=["fwd", "bwd"])
@@ -345,8 +365,8 @@ def test_resnet56_constants_match_the_model():
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 def test_latent_attention_compiles_for_v5e(one_chip, grad):
     """The attention of the cell `sft.gigachat_lora_8k`: 64 heads of 192
-    (one and a half lane tiles) at 4,096 positions, q, k and v alike; the
-    backward is the tiled one."""
+    (one and a half lane tiles) at 4,096 positions, q, k and v alike,
+    forward and backward kernel; no [.., 1024, 1024] score tile is left."""
     attn = functools.partial(flash_attention, causal=True, interpret=False)
     fn = attn
     if grad:
@@ -356,8 +376,7 @@ def test_latent_attention_compiles_for_v5e(one_chip, grad):
                 argnums=(0, 1, 2))(q, k, v)
     qkv = ((1, 64, 4096, 192), jnp.float32)
     text = _compile_text(fn, one_chip, qkv, qkv, qkv)
-    assert "flash_fwd" in text
-    _assert_kernel(text)
+    _assert_attention_kernels(text, grad, r"\[[\d,]*1024,1024\]")
 
 
 @pytest.mark.parametrize("transposed", [False, True], ids=["fwd", "bwd"])

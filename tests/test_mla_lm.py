@@ -264,18 +264,23 @@ def test_flash_attention_at_head_size_192(grad):
             jnp.max(jnp.abs(wanted)))
 
 
-def test_tiled_backward_at_head_size_192(monkeypatch):
-    """A sequence of more than one backward tile walks `_flash_backward_
-    tiled`; at 192 it gives the blockwise backward's gradients."""
+def test_backward_kernel_at_head_size_192():
+    """`flash_bwd` at 192, one and a half lane tiles, over four q tiles of
+    two key passes each and two K/V blocks: the gradients of the jnp
+    backward that rounds the same operands (operands of a few bits, so that
+    no rounding to bfloat16 can fall the other way)."""
+    from conftest import few_bits, rounded_flash_backward
     from fedml_tpu.ops import pallas_attention as pa
 
-    rng = np.random.RandomState(7)
-    q, k, v, do = (jnp.asarray(rng.randn(1, 2, 128, 192), jnp.float32)
-                   for _ in range(4))
+    q, k, v, do = (few_bits(seed, (1, 2, 128, 192)) for seed in range(4))
+    q = q * (192 ** 0.5 / 16)           # q x scale: a multiple of 1/64
     o, l, m = pa._reference_residuals(q, k, v, True)
-    tiled = pa._flash_backward_tiled(q, k, v, o, l, m, do, None, 128, 32)
-    block = pa._flash_backward_blockwise(q, k, v, o, l, m, do, True, 128, 32)
-    for g, wanted in zip(tiled, block):
+    o = jnp.round(o * 64) / 64
+    got = pa._flash_bwd_call(*pa._rounded(q, k, v), o, l, m, do, causal=True,
+                             block_q=32, block_k=32, block_kv=64,
+                             t_valid=128, interpret=True)
+    want = rounded_flash_backward(q, k, v, o, l, m, do, True)
+    for g, wanted in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(wanted),
                                    atol=1e-4, rtol=1e-4)
 

@@ -150,6 +150,35 @@ def test_flash_schedule_kv_resident_or_on_the_grid(monkeypatch, causal,
 
 @pytest.mark.parametrize("resident", [True, False],
                          ids=["kv-resident", "kv-on-grid"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_backward_counts_its_kernel_and_its_kv_blocks(monkeypatch,
+                                                            causal, resident):
+    """A traced backward says `kernel_bwd` once, with the forward's tile.
+    The budget that puts the forward's K and V on the grid in blocks of two
+    passes holds, doubled, the backward's K, V, dK and dV of two passes too:
+    four K/V blocks, each q tile's dQ the sum of their partials."""
+    from fedml_tpu.ops import pallas_attention as pa
+    from fedml_tpu.parallel.ring_attention import reference_attention
+
+    if not resident:
+        _budget_of_two_passes(monkeypatch)
+    q, k, v = _qkv(12, 64, 16)
+    labels = dict(path="kernel_bwd", block_q=16, block_k=8,
+                  kv_resident=str(resident).lower(), head_dim=16)
+    before = _traces(**labels)
+    got = jax.grad(lambda *a: jnp.sum(pa.flash_attention(
+        *a, causal=causal, block_q=16, block_k=8, interpret=True) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    assert _traces(**labels) == before + 1
+    want = jax.grad(lambda *a: jnp.sum(reference_attention(
+        *a, causal=causal) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2 * BF16_ATOL, rtol=2 * BF16_ATOL)
+
+
+@pytest.mark.parametrize("resident", [True, False],
+                         ids=["kv-resident", "kv-on-grid"])
 @pytest.mark.parametrize("tile", [0, 1, 3], ids=["first", "middle", "last"])
 def test_flash_causal_tile_stops_at_its_diagonal(monkeypatch, tile,
                                                  resident):

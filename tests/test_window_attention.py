@@ -1,7 +1,8 @@
 """`flash_attention` with a window and with grouped key/value heads, forward
-and backward, against plain attention: the kernel through the interpreter,
-both backward forms (key blocks; the tiles that hold a visible pair), and the
-off-TPU fallback."""
+and backward, against plain attention: the kernels `flash_fwd` and `flash_bwd`
+through the interpreter, and the off-TPU fallback."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,17 +16,18 @@ from fedml_tpu.ops import pallas_attention as pa
 BF16_ATOL = 3e-2
 
 
-def plain_attention(q, k, v, window=None):
-    """Causal softmax attention in float32 at `highest`, [B, H, T, D] queries
-    over [B, Hk, T, D] keys and values: each q head reads head `h // group`;
-    query i sees key j iff 0 <= i - j (< window)."""
+def plain_attention(q, k, v, window=None, causal=True):
+    """Softmax attention in float32 at `highest`, [B, H, T, D] queries over
+    [B, Hk, T, D] keys and values: each q head reads head `h // group`;
+    under ``causal`` query i sees key j iff 0 <= i - j (< window)."""
     b, h, t, d = q.shape
     g = h // k.shape[1]
     k, v = (jnp.repeat(z, g, axis=1) for z in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    precision="highest") / np.sqrt(d)
     gap = np.arange(t)[:, None] - np.arange(t)[None, :]
-    mask = gap >= 0 if window is None else (gap >= 0) & (gap < window)
+    mask = gap >= 0 if causal else np.ones((t, t), bool)
+    mask = mask if window is None else mask & (gap < window)
     p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
 
@@ -81,37 +83,123 @@ def test_kernel_with_kv_blocks_on_the_grid_skips_by_window(monkeypatch,
 
 
 @pytest.mark.parametrize("window", WINDOWS)
-@pytest.mark.parametrize("tiled", [False, True], ids=["blocks", "tiles"])
-def test_backward_matches_plain_attention(monkeypatch, window, tiled):
-    """Both backward forms under a window and grouped heads: key blocks over
-    all queries (a short sequence), and the tiles that hold a visible pair
-    (a sequence of several `_BWD_TILE`s, 16 here)."""
-    monkeypatch.setattr(pa, "_BWD_TILE", 16 if tiled else 1024)
-    pa._flash_core.cache_clear()
+@pytest.mark.parametrize("tiles", [(16, 8), (8, 24)], ids=["16x8", "8x24"])
+def test_backward_matches_plain_attention(window, tiles):
+    """The kernel `flash_bwd` under a window and grouped heads, against
+    autodiff of plain attention: three q tiles of two key passes each, and
+    six q tiles over passes longer than they are."""
     q, k, v = _qkv(3, 48)
     got = _grads(lambda *a: pa.flash_attention(
-        *a, causal=True, block_q=16, block_k=8, interpret=True,
+        *a, causal=True, block_q=tiles[0], block_k=tiles[1], interpret=True,
         window=window), q, k, v)
     want = _grads(lambda *a: plain_attention(*a, window=window), q, k, v)
-    pa._flash_core.cache_clear()
     for g, w in zip(got, want):
         assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    atol=2 * BF16_ATOL, rtol=2 * BF16_ATOL)
 
 
-def test_tiled_backward_equals_blockwise_backward():
-    """The two forms are the same arithmetic in another order: float32
-    rounding apart, they agree."""
+def _backward(shape, hk, causal, window, t_valid, block_q, block_k, block_kv):
+    """(`flash_bwd` through the interpreter, the jnp backward that rounds
+    what it rounds) on [B, H, T, D] operands of a few bits each, q so that q
+    x scale is one such number: every sum that feeds a rounding to bfloat16
+    (s, dp, delta) is then exact on both sides, and the two differ by the
+    order of the last products' float32 sums alone.  l and m are those of
+    the operands; o, which the backward only multiplies with do, is cut to
+    multiples of 1/64."""
+    from conftest import few_bits, rounded_flash_backward
+
+    b, h, t, d = shape
+    q = few_bits(21, shape) * 2.0 ** -round(np.log2(d) / 2) * float(d) ** 0.5
+    k, v = (few_bits(seed, (b, hk, t, d)) for seed in (22, 23))
+    do = few_bits(24, shape)
+    o, l, m = pa._reference_residuals(q, k, v, causal, t_valid, window)
+    o = jnp.round(o * 64) / 64
+    got = pa._flash_bwd_call(
+        *pa._rounded(q, k, v), o, l, m, do, causal=causal, block_q=block_q,
+        block_k=block_k, block_kv=block_kv, t_valid=t_valid, interpret=True,
+        window=window)
+    return got, rounded_flash_backward(q, k, v, o, l, m, do, causal, t_valid,
+                                       window)
+
+
+# window (none, shorter than a tile, crossing tiles), K/V heads under 6 (or
+# 2) q heads, causal, T (43 pads to 48: padded keys and query rows), head
+# size, K/V blocks of the grid; every case has 3 q tiles of 16 or more
+BACKWARD_CASES = [
+    (None, 6, True, 48, 8, 1), (None, 2, True, 43, 64, 3),
+    (None, 6, False, 48, 8, 2), (None, 2, False, 43, 64, 1),
+    (5, 6, True, 48, 64, 1), (5, 2, True, 43, 8, 3),
+    (23, 6, True, 43, 8, 1), (23, 1, True, 64, 64, 2),
+    (23, 2, True, 43, 192, 1), (None, 1, True, 64, 192, 2),
+    (40, 2, True, 64, 8, 4),
+]
+
+
+@pytest.mark.parametrize("window,hk,causal,t,d,kv_blocks", BACKWARD_CASES)
+def test_backward_kernel_matches_autodiff_and_its_rounded_form(
+        window, hk, causal, t, d, kv_blocks):
+    """`flash_bwd`'s gradients (a) within the bfloat16 tolerance of autodiff
+    of plain float32 attention, through `flash_attention` as a caller
+    reaches it, and (b) tightly against the jnp backward fed operands
+    rounded as the kernel rounds them, over windows, groups, non-causal
+    attention, padded keys, head sizes and K/V blocks on the grid."""
+    h = 2 if d == 192 else 6
+    q, k, v = _qkv(11, t, d=d, b=1, h=h, hk=min(hk, h))
+    got = _grads(lambda *a: pa.flash_attention(
+        *a, causal=causal, block_q=16, block_k=8, interpret=True,
+        window=window), q, k, v)
+    want = _grads(lambda *a: plain_attention(*a, window, causal), q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2 * BF16_ATOL, rtol=2 * BF16_ATOL)
+    t_pad = -(-t // 16) * 16
+    got, tight = _backward((q.shape[0], h, t_pad, d), k.shape[1], causal,
+                           window, t, 16, 8, t_pad // kv_blocks)
+    for g, w in zip(got, tight):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4,
+                                   rtol=1e-4)
+
+
+def test_backward_kernel_is_the_same_arithmetic_at_any_tiles():
+    """Other tiles and K/V blocks walk the same pairs in another order:
+    float32 rounding apart, the gradients agree."""
     q, k, v = _qkv(4, 64)
     o, l, m = pa._reference_residuals(q, k, v, True, window=20)
     do = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.float32)
-    a = pa._flash_backward_blockwise(q, k, v, o, l, m, do, causal=True,
-                                     t_valid=64, block_k=8, window=20)
-    b = pa._flash_backward_tiled(q, k, v, o, l, m, do, 20, 64, 16)
+    a, b = (pa._flash_bwd_call(
+        *pa._rounded(q, k, v), o, l, m, do, causal=True, block_q=bq,
+        block_k=bk, block_kv=bkv, t_valid=64, interpret=True, window=20)
+        for bq, bk, bkv in [(16, 8, 64), (32, 16, 16)])
     for x, y in zip(a, b):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kv_blocks", [1, 4], ids=["kv-resident",
+                                                   "kv-on-grid"])
+def test_backward_kernel_never_visits_a_dead_tile(kv_blocks):
+    """Tiles wholly outside the window or above the diagonal cost neither a
+    fetch nor a step (poisoned here: a visit would show as NaN).  Queries
+    48.. see no key below 48 - 23 + 1 = 26: with the key passes wholly below
+    it poisoned their dQ stays finite, and with those queries' cotangent
+    poisoned, dK and dV of those passes do."""
+    window, dead = 23, 24
+    q, k, v = _qkv(12, 64)
+    do = jnp.asarray(np.random.RandomState(6).randn(*q.shape), jnp.float32)
+    o, l, m = pa._reference_residuals(q, k, v, True, window=window)
+    call = functools.partial(
+        pa._flash_bwd_call, causal=True, block_q=16, block_k=8,
+        block_kv=64 // kv_blocks, t_valid=64, interpret=True, window=window)
+    dq, _, _ = call(*pa._rounded(q, k.at[:, :, :dead].set(jnp.nan),
+                                 v.at[:, :, :dead].set(jnp.nan)), o, l, m, do)
+    assert np.isfinite(np.asarray(dq[:, :, 48:])).all()
+    assert np.isnan(np.asarray(dq[:, :, :dead])).all()
+    _, dk, dv = call(*pa._rounded(q, k, v), o, l, m,
+                     do.at[:, :, 48:].set(jnp.nan))
+    for g in (dk, dv):
+        assert np.isfinite(np.asarray(g[:, :, :dead])).all()
+        assert np.isnan(np.asarray(g[:, :, 48:])).all()
 
 
 @pytest.mark.parametrize("window", [5, 48, None])
