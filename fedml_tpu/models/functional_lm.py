@@ -20,13 +20,18 @@ it rotates q and k, the shape of its attention (query heads, key/value heads,
 head size, window; or latent attention, `Latent`: q through a low rank, keys
 and values through one shared latent beside one rotary key) and its MLP
 (dense GELU, dense SwiGLU, or routed experts of which this chip holds a
-share, with or without a shared expert beside them).  GPT-2 is the default
+share, with or without a shared expert beside them, gated or not).  A layer's
+mixer may also be no attention at all but a gated delta rule (`DeltaRule`: a
+linear-attention layer that mixes tokens through a recurrent state a head,
+`ops/delta_rule`), and its attention may norm q and k by head, rotate only a
+head's first numbers, gate its output by a second half of ``wq``, and centre
+its norms' scales on zero.  GPT-2 is the default
 description; a model of another family (`routed_lm`: RMSNorm, rotary or no
 positions by layer, grouped heads or latent attention, windows by layer,
-routed experts behind a softmax or a group-limited sigmoid router, dense
-layers ahead of the routed ones, an untied head, a second head that predicts
-one token further) is another, through the same `block`, `embed`, `head` and
-`lm_forward`.
+delta-rule layers among the attention ones, routed experts behind a softmax
+or a group-limited sigmoid router, dense layers ahead of the routed ones, an
+untied head, a second head that predicts one token further) is another,
+through the same `block`, `embed`, `head` and `lm_forward`.
 """
 
 from __future__ import annotations
@@ -42,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.mlops import tracing
+from ..ops.delta_rule import gated_delta_rule
 from ..ops.routed_experts import (Experts, held_experts, route,
                                   route_in_groups)
 
@@ -76,6 +82,23 @@ class Latent(NamedTuple):
     mscale_all_dim: float = 0.0
 
 
+class DeltaRule(NamedTuple):
+    """A gated delta-rule mixer in place of attention (Yang et al. 2024;
+    `ops/delta_rule`): ``w_qkvz`` makes q and k for ``key_heads`` heads of
+    ``key_dim``, v and an output gate z for ``value_heads`` heads of
+    ``value_dim`` (value head j reads key head j // (value_heads /
+    key_heads)); ``w_ba`` a writing strength and a decay a value head; q, k
+    and v cross a causal depthwise convolution of ``conv`` taps and a SiLU,
+    q and k are normed to length 1 by head (q over ``sqrt(key_dim)``
+    besides); the rule's output is RMS-normed by head, times ``silu(z)``."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv: int = 4
+
+
 @dataclasses.dataclass(frozen=True)
 class Layer:
     """What one block is made of.  The defaults are GPT-2's."""
@@ -100,6 +123,19 @@ class Layer:
     swiglu: Optional[int] = None
     #: width of a SwiGLU expert that every token crosses, beside the routed
     shared: Optional[int] = None
+    #: a gated delta rule in place of the attention (no ``attend`` then)
+    delta: Optional[DeltaRule] = None
+    #: numbers of a head, its first, that the rotation turns; None: all
+    rotary: Optional[int] = None
+    #: q and k normed by head (scales ``q_norm``, ``k_norm``) before they turn
+    qk_norm: bool = False
+    #: ``wq`` makes, beside a head's q, as many gate numbers: the attention's
+    #: output is taken times their sigmoid
+    out_gate: bool = False
+    #: an RMSNorm's scale is ``1 + g``: the stored ``g`` is centred on zero
+    centred: bool = False
+    #: the shared expert's output times ``sigmoid(y w)``, ``shared_gate`` [D]
+    shared_gate: bool = False
 
 
 GPT2 = Layer()
@@ -137,7 +173,7 @@ def _norm(x, g, layer: Layer = GPT2):
     if layer.norm == "rmsnorm":
         return x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), -1, keepdims=True) + layer.eps
-        ) * g["scale"]
+        ) * (1.0 + g["scale"] if layer.centred else g["scale"])
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.var(x, -1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + layer.eps) * g["scale"] + g["bias"]
@@ -156,6 +192,16 @@ def _rotate(x, freq):
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _rotate_first(x, layer: Layer):
+    """`_rotate` on the first ``layer.rotary`` numbers of a head (all of
+    them where it names none), the rest passing through."""
+    n = layer.rotary or x.shape[-1]
+    turned = _rotate(x[..., :n], _rope_freq(layer.rope_theta, n // 2))
+    if n == x.shape[-1]:
+        return turned
+    return jnp.concatenate([turned, x[..., n:]], axis=-1)
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -213,6 +259,71 @@ def _latent_qkv(y, blk, heads: int, layer: Layer):
     k = jnp.concatenate([kv[..., :la.nope], jnp.broadcast_to(
         k_rope, (*lead, heads, la.rope))], axis=-1)
     return q, k, kv[..., la.nope:]
+
+
+def _causal_conv(x, w):
+    """Depthwise over the channels of ``x`` [B, T, C], causal along T:
+    ``c_t = sum_i w[:, i] x_{t - (taps - 1) + i}``, zeros before the row's
+    start.  As many shifted multiply-adds as taps, which XLA fuses into one
+    pass over ``x``."""
+    taps, t = w.shape[1], x.shape[1]
+    x = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(x[:, i:i + t] * w[:, i].astype(x.dtype) for i in range(taps))
+
+
+def _unit(x):
+    """``x`` over its length, by head."""
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+
+def _delta_mixer(y, blk, layer: Layer):
+    """The gated delta-rule mixer over whole rows ``y`` [B, T, D] from a zero
+    state: what it adds to the stream.  Matrices: ``w_qkvz`` [D, 2 Hk Dk + 2
+    Hv Dv] (q, k, v, z side by side), ``w_ba`` [D, 2 Hv] (b, then a),
+    ``conv`` [2 Hk Dk + Hv Dv, taps], ``a_log`` and ``dt_bias`` [Hv], the
+    gated norm's scale ``gdn_norm`` [Dv], ``wo`` [Hv Dv, D]."""
+    if y.ndim != 3:
+        raise NotImplementedError(
+            "a delta-rule layer runs over whole rows [B, T, D]: the serving "
+            "cache holds no recurrent state")
+    de, (b, t, _) = layer.delta, y.shape
+    nq, nv = de.key_heads * de.key_dim, de.value_heads * de.value_dim
+    with tracing.scope("gdn.proj"):
+        qkvz = y @ blk["w_qkvz"]
+        ba = (y @ blk["w_ba"]).astype(jnp.float32)
+
+    # made again in the backward from the projection: what the convolution
+    # and the norms would keep (two [B, T, 2 Hk Dk + Hv Dv] arrays) is half
+    # a gigabyte a layer at 16,384 positions
+    @jax.checkpoint
+    def operands(qkvz, ba):
+        with tracing.scope("gdn.conv"):
+            qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * nq + nv],
+                                           blk["conv"]))
+        with tracing.scope("gdn.gates"):
+            beta = jax.nn.sigmoid(ba[..., :de.value_heads])
+            g = -jnp.exp(blk["a_log"]) * jax.nn.softplus(
+                ba[..., de.value_heads:] + blk["dt_bias"])
+            heads = (b, t, de.key_heads, de.key_dim)
+            q = _unit(qkv[..., :nq].reshape(heads)) * de.key_dim ** -0.5
+            k = _unit(qkv[..., nq:2 * nq].reshape(heads))
+            v = qkv[..., 2 * nq:].reshape(b, t, de.value_heads, de.value_dim)
+        return q, k, v, g, beta
+
+    o = gated_delta_rule(*operands(qkvz, ba))
+    with tracing.scope("gdn.out"):
+        z = qkvz[..., 2 * nq + nv:].reshape(o.shape)
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                              + layer.eps) * blk["gdn_norm"]["scale"]
+        return (o * jax.nn.silu(z)).astype(y.dtype).reshape(b, t, nv) @ blk[
+            "wo"]
+
+
+@tracing.scope("attn.gate")
+def _gated(o, gate):
+    """An attention's output, a head's numbers times the sigmoid of as many
+    gate numbers."""
+    return o * jax.nn.sigmoid(gate)
 
 
 def _bias(z, blk, key):
@@ -291,26 +402,44 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
     the heads'.  The MLP gets the block's input beside its own (a router
     may read either); a routed layer hands ``note`` how its picks fell, and
     the picks.  A matrix kept below the stream's type is taken up to it at
-    its product."""
+    its product.  A delta-rule layer has no ``attend``: its mixer
+    (`_delta_mixer`) runs over whole rows."""
     dim = h.shape[-1]
     dh = layer.head_dim or dim // heads
     y = _norm(h, blk["ln1"], layer)
 
     def proj(w, b, n):
-        z = _bias(y @ blk[w], blk, b).reshape(*y.shape[:-1], n, dh)
-        if layer.rope_theta is not None and w != "wv":
-            z = _rotate(z, _rope_freq(layer.rope_theta, dh // 2))
+        return _bias(y @ blk[w], blk, b).reshape(*y.shape[:-1], n, -1)
+
+    def turned(z, norm):        # a q or a k on its way to the scores
+        if layer.qk_norm:
+            z = _norm(z, blk[norm], layer)
+        if layer.rope_theta is not None:
+            z = _rotate_first(z, layer)
         return z
 
-    kv = layer.kv_heads or heads
-    with tracing.scope("attn.qkv"):
-        qkv = (_latent_qkv(y, blk, heads, layer) if layer.latent is not None
-               else (proj("wq", "bq", heads), proj("wk", "bk", kv),
-                     proj("wv", "bv", kv)))
-    with tracing.scope("attn"):
-        o = attend(*qkv)
-    with tracing.scope("attn.out"):
-        a = h + _bias(o.reshape(*h.shape[:-1], -1) @ blk["wo"], blk, "bo")
+    if layer.delta is not None:
+        a = h + _delta_mixer(y, blk, layer)
+    else:
+        kv = layer.kv_heads or heads
+        gate = None
+        with tracing.scope("attn.qkv"):
+            if layer.latent is not None:
+                qkv = _latent_qkv(y, blk, heads, layer)
+            else:
+                q = proj("wq", "bq", heads)
+                if layer.out_gate:          # a head's q beside its gate
+                    q, gate = q[..., :dh], q[..., dh:]
+                qkv = (turned(q, "q_norm"),
+                       turned(proj("wk", "bk", kv), "k_norm"),
+                       proj("wv", "bv", kv))
+        with tracing.scope("attn"):
+            o = attend(*qkv)
+        if gate is not None:
+            o = _gated(o, gate)
+        with tracing.scope("attn.out"):
+            a = h + _bias(o.reshape(*h.shape[:-1], -1) @ blk["wo"], blk,
+                          "bo")
     y = _norm(a, blk["ln2"], layer)
     if layer.experts is None:
         with tracing.scope("mlp"):
@@ -321,7 +450,11 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
         note(stats, picks)
     if layer.shared is not None:
         with tracing.scope("mlp.shared"):
-            out = out + _swiglu(y, blk["shared_gate_up"], blk["shared_down"])
+            shared = _swiglu(y, blk["shared_gate_up"], blk["shared_down"])
+            if layer.shared_gate:
+                shared = shared * jax.nn.sigmoid(
+                    y @ blk["shared_gate"])[..., None]
+            out = out + shared
     return a + out
 
 
@@ -495,9 +628,11 @@ def loss_in_row_blocks(h: jnp.ndarray, w_out: jnp.ndarray, y: jnp.ndarray,
 
 
 def _draws(layer: Layer) -> int:
-    """Matrices `init_routed_params` draws for a block."""
+    """Arrays `init_routed_params` draws for a block."""
     mlp = 2 if layer.experts is None else 3 + 2 * (layer.shared is not None)
-    return (5 if layer.latent is not None else 4) + mlp
+    mixer = (6 if layer.delta is not None else
+             5 if layer.latent is not None else 4)
+    return mixer + mlp + layer.shared_gate
 
 
 @partial(jax.jit, static_argnames=("vocab", "dim", "heads", "ffn", "layers",
@@ -519,6 +654,12 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
     the untied head ``w_out`` [D, V].  ``mtp``: the block of a second head
     that predicts one token further, the last of ``blocks``, with its
     joining matrix ``w_eh`` [2 D, D] and three norms under ``"mtp"``.
+    A delta-rule layer: `_delta_mixer`'s arrays in place of the attention's,
+    ``a_log`` the log of a decay rate drawn in (0, 16) and ``dt_bias`` the
+    inverse softplus of a step drawn log-uniformly in [0.001, 0.1] (Yang et
+    al. 2024's initialiser).  A layer with ``out_gate``: ``wq`` [D, 2 H Dh];
+    with ``qk_norm``: ``q_norm`` and ``k_norm`` [Dh]; with ``shared_gate``:
+    ``shared_gate`` [D]; ``centred``: norm scales start at 0, not 1.
     ``store``: the type the matrices
     that training leaves frozen or merges factors into are kept in; norms'
     scales, routers and their biases are float32 whatever it is."""
@@ -530,18 +671,38 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
         return (jax.random.normal(next(ks), shape)
                 / np.sqrt(fan_in)).astype(dtype)
 
-    def scale(n=dim):
-        return {"scale": jnp.ones((n,))}
+    def scale(n=dim, centred=False):
+        return {"scale": jnp.zeros((n,)) if centred else jnp.ones((n,))}
+
+    def delta_mixer(de: DeltaRule):
+        nq, nv = de.key_heads * de.key_dim, de.value_heads * de.value_dim
+        rate = jax.random.uniform(next(ks), (de.value_heads,), minval=1e-3,
+                                  maxval=16.0)
+        step = jnp.exp(jax.random.uniform(
+            next(ks), (de.value_heads,), minval=math.log(1e-3),
+            maxval=math.log(0.1)))
+        return dict(
+            w_qkvz=normal((dim, 2 * nq + 2 * nv), dim),
+            w_ba=normal((dim, 2 * de.value_heads), dim),
+            conv=normal((2 * nq + nv, de.conv), de.conv),
+            a_log=jnp.log(rate), dt_bias=step + jnp.log(-jnp.expm1(-step)),
+            gdn_norm=scale(de.value_dim), wo=normal((nv, dim), nv))
 
     def make(layer):
         dh, kv, ex, la = (layer.head_dim, layer.kv_heads, layer.experts,
                           layer.latent)
-        blk = {"ln1": scale()}
-        if la is None:
-            blk.update(wq=normal((dim, heads * dh), dim),
+        scale_ = partial(scale, centred=layer.centred)
+        blk = {"ln1": scale_()}
+        if layer.delta is not None:
+            blk.update(delta_mixer(layer.delta))
+        elif la is None:
+            blk.update(wq=normal((dim, heads * dh * (1 + layer.out_gate)),
+                                 dim),
                        wk=normal((dim, kv * dh), dim),
                        wv=normal((dim, kv * dh), dim),
                        wo=normal((heads * dh, dim), heads * dh))
+            if layer.qk_norm:
+                blk.update(q_norm=scale_(dh), k_norm=scale_(dh))
         else:
             blk.update(
                 wq_a=normal((dim, la.q_rank), dim), q_norm=scale(la.q_rank),
@@ -552,7 +713,7 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
                 wkv_b=normal((la.kv_rank, heads * (la.nope + la.v)),
                              la.kv_rank),
                 wo=normal((heads * la.v, dim), heads * la.v))
-        blk["ln2"] = scale()
+        blk["ln2"] = scale_()
         if ex is None:
             blk.update(w_gate_up=normal((dim, 2 * layer.swiglu), dim),
                        w_down=normal((layer.swiglu, dim), layer.swiglu))
@@ -566,6 +727,8 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
             blk.update(
                 shared_gate_up=normal((dim, 2 * layer.shared), dim),
                 shared_down=normal((layer.shared, dim), layer.shared))
+            if layer.shared_gate:
+                blk["shared_gate"] = normal((dim,), dim, jnp.float32)
         return blk
 
     params = {"blocks": [make(layer) for layer in every]}
@@ -573,7 +736,7 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
         params["mtp"] = {"norm_e": scale(), "norm_h": scale(),
                          "w_eh": normal((2 * dim, dim), 2 * dim),
                          "ln_f": scale()}
-    return dict(params, ln_f=scale(),
+    return dict(params, ln_f=scale(centred=every[-1].centred),
                 embed=(jax.random.normal(next(ks), (vocab, dim))
                        * 0.02).astype(store),
                 w_out=normal((dim, vocab), dim))

@@ -160,28 +160,41 @@ def create(args: Any, output_dim: Optional[int] = None) -> ModelBundle:
         # `lm_dense_layers` / `lm_dense_ffn`: leading dense SwiGLU layers;
         # `lm_shared_ffn`: a shared expert in the routed ones; `lm_mtp` /
         # `lm_mtp_weight`: a second head one token further; `lm_store`: the
-        # type the frozen matrices are kept in
+        # type the frozen matrices are kept in.  With `lm_delta` (a
+        # `functional_lm.DeltaRule`'s fields) and `lm_delta_layout`: a gated
+        # delta rule in place of attention where the layout gives a layer 1;
+        # `lm_attention` (`rotary qk_norm out_gate` of `Layer`) shapes the
+        # attention of the others; `lm_centred_norm`: norm scales of `1 + g`;
+        # `lm_shared_gate`: the shared expert behind a sigmoid gate
         from ..ops.routed_experts import Experts
-        from .functional_lm import Latent, Layer, RoutedLMModule
+        from .functional_lm import DeltaRule, Latent, Layer, RoutedLMModule
 
         get = lambda key, default=None: getattr(args, key, default) or default
         experts = Experts(
             total=int(args.lm_experts), held=int(args.lm_experts_held),
             first_held=int(get("lm_first_held", 0)),
             top_k=int(args.lm_top_k), **dict(get("lm_router", {})))
-        shape = dict(norm="rmsnorm", eps=float(args.lm_norm_eps))
+        shape = dict(norm="rmsnorm", eps=float(args.lm_norm_eps),
+                     centred=bool(get("lm_centred_norm", False)))
         if get("lm_latent"):
             shape["latent"] = Latent(**dict(args.lm_latent))
             attention = [shape] * int(args.lm_layers)
         else:
             shape.update(kv_heads=int(args.lm_kv_heads),
                          head_dim=int(args.lm_head_dim))
+            shape.update(get("lm_attention", {}))
             attention = [dict(
                 shape, rope_theta=float(args.lm_rope_theta) if rotates
                 else None, window=int(args.lm_window) if windowed else None)
                 for rotates, windowed in zip(args.lm_rope_layout,
                                              args.lm_window_layout)]
-        routed = dict(experts=experts, shared=get("lm_shared_ffn"))
+            if get("lm_delta"):
+                delta = DeltaRule(**dict(args.lm_delta))
+                attention = [dict(a, delta=delta) if recurrent else a
+                             for a, recurrent in zip(attention,
+                                                     args.lm_delta_layout)]
+        routed = dict(experts=experts, shared=get("lm_shared_ffn"),
+                      shared_gate=bool(get("lm_shared_gate", False)))
         dense = int(get("lm_dense_layers", 0))
         module = RoutedLMModule(
             vocab=num_classes, dim=int(args.lm_dim),

@@ -22,8 +22,11 @@ DEFAULT_TARGETS = (r".*attention.*kernel", r".*(query|key|value|out).*kernel",
                    r".*Dense_\d+.*kernel",
                    # functional-LM layout (models/functional_lm.py):
                    # per-block attention/MLP matmuls; a latent layer's two
-                   # matrices each for q and for keys and values
-                   r".*/w[qkvo]", r".*/w[12]", r".*/w(q|kv)_[ab]")
+                   # matrices each for q and for keys and values; a
+                   # delta-rule layer's projection into q, k, v and its gate
+                   # (its way out is a ``wo`` like any other)
+                   r".*/w[qkvo]", r".*/w[12]", r".*/w(q|kv)_[ab]",
+                   r".*/w_qkvz")
 
 
 def _path_str(path) -> str:

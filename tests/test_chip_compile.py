@@ -440,3 +440,51 @@ def test_the_combine_compiles_for_v5e(one_chip, monkeypatch, n, d, experts,
         *((leaf.shape, leaf.dtype) for leaf in plan))
     assert "moe_sum_picks" in text
     _assert_kernel(text)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_gated_attention_shape_compiles_for_v5e(one_chip, grad):
+    """The softmax layers of the cell `sft.qwen3next_lora_16k`: 16 query
+    heads over 2 key/value heads of 256 (two lane tiles) at 16,384 positions,
+    K and V of that length resident; forward and backward kernel, and no
+    [.., 1024, 1024] score tile is left."""
+    attn = functools.partial(flash_attention, causal=True, interpret=False)
+    fn = attn
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: attn(*a).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+    q, kv = ((1, 16, 16384, 256), jnp.float32), ((1, 2, 16384, 256),
+                                                 jnp.float32)
+    text = _compile_text(fn, one_chip, q, kv, kv)
+    _assert_attention_kernels(text, grad, r"\[[\d,]*1024,1024\]")
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_delta_rule_compiles_for_v5e(one_chip, grad):
+    """The scan of the cell `sft.qwen3next_lora_16k`: 16 key heads and 32
+    value heads of 128 at 16,384 positions.  The program holds `gdn_fwd`
+    and, differentiated, `gdn_bwd` under the scope `fedml.gdn.scan_bwd`,
+    which the benchmark's readers match, and no other Mosaic kernel; the
+    forward called for a gradient keeps a state a chunk, rounded to
+    bfloat16, and nothing of [T, T] is made."""
+    from fedml_tpu.ops.delta_rule import gated_delta_rule
+
+    rule = functools.partial(gated_delta_rule, interpret=False)
+    fn = rule
+    if grad:
+        def fn(*operands):
+            return jax.grad(lambda *a: rule(*a).sum(),
+                            argnums=(0, 1, 2, 3, 4))(*operands)
+    qk, v = ((1, 16384, 16, 128), jnp.float32), ((1, 16384, 32, 128),
+                                                 jnp.float32)
+    gate = ((1, 16384, 32), jnp.float32)
+    text = _compile_text(fn, one_chip, qk, qk, v, gate, gate)
+    kernels = _kernels(text)
+    assert set(kernels) == ({"gdn_fwd", "gdn_bwd"} if grad else {"gdn_fwd"})
+    assert "fedml.gdn.scan" in kernels["gdn_fwd"]
+    if grad:
+        assert "fedml.gdn.scan_bwd" in kernels["gdn_bwd"]
+        assert "bf16[32,256,128,128]" in kernels["gdn_fwd"]
+    assert not re.search(r"\[[\d,]*16384,16384\]", text)
