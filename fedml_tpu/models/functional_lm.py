@@ -47,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.mlops import tracing
-from ..ops.delta_rule import gated_delta_rule
+from ..ops.delta_rule import (causal_conv as _causal_conv, gated_delta_mixer,
+                              gated_delta_rule, plain_gate, unit as _unit)
 from ..ops.routed_experts import (Experts, held_experts, route,
                                   route_in_groups)
 
@@ -261,27 +262,15 @@ def _latent_qkv(y, blk, heads: int, layer: Layer):
     return q, k, kv[..., la.nope:]
 
 
-def _causal_conv(x, w):
-    """Depthwise over the channels of ``x`` [B, T, C], causal along T:
-    ``c_t = sum_i w[:, i] x_{t - (taps - 1) + i}``, zeros before the row's
-    start.  As many shifted multiply-adds as taps, which XLA fuses into one
-    pass over ``x``."""
-    taps, t = w.shape[1], x.shape[1]
-    x = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(x[:, i:i + t] * w[:, i].astype(x.dtype) for i in range(taps))
-
-
-def _unit(x):
-    """``x`` over its length, by head."""
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-
-
 def _delta_mixer(y, blk, layer: Layer):
     """The gated delta-rule mixer over whole rows ``y`` [B, T, D] from a zero
     state: what it adds to the stream.  Matrices: ``w_qkvz`` [D, 2 Hk Dk + 2
     Hv Dv] (q, k, v, z side by side), ``w_ba`` [D, 2 Hv] (b, then a),
     ``conv`` [2 Hk Dk + Hv Dv, taps], ``a_log`` and ``dt_bias`` [Hv], the
-    gated norm's scale ``gdn_norm`` [Dv], ``wo`` [Hv Dv, D]."""
+    gated norm's scale ``gdn_norm`` [Dv], ``wo`` [Hv Dv, D].  Between the
+    projection and ``wo`` the rows cross `ops/delta_rule`'s kernels where
+    they run (`gated_delta_mixer`); where they do not, the jnp below, which
+    is their definition."""
     if y.ndim != 3:
         raise NotImplementedError(
             "a delta-rule layer runs over whole rows [B, T, D]: the serving "
@@ -291,32 +280,27 @@ def _delta_mixer(y, blk, layer: Layer):
     with tracing.scope("gdn.proj"):
         qkvz = y @ blk["w_qkvz"]
         ba = (y @ blk["w_ba"]).astype(jnp.float32)
-
-    # made again in the backward from the projection: what the convolution
-    # and the norms would keep (two [B, T, 2 Hk Dk + Hv Dv] arrays) is half
-    # a gigabyte a layer at 16,384 positions
-    @jax.checkpoint
-    def operands(qkvz, ba):
+    with tracing.scope("gdn.gates"):
+        beta = jax.nn.sigmoid(ba[..., :de.value_heads])
+        g = -jnp.exp(blk["a_log"]) * jax.nn.softplus(
+            ba[..., de.value_heads:] + blk["dt_bias"])
+    scale = blk["gdn_norm"]["scale"]
+    o = gated_delta_mixer(qkvz, g, beta, blk["conv"], scale, eps=layer.eps,
+                          heads=(de.key_heads, de.value_heads))
+    if o is None:
         with tracing.scope("gdn.conv"):
             qkv = jax.nn.silu(_causal_conv(qkvz[..., :2 * nq + nv],
                                            blk["conv"]))
         with tracing.scope("gdn.gates"):
-            beta = jax.nn.sigmoid(ba[..., :de.value_heads])
-            g = -jnp.exp(blk["a_log"]) * jax.nn.softplus(
-                ba[..., de.value_heads:] + blk["dt_bias"])
             heads = (b, t, de.key_heads, de.key_dim)
             q = _unit(qkv[..., :nq].reshape(heads)) * de.key_dim ** -0.5
             k = _unit(qkv[..., nq:2 * nq].reshape(heads))
             v = qkv[..., 2 * nq:].reshape(b, t, de.value_heads, de.value_dim)
-        return q, k, v, g, beta
-
-    o = gated_delta_rule(*operands(qkvz, ba))
+        o = gated_delta_rule(q, k, v, g, beta)
+        with tracing.scope("gdn.out"):
+            o = plain_gate(o, qkvz, scale, layer.eps)
     with tracing.scope("gdn.out"):
-        z = qkvz[..., 2 * nq + nv:].reshape(o.shape)
-        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
-                              + layer.eps) * blk["gdn_norm"]["scale"]
-        return (o * jax.nn.silu(z)).astype(y.dtype).reshape(b, t, nv) @ blk[
-            "wo"]
+        return o.astype(y.dtype).reshape(b, t, nv) @ blk["wo"]
 
 
 @tracing.scope("attn.gate")

@@ -488,3 +488,46 @@ def test_delta_rule_compiles_for_v5e(one_chip, grad):
         assert "fedml.gdn.scan_bwd" in kernels["gdn_bwd"]
         assert "bf16[32,256,128,128]" in kernels["gdn_fwd"]
     assert not re.search(r"\[[\d,]*16384,16384\]", text)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_delta_mixer_compiles_for_v5e(one_chip, grad):
+    """The mixer of the same cell between its projection `[1, 16384, 12288]`
+    and its way out: the operands' and the gated norm's kernels beside the
+    scan's, each under the scope the benchmark's readers sum (none under a
+    name that begins with `gdn_fwd` or `gdn_bwd`), the convolution's weights
+    as stored (bfloat16).  Differentiated to the projection, the decay and
+    the writing strength (LoRA's case: the convolution and the norm's scale
+    frozen), the projection's gradient is one array that two kernels write,
+    the second in place, and nothing else passes over an array of 8,192 or
+    12,288 columns."""
+    from fedml_tpu.ops.delta_rule import gated_delta_mixer
+
+    mixer = functools.partial(gated_delta_mixer, heads=(16, 32), eps=1e-6,
+                              interpret=False)
+    fn = mixer
+    if grad:
+        def fn(*operands):
+            return jax.grad(lambda *a: jnp.square(mixer(*a)).sum(),
+                            argnums=(0, 1, 2))(*operands)
+    gate = ((1, 16384, 32), jnp.float32)
+    text = _compile_text(fn, one_chip, ((1, 16384, 12288), jnp.float32), gate,
+                         gate, ((8192, 4), jnp.bfloat16),
+                         ((128,), jnp.float32))
+    kernels = _kernels(text)
+    scopes = {"gdn_operands_fwd": "fedml.gdn.conv", "gdn_fwd": "fedml.gdn.scan",
+              "gdn_gate_fwd": "fedml.gdn.out"}
+    if grad:
+        scopes.update(gdn_gate_bwd="fedml.gdn.out",
+                      gdn_bwd="fedml.gdn.scan_bwd",
+                      gdn_operands_bwd="fedml.gdn.conv")
+    assert set(kernels) == set(scopes)
+    for name, scope in scopes.items():
+        assert scope in kernels[name], name
+    if grad:
+        assert "output_to_operand_aliasing" in kernels["gdn_operands_bwd"]
+    # what XLA is left with is the [16384, 32] arrays' work
+    wide = re.findall(r"\n\s*(?:ROOT )?%\S+ = \w+\[1,16384,(?:8192|12288)\]"
+                      r"\S* (?!custom-call|parameter|get-tuple-element)\w",
+                      text)
+    assert not wide, wide

@@ -119,6 +119,31 @@ def test_program_with_the_kernels_interpreted_agrees_too(weights,
     assert _worst(got_g, want_g) < 2e-2
 
 
+def test_the_mixers_kernels_interpreted_are_the_jnp_path(weights,
+                                                        monkeypatch):
+    """The passes around the scan in their kernels (`gated_delta_mixer`:
+    operands, scan and gated norm under one `custom_vjp`, blocks of 16
+    positions) against the jnp of `_delta_mixer` with the recurrence: the
+    loss and every factor's gradient, to float32 rounding (the experts'
+    operands float32 on both sides: rounded to bfloat16 they turn a last
+    digit's difference into one of 1e-3)."""
+    from fedml_tpu.ops import delta_rule
+
+    params, lora, (x, y) = weights
+    monkeypatch.setattr(rex, "_OPERAND", jnp.float32)
+    want, want_g = _program_loss(_module(), params, lora, x, y)
+    for name, value in (("_OPERAND", "float32"), ("_CHUNK", 16),
+                        ("_MIXER_ROWS", 16), ("_SLAB", 8)):
+        monkeypatch.setattr(delta_rule, name, value)
+    calls = []
+    monkeypatch.setattr(flm, "gated_delta_mixer", lambda *a, **kw: calls.append(
+        1) or delta_rule.gated_delta_mixer(*a, interpret=True, **kw))
+    got, got_g = _program_loss(_module(), params, lora, x, y)
+    assert len(calls) >= 3                      # the three delta-rule layers
+    assert abs(float(got) - float(want)) < 1e-6 * float(want)
+    assert _worst(got_g, want_g) < 5e-5
+
+
 def _without(monkeypatch, what):
     """The module with one mechanism of the family dropped."""
     if what == "output gate":
