@@ -31,7 +31,10 @@ positions by layer, grouped heads or latent attention, windows by layer,
 delta-rule layers among the attention ones, routed experts behind a softmax
 or a group-limited sigmoid router, dense layers ahead of the routed ones, an
 untied head, a second head that predicts one token further) is another,
-through the same `block`, `embed`, `head` and `lm_forward`.
+through the same `block`, `embed`, `head` and `lm_forward`.  A third kind of
+mixer is a gated short convolution (`ShortConv`): two gates around a causal
+depthwise convolution of a few taps, whose state a row carries is its last
+``taps - 1`` inputs; its ``attend`` is the convolution itself.
 """
 
 from __future__ import annotations
@@ -100,6 +103,33 @@ class DeltaRule(NamedTuple):
     conv: int = 4
 
 
+class ShortConv(NamedTuple):
+    """A gated short convolution in place of attention (LFM2's ``conv``
+    layers): ``[b, c, x] = y w_in`` ([D, 3 D]), ``u = b * x``, ``z`` the
+    causal depthwise convolution of ``u`` over ``taps`` positions (``conv``
+    [D, taps], zeros left of position 0), out ``(c * z) wo``.  No
+    activation.  What a row carries from one position to the next is ``u``
+    at its last ``taps - 1`` positions."""
+
+    taps: int = 3
+
+
+class Rows(NamedTuple):
+    """What a caller that serves knows of the rows it hands `block` and a
+    whole-sequence pass does not need to be told."""
+
+    #: each row's position, in the shape of ``h`` less its last axis; None:
+    #: a row's index along the axis before the heads'
+    pos: Optional[jnp.ndarray] = None
+    #: [N] bool over the flattened rows: those that hold a request's token.
+    #: The picks of the others land on no expert (a slot that holds no
+    #: request, a prompt's padding: they would fetch matrices for nothing)
+    live: Optional[jnp.ndarray] = None
+    #: rows of a tile of the expert layer's layout (`routed_experts.
+    #: fit_tile`); None: `routed_experts.TILE`
+    tile: Optional[int] = None
+
+
 @dataclasses.dataclass(frozen=True)
 class Layer:
     """What one block is made of.  The defaults are GPT-2's."""
@@ -137,6 +167,9 @@ class Layer:
     centred: bool = False
     #: the shared expert's output times ``sigmoid(y w)``, ``shared_gate`` [D]
     shared_gate: bool = False
+    #: a gated short convolution in place of the attention: ``attend`` is
+    #: then handed ``u`` and the taps, and gives the convolution
+    conv: Optional[ShortConv] = None
 
 
 GPT2 = Layer()
@@ -172,9 +205,11 @@ def init_lm_params(key: jax.Array, vocab: int, dim: int = 64,
 @tracing.scope("norm")
 def _norm(x, g, layer: Layer = GPT2):
     if layer.norm == "rmsnorm":
-        return x * jax.lax.rsqrt(
+        # in the stream's type, whatever the scale is kept in
+        return (x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), -1, keepdims=True) + layer.eps
         ) * (1.0 + g["scale"] if layer.centred else g["scale"])
+                ).astype(x.dtype)
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.var(x, -1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + layer.eps) * g["scale"] + g["bias"]
@@ -184,22 +219,26 @@ def _rope_freq(theta: float, half: int):
     return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
 
 
-def _rotate(x, freq):
-    """Rotary positions on [..., T, H, Dh], position = index along T, pair i
-    turning ``freq[i]`` a position: the two halves of a head are the pairs'
-    first and second members."""
+def _rotate(x, freq, pos=None):
+    """Rotary positions on [..., T, H, Dh], position = index along T (or
+    ``pos``, in the shape of ``x`` less its last two axes), pair i turning
+    ``freq[i]`` a position: the two halves of a head are the pairs' first and
+    second members."""
     t, half = x.shape[-3], x.shape[-1] // 2
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if pos is None:
+        pos = jnp.arange(t, dtype=jnp.float32)
+    angle = pos.astype(jnp.float32)[..., None] * freq
+    cos, sin = jnp.cos(angle)[..., None, :], jnp.sin(angle)[..., None, :]
     a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
-def _rotate_first(x, layer: Layer):
+def _rotate_first(x, layer: Layer, pos=None):
     """`_rotate` on the first ``layer.rotary`` numbers of a head (all of
     them where it names none), the rest passing through."""
     n = layer.rotary or x.shape[-1]
-    turned = _rotate(x[..., :n], _rope_freq(layer.rope_theta, n // 2))
+    turned = _rotate(x[..., :n], _rope_freq(layer.rope_theta, n // 2), pos)
     if n == x.shape[-1]:
         return turned
     return jnp.concatenate([turned, x[..., n:]], axis=-1)
@@ -344,11 +383,13 @@ def _swiglu(y, w_gate_up, w_down):
     return (jax.nn.silu(gate) * up) @ w_down
 
 
-def _expert_mlp(y, h_in, blk, experts: Experts):
+def _expert_mlp(y, h_in, blk, experts: Experts, rows: Rows = Rows()):
     """The held experts' share of the routed layer (`ops/routed_experts`).
     The router reads what ``experts`` says: ``h_in``, the block's input
     ahead of its first norm, or ``y``, which the experts read.  Also how the
-    layer's picks fell, and the picks themselves [N, top_k]."""
+    layer's picks fell, and the picks themselves [N, top_k].  Where ``rows``
+    says which rows are live, the picks of the others land on no expert and
+    are not counted."""
     src = y if experts.reads == "normed" else h_in
     src = src.reshape(-1, src.shape[-1])
     kept = None
@@ -357,13 +398,20 @@ def _expert_mlp(y, h_in, blk, experts: Experts):
             src, blk["router"], blk["router_bias"], experts)
     else:
         picks, weights = route(src, blk["router"], experts.top_k)
+    n_picks = jnp.asarray(picks.size, jnp.int32)
+    if rows.live is not None:
+        picks = jnp.where(rows.live[:, None], picks, experts.total)
+        n_picks = jnp.sum(rows.live, dtype=jnp.int32) * picks.shape[1]
     out, counts, rows_passed = held_experts(
         y.reshape(-1, y.shape[-1]), picks, weights, blk["w_gate_up"],
-        blk["w_down"], experts)
-    stats = {"picks": jnp.asarray(picks.size, jnp.int32),
+        blk["w_down"], experts, tile=rows.tile)
+    stats = {"picks": n_picks,
              "picks_held": jnp.sum(counts),
              "rows_passed": rows_passed,
              "expert_picks_max": jnp.max(counts)}
+    if rows.live is not None:
+        # held experts a live row picked at all: whose matrices were fetched
+        stats["experts_touched"] = jnp.sum(counts > 0, dtype=jnp.int32)
     if kept is not None:
         # tokens that kept the group the held experts lie in
         stats["tokens_in_held_group"] = jnp.sum(
@@ -372,18 +420,35 @@ def _expert_mlp(y, h_in, blk, experts: Experts):
     return out.reshape(y.shape).astype(y.dtype), stats, picks
 
 
+def _conv_mixer(y, blk, convolve: Callable):
+    """The gated short convolution's mixer (`ShortConv`): what it adds to
+    the stream.  ``convolve(u, taps)`` is the causal convolution of ``u``
+    [..., D]: over whole rows from zeros, or one position from the inputs a
+    row carries (`serving.kv_cache_lm`)."""
+    with tracing.scope("conv.proj"):
+        b, c, x = jnp.split(y @ blk["w_in"], 3, axis=-1)
+    with tracing.scope("conv.taps"):
+        z = convolve(b * x, blk["conv"])
+    with tracing.scope("conv.out"):
+        return (c * z) @ blk["wo"]
+
+
 def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
           attend: Callable, layer: Layer = GPT2,
           note: Optional[Callable[[Dict[str, jnp.ndarray], jnp.ndarray],
-                                  None]] = None) -> jnp.ndarray:
+                                  None]] = None,
+          rows: Rows = Rows()) -> jnp.ndarray:
     """One pre-norm block over ``h`` [..., D], made as ``layer`` says.
     ``attend(q, k, v)`` takes the three projections as [..., H, Dh] (k and
     v [..., Hk, Dh] under grouped heads) and returns the attention's output
     in q's shape: it is all that differs between training (an attention
     over the whole sequence), prefill (the same, keeping K and V) and
     decode (one position against a cache).  A rotation, where the layer
-    has one, takes a row's position from its index along the axis before
-    the heads'.  The MLP gets the block's input beside its own (a router
+    has one, takes a row's position from ``rows.pos`` or, where the caller
+    gives none, from its index along the axis before the heads'.  A layer
+    whose mixer is a short convolution hands ``attend`` the gated input and
+    the taps instead, ``attend(u, taps)``, and takes the convolution back.
+    The MLP gets the block's input beside its own (a router
     may read either); a routed layer hands ``note`` how its picks fell, and
     the picks.  A matrix kept below the stream's type is taken up to it at
     its product.  A delta-rule layer has no ``attend``: its mixer
@@ -399,11 +464,13 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
         if layer.qk_norm:
             z = _norm(z, blk[norm], layer)
         if layer.rope_theta is not None:
-            z = _rotate_first(z, layer)
+            z = _rotate_first(z, layer, rows.pos)
         return z
 
     if layer.delta is not None:
         a = h + _delta_mixer(y, blk, layer)
+    elif layer.conv is not None:
+        a = h + _conv_mixer(y, blk, attend)
     else:
         kv = layer.kv_heads or heads
         gate = None
@@ -429,7 +496,7 @@ def block(h: jnp.ndarray, blk: Dict[str, Any], heads: int,
         with tracing.scope("mlp"):
             return a + (_dense_mlp(y, blk) if layer.swiglu is None else
                         _swiglu(y, blk["w_gate_up"], blk["w_down"]))
-    out, stats, picks = _expert_mlp(y, h, blk, layer.experts)
+    out, stats, picks = _expert_mlp(y, h, blk, layer.experts, rows)
     if note is not None:
         note(stats, picks)
     if layer.shared is not None:
@@ -464,7 +531,10 @@ def _add_stats(a: Dict[str, jnp.ndarray],
 
 def _over_sequence(attn_fn, layer: Layer) -> Callable:
     """The ``attend`` of a whole-sequence pass: ``attn_fn`` on [B, H, T,
-    Dh], under the layer's window where it has one."""
+    Dh], under the layer's window where it has one; for a short-convolution
+    layer the convolution from zeros."""
+    if layer.conv is not None:
+        return _causal_conv
     if layer.window is not None:
         attn_fn = partial(attn_fn, window=layer.window)
 
@@ -615,6 +685,7 @@ def _draws(layer: Layer) -> int:
     """Arrays `init_routed_params` draws for a block."""
     mlp = 2 if layer.experts is None else 3 + 2 * (layer.shared is not None)
     mixer = (6 if layer.delta is not None else
+             3 if layer.conv is not None else
              5 if layer.latent is not None else 4)
     return mixer + mlp + layer.shared_gate
 
@@ -638,6 +709,8 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
     the untied head ``w_out`` [D, V].  ``mtp``: the block of a second head
     that predicts one token further, the last of ``blocks``, with its
     joining matrix ``w_eh`` [2 D, D] and three norms under ``"mtp"``.
+    A short-convolution layer: ``w_in`` [D, 3 D], ``conv`` [D, taps], ``wo``
+    [D, D] in place of the attention's.
     A delta-rule layer: `_delta_mixer`'s arrays in place of the attention's,
     ``a_log`` the log of a decay rate drawn in (0, 16) and ``dt_bias`` the
     inverse softplus of a step drawn log-uniformly in [0.001, 0.1] (Yang et
@@ -679,6 +752,10 @@ def init_routed_params(key: jax.Array, vocab: int, dim: int, heads: int,
         blk = {"ln1": scale_()}
         if layer.delta is not None:
             blk.update(delta_mixer(layer.delta))
+        elif layer.conv is not None:
+            blk.update(w_in=normal((dim, 3 * dim), dim),
+                       conv=normal((dim, layer.conv.taps), layer.conv.taps),
+                       wo=normal((dim, dim), dim))
         elif la is None:
             blk.update(wq=normal((dim, heads * dh * (1 + layer.out_gate)),
                                  dim),

@@ -165,9 +165,13 @@ def create(args: Any, output_dim: Optional[int] = None) -> ModelBundle:
         # delta rule in place of attention where the layout gives a layer 1;
         # `lm_attention` (`rotary qk_norm out_gate` of `Layer`) shapes the
         # attention of the others; `lm_centred_norm`: norm scales of `1 + g`;
-        # `lm_shared_gate`: the shared expert behind a sigmoid gate
+        # `lm_shared_gate`: the shared expert behind a sigmoid gate.  With
+        # `lm_conv` (a `functional_lm.ShortConv`'s fields) and
+        # `lm_conv_layout`: a gated short convolution in place of attention
+        # where the layout gives a layer 1
         from ..ops.routed_experts import Experts
-        from .functional_lm import DeltaRule, Latent, Layer, RoutedLMModule
+        from .functional_lm import (DeltaRule, Latent, Layer, RoutedLMModule,
+                                    ShortConv)
 
         get = lambda key, default=None: getattr(args, key, default) or default
         experts = Experts(
@@ -193,6 +197,11 @@ def create(args: Any, output_dim: Optional[int] = None) -> ModelBundle:
                 attention = [dict(a, delta=delta) if recurrent else a
                              for a, recurrent in zip(attention,
                                                      args.lm_delta_layout)]
+            if get("lm_conv"):
+                conv = ShortConv(**dict(args.lm_conv))
+                attention = [dict(a, conv=conv) if short else a
+                             for a, short in zip(attention,
+                                                 args.lm_conv_layout)]
         routed = dict(experts=experts, shared=get("lm_shared_ffn"),
                       shared_gate=bool(get("lm_shared_gate", False)))
         dense = int(get("lm_dense_layers", 0))

@@ -20,8 +20,11 @@ used.)  Inside a step the block's live tiles of 128 positions are taken in
 one after another: each of the 128 lanes keeps its own running maximum, sum
 and ``[Dh]`` accumulator (an online softmax 128 wide: no lane meets another
 until the row's last block), scores and sums in float32, K and V read as
-stored.  The row's last step folds the 128 lanes into the row's maximum
-``m``, sum ``l`` and unnormalised output ``o``: ``softmax(s) @ v == o / l``,
+stored.  Under grouped heads the cache holds ``Hk`` key/value heads for
+``H`` query heads: query head h reads key/value head ``h // (H / Hk)`` of
+the same block, so a block is fetched once for its whole group.  The row's
+last step folds the 128 lanes into the row's maximum ``m``, sum ``l`` and
+unnormalised output ``o``: ``softmax(s) @ v == o / l``,
 and a caller with more positions of its own (the dispatch's chunk) merges
 them by the two statistics.
 
@@ -71,6 +74,7 @@ def _attention_kernel(scale: float, block: int, ragged: bool,
                       rows_ref, blocks_ref, len_ref, q_ref, k_ref, v_ref,
                       o_ref, m_ref, l_ref, q_wide, acc, m_lane, l_lane):
     heads, dh = acc.shape[:2]
+    group = heads // k_ref.shape[1]     # query heads a key/value head
     step = pl.program_id(0)
     blk = blocks_ref[step]
     length = len_ref[rows_ref[step]]
@@ -92,7 +96,7 @@ def _attention_kernel(scale: float, block: int, ragged: bool,
         position = first + jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
         live = position < length
         for h in range(heads):
-            k = k_ref[0, h, :, lanes].astype(jnp.float32)
+            k = k_ref[0, h // group, :, lanes].astype(jnp.float32)
             s = jnp.sum(k * q_wide[h], axis=0, keepdims=True) * scale
             s = jnp.where(live, s, MASKED)
             m_old = m_lane[h:h + 1, :]
@@ -101,7 +105,7 @@ def _attention_kernel(scale: float, block: int, ragged: bool,
             p = jnp.where(live, jnp.exp(s - m_new), 0.0)
             m_lane[h:h + 1, :] = m_new
             l_lane[h:h + 1, :] = shrink * l_lane[h:h + 1, :] + p
-            v = v_ref[0, h, :, lanes].astype(jnp.float32)
+            v = v_ref[0, h // group, :, lanes].astype(jnp.float32)
             if ragged:      # past the cache's end a block holds no value
                 v = jnp.where(live, v, 0.0)
             acc[h] = shrink * acc[h] + p * v
@@ -134,7 +138,8 @@ def _attend(q, k, v, lengths, scale: float, interpret: bool):
     """`decode_attention`, under its own `jit`: a program that attends layer
     after layer traces and lowers the kernel once and calls it (as
     `pallas_kv_store._store_window`)."""
-    b, h, dh, t = k.shape
+    b, hk, dh, t = k.shape
+    h = q.shape[1]
     lengths = jnp.clip(lengths, 0, t)
     nblocks = -(-t // _BLOCK)
     rows, blocks, steps = _live_blocks(lengths, _BLOCK, nblocks)
@@ -145,7 +150,7 @@ def _attend(q, k, v, lengths, scale: float, interpret: bool):
                             lambda n, rows, blocks, lens: (rows[n], 0, 0))
 
     kv_block = pl.BlockSpec(
-        (1, h, dh, _BLOCK),
+        (1, hk, dh, _BLOCK),
         lambda n, rows, blocks, lens: (rows[n], 0, 0, blocks[n]))
     o, m, l = pl.pallas_call(
         functools.partial(_attention_kernel, scale, _BLOCK,
@@ -180,8 +185,10 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     head, in float32.  A row of length 0 gives ``m = MASKED``, ``l = 0`` and
     ``o = 0``, and none of its cache is read.
 
-    ``q``: ``[B, H, Dh]``, ``k`` and ``v``: ``[B, H, Dh, T]`` (read in the
-    type they are stored in), ``lengths``: int ``[B]``, taken as at most T.
+    ``q``: ``[B, H, Dh]``, ``k`` and ``v``: ``[B, Hk, Dh, T]`` (read in the
+    type they are stored in; ``Hk`` divides ``H``, query head h reading
+    key/value head ``h // (H / Hk)``), ``lengths``: int ``[B]``, taken as at
+    most T.
     Returns ``o [B, H, Dh]``, ``m [B, H]`` and ``l [B, H]``."""
     return _attend(q, k, v, lengths.astype(jnp.int32), scale=float(scale),
                    interpret=not _on_tpu())

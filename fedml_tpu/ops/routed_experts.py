@@ -74,6 +74,18 @@ _OPERAND = jnp.bfloat16
 #: expert's matrix, and the boundary an expert's rows start at
 TILE = 256
 
+
+def fit_tile(picks: int, held: int) -> int:
+    """Rows of a tile for a layer that lays out ``picks`` picks over ``held``
+    experts: the power of two at or over twice an expert's even share, from
+    16 (a bfloat16 sublane tile) to `TILE`.  A training step's rows get
+    `TILE`; a decode step of 32 rows x 4 picks over 64 experts, two rows an
+    expert, gets 16, where a tile of 256 would do 128 times the products its
+    rows need, about as long as the expert's matrix takes to fetch."""
+    share = max(2 * picks // max(held, 1), 1)
+    return min(max(1 << (share - 1).bit_length(), 16), TILE)
+
+
 #: tiles of a chunk: what one trip of a row-wise pass goes over.  Half a
 #: chunk a pass goes over for nothing, and a trip costs next to nothing
 #: beside its 2,048 rows; at 16 tiles and more XLA sums a row's dot in
@@ -746,7 +758,8 @@ _held_share.defvjp(_held_share_fwd, _held_share_bwd)
 
 
 def held_experts(y, picks, weights, w_gate_up, w_down, experts: Experts,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None,
+                 tile: Optional[int] = None):
     """The held experts' share of a gated expert layer.  ``y`` [N, D] the
     layer's normed input (float32), ``picks``/``weights`` [N, top_k] from
     the router, ``w_gate_up`` [held, D, 2F] (an expert's gate columns, then
@@ -755,8 +768,10 @@ def held_experts(y, picks, weights, w_gate_up, w_down, experts: Experts,
     [N, D] float32 (``act`` relu or silu, as ``experts`` says), and the
     picks that landed on each held expert [held], and the rows each of the
     layer's passes went over.  Differentiable to ``y``, the weights and the
-    matrices."""
-    plan = plan_rows(picks, experts, TILE)
+    matrices.  ``tile``: the rows of a tile of the layout, `TILE` where the
+    caller names none (`fit_tile` for a caller with few rows); a pick of an
+    expert number outside ``0 .. total`` lands nowhere."""
+    plan = plan_rows(picks, experts, tile or TILE)
     return (_held_share(y.astype(jnp.float32), weights, w_gate_up, w_down,
                         plan, interpret, experts.act), plan.counts,
             rows_passed(plan))

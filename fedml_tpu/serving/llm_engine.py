@@ -128,9 +128,42 @@ class _EngineMetrics:
             "Cache blocks there are (max_batch x ceil(T / 128)), summed "
             "over decode_multi's token steps",
             labels=("engine",)).labels(engine=engine_label)
+        # a state that is not by position (a short convolution's last
+        # inputs) is set at every admission, never inherited: from the
+        # prefill, or to zeros by the row's first dispatch from position 0
+        self.state_sets = _metrics.counter(
+            "fedml_llm_state_sets_total",
+            "Admissions that set a slot's fixed-size state, by how: from "
+            "the prefill, or to zeros for a prompt fed through decode",
+            labels=("engine", "how"))
         self._decode_lock = named_lock("_EngineMetrics._decode_lock")
         self._decode_steps = 0
         self._decode_secs = 0.0
+
+    def note_moe(self, counts: "np.ndarray") -> None:
+        """A routed model's counts of one dispatch (`kv_cache_lm.MOE_COUNTS`,
+        a token step a column), onto the process's counters: the same
+        ``fedml_moe_*`` the epoch program's counts land on, and the experts
+        touched (docs/OBSERVABILITY.md)."""
+        from .kv_cache_lm import MOE_COUNTS
+
+        for key, row in zip(MOE_COUNTS, counts):
+            name, what = self._MOE[key]
+            # on the host already: `_step_multi` fetched it with the tokens
+            _metrics.counter(name, what).inc(
+                float(row.sum()))  # fedml: noqa[JAX003]
+
+    _MOE = {
+        "picks": ("fedml_moe_picks_total",
+                  "expert picks routed, over tokens, layers and steps"),
+        "expert_picks_max": (
+            "fedml_moe_expert_picks_max",
+            "picks of the heaviest held expert of each step, summed"),
+        "experts_touched": (
+            "fedml_moe_experts_touched_total",
+            "held experts a token step picked at all, summed over layers "
+            "and steps: the matrices decode had to fetch"),
+    }
 
     # -- per-request lifecycle ----------------------------------------------
     # events: submit → (queue) → admit|shed → prefill → first_token →
@@ -241,17 +274,18 @@ _scatter_cache_row_jit = None
 
 
 def _scatter_cache_row(cache, row_cache, slot):
-    """Write a 1-row prefilled KV cache into row ``slot`` of the batch
-    cache (one jitted donate-in-place dispatch for all layers) — the
-    admission path of `KVCacheLLMEngine._prefill_admit`."""
+    """Write a 1-row prefilled cache into row ``slot`` of the batch cache
+    (one jitted donate-in-place dispatch for all layers, whatever each
+    layer keeps: `kv_cache_lm.layer_state` puts the rows first in every
+    entry) — the admission path of `KVCacheLLMEngine._prefill_admit`."""
     global _scatter_cache_row_jit
     if _scatter_cache_row_jit is None:
         import jax
 
         def scatter_cache_row(cache, row_cache, slot):
             return [
-                {"k": layer["k"].at[slot].set(row["k"][0]),
-                 "v": layer["v"].at[slot].set(row["v"][0])}
+                {name: kept.at[slot].set(row[name][0])
+                 for name, kept in layer.items()}
                 for layer, row in zip(cache, row_cache)]
 
         _scatter_cache_row_jit = jax.jit(scatter_cache_row,
@@ -687,6 +721,8 @@ class KVCacheLLMEngine:
         # _pos vs len(req.ids))
         self._pos = np.zeros((self.max_batch,), np.int32)
         self._cache = lm.init_cache(self.max_batch)
+        #: some layer keeps a state that is not by position
+        self._stateful = any("k" not in layer for layer in self._cache)
         self._stop = threading.Event()
         self._rng_key = jax.random.PRNGKey(13)
         #: guards loop-mutated counters that stats() snapshots from other
@@ -805,6 +841,10 @@ class KVCacheLLMEngine:
             self._pos[slot] = 0
             self._metrics.note_admit(req, slot)
             prefilled = self._prefill_admit(slot, req)
+            if self._stateful:
+                self._metrics.state_sets.labels(
+                    engine=self._metrics.label,
+                    how="prefill" if prefilled else "zero").inc()
         return prefilled, admit.dur_s
 
     def _end_iteration(self, admit_s: float, *parts: tracing.Phase) -> None:
@@ -834,7 +874,10 @@ class KVCacheLLMEngine:
         Measured on v5e (GPT-2 geometry, 45-token prompt, k=16): served
         TTFT 1075 ms → one prefill + one decode dispatch.  Decode resumes
         at the LAST prompt position: feeding ids[P-1] at pos P-1 rewrites
-        identical K/V and yields the logits that sample token P."""
+        identical K/V and yields the logits that sample token P; a state
+        that is not by position comes from `prefill` as it stood before
+        that position.  A prompt that is not prefilled starts at position
+        0, where `decode_multi` starts every such state from zeros."""
         p = len(req.ids)
         k = self.tokens_per_dispatch
         # short prompts: chunked prefill already reaches generation in one
@@ -957,9 +1000,10 @@ class KVCacheLLMEngine:
         b = self.max_batch
         with tracing.phase("fedml.serve.build") as build:
             prompt_buf = np.zeros((b, k), np.int32)
-            prompt_n = np.ones((b,), np.int32)
-            # a slot that holds no request is sent with length 0: the
-            # attention then reads nothing of its row
+            # a slot that holds no request is sent with no token to feed and
+            # length 0: its picks then land on no expert, and the attention
+            # reads nothing of its row
+            prompt_n = np.zeros((b,), np.int32)
             pos0 = np.zeros((b,), np.int32)
             temps = np.zeros((b,), np.float32)
             top_k = np.zeros((b,), np.int32)
@@ -1001,6 +1045,8 @@ class KVCacheLLMEngine:
                 self._cache, *operands, sub, k, exact_filters=exact)
         with tracing.phase("fedml.serve.fetch") as fetch:
             emitted = np.asarray(emitted)
+        if emitted.shape[0] > b:        # a routed model's counts ride along
+            self._metrics.note_moe(emitted[b:])
         dt_dispatch = dispatch.dur_s + fetch.dur_s
         self._metrics.step.observe(dt_dispatch)
         self._metrics.note_decode(dt_dispatch, self.active_count)
